@@ -561,6 +561,7 @@ for ev in events[warmup:]:
 mt.core.block_until_ready()
 dt = time.perf_counter() - t0
 row = {
+    "platform": jax.devices()[0].platform,
     "n_devices": len(jax.devices()),
     "vertex_sharding": vertex_sharding,
     "frontier_exchange": frontier_exchange,
@@ -572,6 +573,24 @@ if mesh_shape is not None:
     row["mesh_shape"] = list(mesh_shape)
 print(json.dumps(row))
 """
+
+
+def _cpu_child_env(ndev: int) -> Dict[str, str]:
+    """Environment of a forced-host-device child: pinned to the CPU, so
+    it can never contend for an accelerator the parent process holds.
+    XLA_FLAGS is appended to, not clobbered: the child runs under the
+    parent's XLA settings plus the forced device count."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={ndev}"
+    ).strip()
+    src_path = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")
+    )
+    env["PYTHONPATH"] = src_path + os.pathsep + env.get("PYTHONPATH", "")
+    return env
 
 
 def sharded_device_scaling(
@@ -586,34 +605,23 @@ def sharded_device_scaling(
 ) -> List[Dict[str, float]]:
     """Time the sharded engine (replicated or range-sharded vertex state,
     bitmask or sparse frontier exchange) under forced host device counts
-    (one subprocess per count — XLA fixes the device count at init). On
-    a single-core CPU container the host devices share one core, so this
-    measures collective overhead rather than speedup; on real multi-core
-    or multi-chip hardware the same harness reports the paper's
-    time-vs-workers curve — the ``vertex_sharding="range"`` sweep is the
+    (one subprocess per count — XLA fixes the device count at init).
+    Every child is pinned to the CPU and its row says so (``platform``):
+    the forced devices share the host's cores, so this is a rehearsal
+    of collective overhead, never a device timing — the
+    ``vertex_sharding="range"`` sweep is the
     one whose per-round vertex traffic stays O(n + frontier bits * d) as
     d grows (docs/DESIGN.md §4.2), and ``frontier_exchange="sparse"``
     shrinks the frontier term to O(cap * d) words (§4.3)."""
-    src_path = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")
-    )
     rows: List[Dict[str, float]] = []
     for ndev in device_counts:
-        env = dict(os.environ)
-        # append, don't clobber: the child must run under the same XLA
-        # settings as the parent's timings, plus the forced device count
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={ndev}"
-        ).strip()
-        env["PYTHONPATH"] = src_path + os.pathsep + env.get("PYTHONPATH", "")
         out = subprocess.run(
             [sys.executable, "-c", _SCALING_SCRIPT,
              str(n), str(m), str(n_batches), str(batch_size), str(warmup),
              vertex_sharding, frontier_exchange],
             capture_output=True,
             text=True,
-            env=env,
+            env=_cpu_child_env(ndev),
             timeout=900,
         )
         if out.returncode != 0:
@@ -635,32 +643,22 @@ def halo_mesh_scaling(
 ) -> List[Dict[str, float]]:
     """Time the halo engine across 2-axis (edge x vertex) mesh
     factorizations of forced host devices (one subprocess per shape —
-    d_e * d_v devices each). The same wall-clock caveat as
-    ``sharded_device_scaling`` applies on this 1-core container; what
+    d_e * d_v CPU devices each). The same CPU-rehearsal caveat as
+    ``sharded_device_scaling`` applies; what
     the sweep pins everywhere is the SHAPE axis the flat engines don't
     have: at fixed device count, trading edge lanes (d_e) against
     vertex owners (d_v) moves per-device memory O(n/d_v + halo) and the
     halo exchange O(d_v * hcap) in opposite directions
     (docs/DESIGN.md §4.4)."""
-    src_path = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", "src")
-    )
     rows: List[Dict[str, float]] = []
     for d_e, d_v in mesh_shapes:
-        ndev = d_e * d_v
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={ndev}"
-        ).strip()
-        env["PYTHONPATH"] = src_path + os.pathsep + env.get("PYTHONPATH", "")
         out = subprocess.run(
             [sys.executable, "-c", _SCALING_SCRIPT,
              str(n), str(m), str(n_batches), str(batch_size), str(warmup),
              "halo", "bitmask", f"{d_e}x{d_v}"],
             capture_output=True,
             text=True,
-            env=env,
+            env=_cpu_child_env(d_e * d_v),
             timeout=900,
         )
         if out.returncode != 0:

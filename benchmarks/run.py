@@ -24,6 +24,9 @@ def main() -> None:
     ap.add_argument("--roofline-json", default="dryrun_results.json")
     ap.add_argument("--stream-json", default="BENCH_stream.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.quick and args.stream_json == "BENCH_stream.json":
         # --quick skips the device-scaling sweeps; never let it clobber
         # the committed artifact (CI asserts the sweep rows are present)

@@ -15,6 +15,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.api import CoreMaintainer
 from repro.core.oracle import bz_from_csr
 from repro.core.weighted import weighted_core_oracle
@@ -84,6 +85,7 @@ def main():
              "overflow — docs/DESIGN.md §4.3)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.weighted and args.engine == "host":
         ap.error("--weighted needs a device engine (unified | sharded)")
     if args.window is not None and args.window < 1:

@@ -290,7 +290,9 @@ def write_budgets(engines: Sequence[str],
 def _reexec_with_devices(n_devices: int, argv: Sequence[str]) -> int:
     """Re-run this CLI in a subprocess with N forced host devices.
     Needed because importing repro.analysis already initialized jax —
-    XLA_FLAGS must be set before that import, not after."""
+    XLA_FLAGS must be set before that import, not after. The child is
+    pinned to the CPU: it audits traced programs on forced host devices
+    and must never contend for an accelerator this process holds."""
     if os.environ.get(_CHILD_GUARD):
         print(
             f"audit: failed to force {n_devices} host devices via "
@@ -306,6 +308,7 @@ def _reexec_with_devices(n_devices: int, argv: Sequence[str]) -> int:
     env["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={n_devices}"
     ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD_GUARD] = "1"
     src_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

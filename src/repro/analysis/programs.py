@@ -51,9 +51,9 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.api import plan_frontier_cap, plan_window
 from ..core.engine import (
     DONATED_STATE_ARGS,

@@ -620,9 +620,6 @@ class CoreMaintainer:
         src[:m] = edges[:, 0]
         dst[:m] = edges[:, 1]
         val[:m] = True
-        edge_slot = {
-            (int(a), int(b)): i for i, (a, b) in enumerate(edges)
-        }
         n_levels = g.n + 2
         if weighted:
             if weights is None:
@@ -677,7 +674,6 @@ class CoreMaintainer:
                 weighted=True,
                 w=jnp.asarray(wcol),
                 validate=validate,
-                slot_cache=edge_slot,
                 live_ub=m,
                 hwm_ub=m,
             )
@@ -716,7 +712,6 @@ class CoreMaintainer:
             frontier_cap=frontier_cap,
             kernel_backend=kernel_backend,
             validate=validate,
-            slot_cache=edge_slot,
             live_ub=m,
             hwm_ub=m,
         )

@@ -254,19 +254,16 @@ def renumber_ring(core: Array, label: Array, axis: str, n_shards: int,
 def maybe_renumber_ring(core: Array, label: Array, axis: str,
                         n_shards: int, note=None,
                         force: Array | None = None) -> Tuple[Array, Array]:
-    """``maybe_renumber`` over owned slices: the headroom check completes
-    with one pmin + one pmax over the owner axis (replicated verdict, so
-    every device takes the same cond arm); the relabel itself is the
-    ring renumber, traced inside the cond. ``force`` (a replicated bool)
-    ORs into the verdict — the weighted engine relabels whenever cores
-    moved, since its fixpoints freeze labels instead of placing blocks."""
-    lim = jnp.int64(1) << 61
+    """``maybe_renumber`` over owned slices: the local headroom verdict
+    completes with one int32 pmax over the owner axis (replicated, so
+    every device takes the same cond arm; the TPU compiler reduces 64-bit
+    values by sum only); the relabel itself is the ring renumber, traced
+    inside the cond. ``force`` (a replicated bool) ORs into the verdict —
+    the weighted engine relabels whenever cores moved, since its
+    fixpoints freeze labels instead of placing blocks."""
     if note is not None:
-        note("pmin_scalar", 8)
-        note("pmax_scalar", 8)
-    lo = jax.lax.pmin(jnp.min(label), axis)
-    hi = jax.lax.pmax(jnp.max(label), axis)
-    need = (lo < -lim) | (hi > lim)
+        note("pmax_scalar", 4)
+    need = jax.lax.pmax(needs_renumber(label).astype(jnp.int32), axis) > 0
     if force is not None:
         need = need | force
     new_label = jax.lax.cond(

@@ -50,9 +50,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from .engine import (DONATED_STATE_ARGS, WEIGHTED_DONATED_STATE_ARGS,
                      batch_program, batch_program_halo)
 from .vertex_layout import make_layout

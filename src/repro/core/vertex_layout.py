@@ -353,9 +353,18 @@ class HaloSession:
         safe, mine = self._owner_rows()
         contrib = jnp.where(mine, owned[safe], jnp.zeros((), owned.dtype))
         _note("regather", self.halo_cap * owned.dtype.itemsize)
-        return jax.lax.psum_scatter(
+        wide = owned.dtype.itemsize == 8
+        if wide:
+            # the TPU compiler has no 64-bit reduce_scatter: ship the two
+            # 32-bit words instead — each row has one nonzero contributor,
+            # so each word's sum is that contributor's word, exactly
+            contrib = jax.lax.bitcast_convert_type(contrib, jnp.int32)
+        out = jax.lax.psum_scatter(
             contrib, self.axis, scatter_dimension=0, tiled=False
         )
+        if wide:
+            out = jax.lax.bitcast_convert_type(out, owned.dtype)
+        return out
 
     # -- halo stat partials -> owned completed stats -------------------
     def complete(self, stats: Array) -> Array:

@@ -16,12 +16,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-try:  # jax is always present in this repo, but keep numpy paths importable
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+import jax
+import jax.numpy as jnp
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
-from repro.compat import set_mesh  # noqa: E402
 from repro.launch.mesh import HW, make_production_mesh  # noqa: E402
 from repro.launch.steps import build_cell, cell_names  # noqa: E402
 from repro.configs import arch_names, get_arch  # noqa: E402
@@ -319,7 +318,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, verbose: bool = True,
             is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
         )
     t0 = time.time()
-    set_mesh(mesh)
+    jax.set_mesh(mesh)
     with mesh:
         jitted = jax.jit(
             prog.fn, in_shardings=in_shardings, out_shardings=out_shardings,

@@ -21,8 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import shard_map
+from jax import shard_map
 
 Array = jax.Array
 

@@ -18,6 +18,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import (
@@ -40,7 +41,6 @@ from repro.analysis import (
     trace_engine,
 )
 from repro.analysis.programs import trace_removal_round
-from repro.compat import shard_map
 
 
 # -- fixtures ---------------------------------------------------------------

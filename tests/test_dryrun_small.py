@@ -15,11 +15,10 @@ _SCRIPT = textwrap.dedent(
     import jax
     from jax.sharding import NamedSharding
 
-    from repro.compat import set_mesh
     from repro.launch.steps import build_cell
 
     mesh = jax.make_mesh((2, 4), ("data", "model"))
-    set_mesh(mesh)
+    jax.set_mesh(mesh)
     cells = [
         ("qwen2-7b", "train_4k"),
         ("deepseek-v2-lite-16b", "decode_32k"),

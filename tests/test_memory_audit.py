@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import (
@@ -26,7 +27,6 @@ from repro.analysis import (
 )
 from repro.analysis.memory import STATE_ARGS, body_arg_map
 from repro.analysis.rules import eval_formula, run_rules
-from repro.compat import shard_map
 
 
 def _mini_traced(config=None, programs=None, donated=None, sizes=None):

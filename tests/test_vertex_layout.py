@@ -31,11 +31,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import cross_check_round, primitive_names
 from repro.analysis.programs import trace_removal_round
-from repro.compat import shard_map
 from repro.core.vertex_layout import (
     HaloShardedVertices,
     ReplicatedVertices,
