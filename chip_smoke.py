@@ -23,17 +23,26 @@ After the stream, each of these is fatal when it fails:
   * one batch program was compiled for the whole stream.
 
 What this size cuts from ``configs/coremaint.py::full()`` (4,847,571
-vertices, 140M slots): 74x fewer vertices and 77x fewer slots. Device
-time forces the cut. The lax path runs every peel wave and every
-fixpoint round as gathers and scatter-adds over the whole slot window,
-and on a TPU v5e those cost tens of nanoseconds per slot and pass. At
-SCALE 20 (1,048,576 vertices, 31.4M slots) one chip took 1,273 s for
-the device peel (628 waves) and 331 s for the first batch, compile
-included; the run did not end within 30 minutes. At SCALE 16 the same
-passes are 17x shorter and the peel has 314 waves. The full size itself
-compiles for one chip at ~10.7 GB of device memory, and its host-side
-setup (generator, oracle) is ROADMAP B1. The oracle, a Python heap
-peel, runs in a thread alongside the device work; it is never skipped.
+vertices, 140M slots): 74x fewer vertices and 77x fewer slots. Each cut
+and what forced it:
+  * SCALE 20 -> 16. At SCALE 20 (1,048,576 vertices, 31.4M slots) one
+    TPU v5e took 1,273 s for the device peel (628 waves) and 331 s for
+    the first batch, compile included, and did not end within 30
+    minutes: the lax path runs every peel wave and every fixpoint round
+    as gathers and scatter-adds over the whole slot window, at tens of
+    nanoseconds per slot and pass. SCALE 17-19 have not been run on a
+    chip; scaling the SCALE 16 and 20 runs (peel ~ slots^1.4, a round ~
+    window slots) puts a cold SCALE 18 run near 10 minutes, so a
+    20-minute budget for the whole smoke does not by itself force
+    SCALE 16. The full size
+    compiles for one chip at ~10.7 GB of device memory; its host-side
+    setup (generator, oracle) is ROADMAP B1.
+  * 50,000 -> 25,000 edges removed and inserted per batch (2^15 lanes
+    instead of 2^16). Graph500 defines no update stream; 50,000 is 0.3%
+    of a SCALE 20 graph's edges, while 25,000 is already 2.7% of this
+    one's, and a second lane bucket would be a second cold compile.
+The oracle, a Python heap peel, runs in a thread alongside the device
+work; it is never skipped.
 
 ``--chips 4`` runs only the multi-chip path, on the same graph and
 stream: ``engine="sharded", vertex_sharding="halo", mesh_shape=(2, 2)``
@@ -53,6 +62,7 @@ from __future__ import annotations
 import argparse
 import gc
 import json
+import math
 import os
 import sys
 import time
@@ -235,6 +245,44 @@ def check_cores(got: np.ndarray, want: np.ndarray, what: str) -> None:
     log(f"{what}: equal at all {want.size} vertices")
 
 
+def batch_program_memory(mt) -> str:
+    """The compiler's memory figures for the batch program the stream
+    ran: lowered again with the same arguments and static sizes, JAX's
+    in-process caches hand back that executable instead of compiling
+    (the line prints how long that took)."""
+    import jax.numpy as jnp
+
+    from repro.core import engine
+
+    lanes = 1 << (BATCH - 1).bit_length()
+    idx, ok = jnp.zeros(lanes, jnp.int32), jnp.zeros(lanes, jnp.bool_)
+    t0 = time.perf_counter()
+    m = engine.apply_batch.lower(
+        mt.src, mt.dst, mt.valid, mt.core, mt.label, mt.n_edges,
+        idx, idx, ok, idx, idx, ok, mt.n, mt.n_levels, mt._last_window,
+        kernel_backend=mt.kernel_backend,
+    ).compile().memory_analysis()
+    return (f"batch program memory_analysis "
+            f"({time.perf_counter() - t0:.3f} s to lower and fetch): "
+            f"argument={m.argument_size_in_bytes} "
+            f"output={m.output_size_in_bytes} "
+            f"alias={m.alias_size_in_bytes} temp={m.temp_size_in_bytes} "
+            f"generated_code={m.generated_code_size_in_bytes}")
+
+
+def live_array_bytes() -> Counter:
+    """Bytes of the live jax arrays held on each device (by device id):
+    what of ``bytes_in_use`` is array state rather than anything else."""
+    import jax
+
+    held: Counter = Counter()
+    for a in jax.live_arrays():
+        shard = math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+        for dev in a.sharding.device_set:
+            held[dev.id] += shard
+    return held
+
+
 def build(g, **engine) -> object:
     from repro.core.api import CoreMaintainer
 
@@ -262,6 +310,7 @@ def one_chip(g, batches, live, oracle, clog) -> None:
     stats = jax.devices()[0].memory_stats() or {}
     log(f"device 0 peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
         f"bytes_in_use={stats.get('bytes_in_use')}")
+    log(batch_program_memory(mt))
     check_state(mt, live, "unified")
     core, secs = oracle.result()
     log(f"oracle bz_from_csr: {secs:.3f} s, max core {int(core.max())}")
@@ -280,10 +329,12 @@ def four_chips(g, batches, live, oracle, clog) -> None:
     mt = build(g, engine="sharded", vertex_sharding="halo", mesh_shape=(2, 2))
     run_stream(mt, batches, "halo(2,2)")
     log(clog.summary())
+    arrays = live_array_bytes()
     for dev in jax.devices():
         stats = dev.memory_stats() or {}
         log(f"device {dev.id} bytes_in_use={stats.get('bytes_in_use')} "
-            f"peak_bytes_in_use={stats.get('peak_bytes_in_use')}")
+            f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
+            f"live_array_bytes={arrays[dev.id]}")
     check_state(mt, live, "halo(2,2)")
     check_cores(mt.cores(), ref_core, "halo(2,2) cores vs unified")
     check_cores(mt.labels(), ref_label, "halo(2,2) labels vs unified")

@@ -353,18 +353,14 @@ class HaloSession:
         safe, mine = self._owner_rows()
         contrib = jnp.where(mine, owned[safe], jnp.zeros((), owned.dtype))
         _note("regather", self.halo_cap * owned.dtype.itemsize)
-        wide = owned.dtype.itemsize == 8
-        if wide:
-            # the TPU compiler has no 64-bit reduce_scatter: ship the two
-            # 32-bit words instead — each row has one nonzero contributor,
-            # so each word's sum is that contributor's word, exactly
-            contrib = jax.lax.bitcast_convert_type(contrib, jnp.int32)
+        # the TPU compiler has no 64-bit reduce_scatter: ship 32-bit words
+        # (a no-op for int32, two words per int64) — each row has one
+        # nonzero contributor, so each word's sum is that word, exactly
         out = jax.lax.psum_scatter(
-            contrib, self.axis, scatter_dimension=0, tiled=False
+            jax.lax.bitcast_convert_type(contrib, jnp.int32),
+            self.axis, scatter_dimension=0, tiled=False,
         )
-        if wide:
-            out = jax.lax.bitcast_convert_type(out, owned.dtype)
-        return out
+        return jax.lax.bitcast_convert_type(out, owned.dtype)
 
     # -- halo stat partials -> owned completed stats -------------------
     def complete(self, stats: Array) -> Array:

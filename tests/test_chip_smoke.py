@@ -46,6 +46,7 @@ def test_smoke_one_chip_path_runs_on_cpu(smoke, monkeypatch, capsys):
     assert "unified cores vs oracle: equal" in text
     assert "certificate dout <= core holds" in text
     assert "batch programs compiled: 1" in text
+    assert "batch program memory_analysis (" in text
 
 
 def test_smoke_stream_protocol(smoke):
@@ -98,3 +99,4 @@ def test_smoke_four_chip_path_runs_on_cpu(tmp_path):
     assert "halo(2,2) labels vs unified: equal" in text
     assert "unified cores vs oracle: equal" in text
     assert text.count("bytes_in_use=") >= 4
+    assert text.count("live_array_bytes=") == 4
