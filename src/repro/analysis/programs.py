@@ -302,7 +302,7 @@ def trace_promotion_round(
             in_specs=(espec, espec, espec, P(axis), P(axis),
                       P(), P(), P(), P(axis), P(axis)),
             out_specs=(P(axis), P(axis), P(), P(), P(),
-                       P(axis), P(), P()),
+                       P(axis), P(), P(), P(), P()),
             check_vma=False,
         )
         src = jnp.zeros(cap, jnp.int32)
@@ -332,7 +332,7 @@ def trace_promotion_round(
         kernel, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(), P(),
                   P(), P(), P(), P(), P()),
-        out_specs=(P(), P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P(), P(), P()),
         check_vma=False,
     )
     src = jnp.zeros(cap, jnp.int32)

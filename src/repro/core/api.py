@@ -71,13 +71,15 @@ the per-vertex stat scatters (which would clamp it onto vertex n-1).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import warnings
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..graph.csr import CSRGraph, build_csr
 from .decomposition import peel_decomposition, rank_to_labels
@@ -93,6 +95,38 @@ from .sharded import make_sharded_apply
 EDGE_AXIS = "data"  # mesh axis the sharded engine shards edge slots over
 
 _ENGINES = ("unified", "host", "sharded")
+
+
+class BatchCall(NamedTuple):
+    """One ``CoreMaintainer.apply_batch`` call as a profiler reads it
+    after a run: its ``BatchStats`` (the round and wave counters) and
+    the device program it dispatched, by its arguments' shapes."""
+
+    stats: BatchStats
+    program: Optional[Callable] = None  # None: host engine, empty batch
+    args: tuple = ()  # ShapeDtypeStructs, then the static arguments
+    kwargs: Optional[dict] = None
+
+    def compiled_text(self) -> Optional[str]:
+        """The optimized HLO text of the program this call ran; each
+        instruction's ``op_name`` carries the ``coremaint.*`` scope of
+        its phase. Lowers and compiles the program again, or finds it
+        in JAX's cache: a step for after a run, not for the hot path."""
+        if self.program is None:
+            return None
+        return self.program.lower(*self.args, **(self.kwargs or {})) \
+            .compile().as_text()
+
+
+# the last calls of apply_batch in this process, oldest first
+RECENT_CALLS: Deque[BatchCall] = collections.deque(maxlen=64)
+
+
+def _shape_of(x):
+    # as jit keys its cache: an uncommitted array's placement is not
+    # part of the key, so a later lowering finds the compiled program
+    sharding = x.sharding if getattr(x, "committed", True) else None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
 def _pow2_roundup(need: int) -> int:
@@ -291,6 +325,9 @@ class CoreMaintainer:
                                             repr=False)
     _frontier_hist: list = dataclasses.field(default_factory=list,
                                              repr=False)
+    # sequence number of the next apply_batch call: the ``batch=`` tag
+    # every host span of one call carries in a profile
+    _batch_seq: int = dataclasses.field(default=0, repr=False)
 
     def __post_init__(self) -> None:
         # the FULL engine-configuration matrix is validated here, at
@@ -813,171 +850,186 @@ class CoreMaintainer:
         row-for-row with ``insert_edges``; omitted means weight 1 per
         edge. Duplicate rows keep the FIRST occurrence's weight, and
         inserting an already-live edge is a no-op that keeps the stored
-        weight — remove + insert updates a weight."""
+        weight — remove + insert updates a weight.
+
+        Each call is one ``coremaint.apply_batch`` profiler span with
+        children ``validate``, ``pad``, ``plan_window``, ``transfer``
+        and ``dispatch``, all tagged ``batch=<call number>``; they cost
+        a flag check while no profile is being taken."""
         _require_x64()
-        if insert_weights is not None and not self.weighted:
-            raise ValueError(
-                "insert_weights= needs weighted=True — the unweighted "
-                "engines would silently drop the weights"
-            )
-        # validate BOTH lists before any engine touches state, so a
-        # rejected batch is rejected atomically (the host path applies
-        # removals first and must not commit them before the insert list
-        # has passed validation)
-        if self.weighted:
-            ins_np = _as_edge_array(insert_edges)
-            if insert_weights is None:
-                insert_weights = np.ones(ins_np.shape[0], dtype=np.int64)
-            ins, ins_wts = self._validated(insert_edges, "insert",
-                                           weights=insert_weights)
-        else:
-            ins = self._validated(insert_edges, "insert")
-        rm = self._validated(remove_edges, "remove")
-        if self.engine == "host":
-            n_live0 = self.live_edges
-            rm_st = self._remove_edges_host(rm)
-            n_live1 = self.live_edges
-            renumbered = self.host_renumbered
-            in_st = self._insert_edges_host(ins)
-            renumbered = renumbered or self.host_renumbered
-            stats = BatchStats(
-                n_inserted=jnp.int32(self.live_edges - n_live1),
-                n_removed=jnp.int32(n_live0 - n_live1),
-                insert_rounds=in_st.rounds,
-                n_promoted=in_st.n_promoted,
-                v_plus=in_st.v_plus,
-                remove_rounds=rm_st.rounds,
-                n_dropped=rm_st.n_dropped,
-                renumbered=jnp.bool_(renumbered),
-                n_recycled=jnp.int32(0),  # host path reclaims via _compact
-                high_water=self.n_edges,  # == the host bump pointer
-                max_frontier=jnp.maximum(in_st.max_frontier,
-                                         rm_st.max_frontier),
-                n_overflow=jnp.int32(0),  # host path has no halo exchange
-            )
-            self.last_batch_stats = stats
-            return stats
-        b_ins = ins.shape[0]
-        if b_ins == 0 and rm.shape[0] == 0:
-            z = jnp.int32(0)
-            stats = BatchStats(z, z, z, z, z, z, z, jnp.bool_(False), z,
-                               jnp.int32(self.hwm_ub), z, z)
-            self.last_batch_stats = stats
-            return stats
-        self._ensure_capacity(b_ins)
-        iu = _pad_pow2(ins[:, 0], 0)
-        iv = _pad_pow2(ins[:, 1], 0)
-        iok = np.zeros(len(iu), dtype=bool)
-        iok[:b_ins] = True
-        ru = _pad_pow2(rm[:, 0], 0)
-        rv = _pad_pow2(rm[:, 1], 0)
-        rok = np.zeros(len(ru), dtype=bool)
-        rok[: rm.shape[0]] = True
-        if self.weighted:
-            # padded lanes carry weight 1, but iok=False keeps them out
-            # of the slot writes and the total-weight promotion bound
-            iw = _pad_pow2(ins_wts.astype(np.int32), 1)
-            args = (
-                self.src,
-                self.dst,
-                self.valid,
-                self.w,
-                self.core,
-                self.label,
-                self.n_edges,
-                jnp.asarray(iu),
-                jnp.asarray(iv),
-                jnp.asarray(iw),
-                jnp.asarray(iok),
-                jnp.asarray(ru),
-                jnp.asarray(rv),
-                jnp.asarray(rok),
-            )
-        else:
-            args = (
-                self.src,
-                self.dst,
-                self.valid,
-                self.core,
-                self.label,
-                self.n_edges,
-                jnp.asarray(iu),
-                jnp.asarray(iv),
-                jnp.asarray(iok),
-                jnp.asarray(ru),
-                jnp.asarray(rv),
-                jnp.asarray(rok),
-            )
-        # static pow2 bound on the per-shard slot high-water mark incl.
-        # this batch: every edge pass runs over this per-shard slot
-        # prefix only, and (because the free-list allocator fills the
-        # lowest holes first) the window always contains >= b_ins free
-        # slots per shard — so the in-program recycler can never run dry
-        window = self._window(b_ins)
-        if 0 < self._last_window < window:
-            # the bucket would grow — but hwm_ub is the conservative
-            # march, not the truth. Refresh the exact device bounds (one
-            # amortized sync) before paying a recompile + wider passes:
-            # under balanced churn the true high-water mark is flat and
-            # the bucket never actually grows
-            self._refresh_bounds()
-            window = self._window(b_ins)
-        self._last_window = window
-        with warnings.catch_warnings():
-            # donation is declared for accelerator backends; backends
-            # without buffer aliasing (CPU) warn and copy instead
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable"
-            )
-            if self.engine == "sharded":
-                # the per-shard window is sliced INSIDE the shard_map
-                # kernel (slicing the sharded buffer here would reshard);
-                # the sparse frontier cap is a second static bucket keyed
-                # off the padded batch size (0 = exchange off)
-                fcap = self._frontier_bucket(max(len(iu), len(ru)))
-                out = self._get_sharded_fn(window, fcap)(*args)
-            elif self.weighted:
-                out = apply_batch_weighted(
-                    *args, self.n, self.n_levels, window,
-                    kernel_backend=self.kernel_backend)
+        seq = self._batch_seq
+        self._batch_seq += 1
+
+        def span(name):
+            return TraceAnnotation(f"coremaint.{name}", batch=seq)
+
+        with span("apply_batch"):
+            with span("validate"):
+                if insert_weights is not None and not self.weighted:
+                    raise ValueError(
+                        "insert_weights= needs weighted=True — the "
+                        "unweighted engines would silently drop the weights"
+                    )
+                # validate BOTH lists before any engine touches state, so
+                # a rejected batch is rejected atomically (the host path
+                # applies removals first and must not commit them before
+                # the insert list has passed validation)
+                if self.weighted:
+                    ins_np = _as_edge_array(insert_edges)
+                    if insert_weights is None:
+                        insert_weights = np.ones(ins_np.shape[0],
+                                                 dtype=np.int64)
+                    ins, ins_wts = self._validated(insert_edges, "insert",
+                                                   weights=insert_weights)
+                else:
+                    ins = self._validated(insert_edges, "insert")
+                rm = self._validated(remove_edges, "remove")
+            if self.engine == "host":
+                with span("dispatch"):
+                    n_live0 = self.live_edges
+                    rm_st = self._remove_edges_host(rm)
+                    n_live1 = self.live_edges
+                    renumbered = self.host_renumbered
+                    in_st = self._insert_edges_host(ins)
+                    renumbered = renumbered or self.host_renumbered
+                stats = BatchStats(
+                    n_inserted=jnp.int32(self.live_edges - n_live1),
+                    n_removed=jnp.int32(n_live0 - n_live1),
+                    insert_rounds=in_st.rounds,
+                    n_promoted=in_st.n_promoted,
+                    v_plus=in_st.v_plus,
+                    remove_rounds=rm_st.rounds,
+                    n_dropped=rm_st.n_dropped,
+                    renumbered=jnp.bool_(renumbered),
+                    # the host path reclaims via _compact and has no
+                    # halo exchange
+                    n_recycled=jnp.int32(0),
+                    high_water=self.n_edges,  # == the host bump pointer
+                    max_frontier=jnp.maximum(in_st.max_frontier,
+                                             rm_st.max_frontier),
+                    n_overflow=jnp.int32(0),
+                    forward_waves=in_st.forward_waves,
+                    evict_waves=in_st.evict_waves,
+                )
+                self.last_batch_stats = stats
+                RECENT_CALLS.append(BatchCall(stats))
+                return stats
+            b_ins = ins.shape[0]
+            if b_ins == 0 and rm.shape[0] == 0:
+                z = jnp.int32(0)
+                stats = BatchStats(
+                    n_inserted=z, n_removed=z, insert_rounds=z,
+                    n_promoted=z, v_plus=z, remove_rounds=z, n_dropped=z,
+                    renumbered=jnp.bool_(False), n_recycled=z,
+                    high_water=jnp.int32(self.hwm_ub), max_frontier=z,
+                    n_overflow=z, forward_waves=z, evict_waves=z,
+                )
+                self.last_batch_stats = stats
+                RECENT_CALLS.append(BatchCall(stats))
+                return stats
+            with span("pad"):
+                iu = _pad_pow2(ins[:, 0], 0)
+                iv = _pad_pow2(ins[:, 1], 0)
+                iok = np.zeros(len(iu), dtype=bool)
+                iok[:b_ins] = True
+                ru = _pad_pow2(rm[:, 0], 0)
+                rv = _pad_pow2(rm[:, 1], 0)
+                rok = np.zeros(len(ru), dtype=bool)
+                rok[: rm.shape[0]] = True
+                # padded lanes carry weight 1, but iok=False keeps them
+                # out of the slot writes and the total-weight promotion
+                # bound
+                iw = (_pad_pow2(ins_wts.astype(np.int32), 1)
+                      if self.weighted else None)
+            with span("plan_window"):
+                # may re-lay the table (defrag): before the state is read
+                self._ensure_capacity(b_ins)
+                # static pow2 bound on the per-shard slot high-water mark
+                # incl. this batch: every edge pass runs over this
+                # per-shard slot prefix only, and (because the free-list
+                # allocator fills the lowest holes first) the window
+                # always contains >= b_ins free slots per shard — so the
+                # in-program recycler can never run dry
+                window = self._window(b_ins)
+                if 0 < self._last_window < window:
+                    # the bucket would grow — but hwm_ub is the
+                    # conservative march, not the truth. Refresh the exact
+                    # device bounds (one amortized sync) before paying a
+                    # recompile + wider passes: under balanced churn the
+                    # true high-water mark is flat and the bucket never
+                    # actually grows
+                    self._refresh_bounds()
+                    window = self._window(b_ins)
+                self._last_window = window
+            with span("transfer"):
+                lanes = (iu, iv, iw, iok, ru, rv, rok) if self.weighted \
+                    else (iu, iv, iok, ru, rv, rok)
+                state = (self.src, self.dst, self.valid) + (
+                    (self.w,) if self.weighted else ()
+                ) + (self.core, self.label, self.n_edges)
+                args = state + tuple(jnp.asarray(x) for x in lanes)
+            with span("dispatch"), warnings.catch_warnings():
+                # donation is declared for accelerator backends; backends
+                # without buffer aliasing (CPU) warn and copy instead
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable"
+                )
+                if self.engine == "sharded":
+                    # the per-shard window is sliced INSIDE the shard_map
+                    # kernel (slicing the sharded buffer here would
+                    # reshard); the sparse frontier cap is a second static
+                    # bucket keyed off the padded batch size (0 = exchange
+                    # off)
+                    fcap = self._frontier_bucket(max(len(iu), len(ru)))
+                    program = self._get_sharded_fn(window, fcap)
+                    static, kwargs = (), {}
+                else:
+                    program = (apply_batch_weighted if self.weighted
+                               else apply_batch)
+                    static = (self.n, self.n_levels, window)
+                    kwargs = dict(kernel_backend=self.kernel_backend)
+                # the shapes before the call: it donates the state
+                shapes = tuple(_shape_of(x) for x in args) + static
+                out = program(*args, *static, **kwargs)
+            if self.weighted:
+                (
+                    self.src,
+                    self.dst,
+                    self.valid,
+                    self.w,
+                    self.core,
+                    self.label,
+                    self.n_edges,
+                    stats,
+                ) = out
             else:
-                out = apply_batch(*args, self.n, self.n_levels, window,
-                                  kernel_backend=self.kernel_backend)
-        if self.weighted:
-            (
-                self.src,
-                self.dst,
-                self.valid,
-                self.w,
-                self.core,
-                self.label,
-                self.n_edges,
-                stats,
-            ) = out
-        else:
-            (
-                self.src,
-                self.dst,
-                self.valid,
-                self.core,
-                self.label,
-                self.n_edges,
-                stats,
-            ) = out
-        # monotone sync-free bounds: each insert can raise the densest
-        # shard's high-water mark by at most one (holes fill first), and
-        # the live count by at most one; removals only help. The exact
-        # values (stats.high_water / n_edges) are re-read only when
-        # planning crosses the capacity threshold (_refresh_bounds).
-        self.hwm_ub = min(self.hwm_ub + b_ins, self._local_cap)
-        self.live_ub = min(self.live_ub + b_ins, self.capacity)
-        self.slot_cache = None
-        self.last_batch_stats = stats
-        if self.frontier_exchange == "sparse" and self.frontier_cap == 0:
-            # queue the device scalar for the sync-free observed-quantile
-            # harvest (_observed_frontier) that seeds future cap buckets
-            self._frontier_obs.append(stats.max_frontier)
-        return stats
+                (
+                    self.src,
+                    self.dst,
+                    self.valid,
+                    self.core,
+                    self.label,
+                    self.n_edges,
+                    stats,
+                ) = out
+            # monotone sync-free bounds: each insert can raise the
+            # densest shard's high-water mark by at most one (holes fill
+            # first), and the live count by at most one; removals only
+            # help. The exact values (stats.high_water / n_edges) are
+            # re-read only when planning crosses the capacity threshold
+            # (_refresh_bounds).
+            self.hwm_ub = min(self.hwm_ub + b_ins, self._local_cap)
+            self.live_ub = min(self.live_ub + b_ins, self.capacity)
+            self.slot_cache = None
+            self.last_batch_stats = stats
+            RECENT_CALLS.append(BatchCall(stats, program, shapes, kwargs))
+            if (self.frontier_exchange == "sparse"
+                    and self.frontier_cap == 0):
+                # queue the device scalar for the sync-free
+                # observed-quantile harvest (_observed_frontier) that
+                # seeds future cap buckets
+                self._frontier_obs.append(stats.max_frontier)
+            return stats
 
     def insert_edges(self, edges: np.ndarray,
                      weights: Optional[np.ndarray] = None) -> InsertStats:
@@ -993,6 +1045,8 @@ class CoreMaintainer:
             n_promoted=st.n_promoted,
             v_plus=st.v_plus,
             max_frontier=st.max_frontier,
+            forward_waves=st.forward_waves,
+            evict_waves=st.evict_waves,
         )
         return self.last_insert_stats
 
@@ -1023,8 +1077,10 @@ class CoreMaintainer:
             keep.append(key)
         if not keep:
             self.last_insert_stats = None
-            return InsertStats(jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                               jnp.int32(0))
+            z = jnp.int32(0)
+            return InsertStats(rounds=z, n_promoted=z, v_plus=z,
+                               max_frontier=z, forward_waves=z,
+                               evict_waves=z)
         arr = np.asarray(keep, dtype=np.int32)
         if int(self.n_edges) + arr.shape[0] + 1 >= self.capacity:
             self._compact()  # replaces slot_cache — re-read below
@@ -1111,9 +1167,12 @@ class CoreMaintainer:
         Under balanced churn the exact high-water mark is flat (the
         free-list recycles every tombstone), so this usually reveals
         plenty of headroom and no defrag or growth happens at all."""
-        if self.last_batch_stats is not None:
-            self.hwm_ub = int(self.last_batch_stats.high_water)
-        self.live_ub = int(self.n_edges)
+        # called from apply_batch only: the span joins that call's tag
+        with TraceAnnotation("coremaint.refresh_bounds",
+                             batch=self._batch_seq - 1):
+            if self.last_batch_stats is not None:
+                self.hwm_ub = int(self.last_batch_stats.high_water)
+            self.live_ub = int(self.n_edges)
 
     def _ensure_capacity(self, b_ins: int) -> None:
         """Make the per-shard window able to hold the live slots plus this
@@ -1132,7 +1191,8 @@ class CoreMaintainer:
         # after a balanced defrag the densest shard holds ceil(live / nd)
         while -(-self.live_ub // nd) + b_ins + 1 >= new_cap // nd:
             new_cap = max(new_cap * 2, new_cap + nd * (2 * b_ins + 16))
-        self._defrag_to(new_cap)
+        with TraceAnnotation("coremaint.defrag", batch=self._batch_seq - 1):
+            self._defrag_to(new_cap)
 
     def _defrag_to(self, new_cap: int) -> None:
         """Repack live slots into a balanced layout at ``new_cap`` total

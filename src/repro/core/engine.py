@@ -27,6 +27,12 @@ buffer copies. ``apply_batch`` moves all of it on-device:
                the program (order.maybe_renumber): no dedicated
                device->host sync, and the flag is reported in the stats.
 
+Each phase runs under a ``jax.named_scope`` with the ``coremaint.``
+prefix (``table``, ``remove.stats``, ``promote.seed``,
+``promote.forward``, ``promote.evict``, ``promote.stats``, ``labels``),
+which reaches every compiled instruction's ``op_name``: a device profile
+splits the program's time by phase. Scopes are metadata only.
+
 ``src``/``dst``/``valid``/``core``/``label``/``n_edges`` are donated, so
 each batch updates the edge table in place instead of copying O(capacity)
 arrays (donation is a no-op on backends without buffer aliasing, e.g.
@@ -98,6 +104,11 @@ class BatchStats(NamedTuple):
     n_overflow: Array      # sparse exchanges that fell back dense (halo) /
     #                        bitmask (0 outside the sparse regimes) — the
     #                        observed-cap planner's tuning datum (§4.3)
+    forward_waves: Array   # FORWARD wave iterations over all promotion
+    #                        rounds (0 in weighted mode)
+    evict_waves: Array     # EVICT iterations over all promotion rounds;
+    #                        a batch's passes over the slot table are
+    #                        remove_rounds + insert_rounds + both waves
 
 
 def edge_key(lo: Array, hi: Array, n: int) -> Array:
@@ -231,134 +242,158 @@ def batch_program(
     def allsum(x):
         return x if axis is None else jax.lax.psum(x, axis)
 
-    # pre-batch LOCAL high-water mark: inserts landing below it reclaimed
-    # a tombstone (the n_recycled statistic)
-    hwm0 = G.slot_high_water(valid)
+    with jax.named_scope("coremaint.table"):
+        # pre-batch LOCAL high-water mark: inserts landing below it
+        # reclaimed a tombstone (the n_recycled statistic)
+        hwm0 = G.slot_high_water(valid)
 
-    # one sorted view of the (local) table serves BOTH the removal slot
-    # lookup and the insert membership test
-    lookup = table_lookup(src, dst, valid, n)
+        # one sorted view of the (local) table serves BOTH the removal
+        # slot lookup and the insert membership test
+        lookup = table_lookup(src, dst, valid, n)
 
-    # ---- 1. removals: vectorized slot lookup + tombstoning ---------------
-    rlo = jnp.minimum(rm_u, rm_v)
-    rhi = jnp.maximum(rm_u, rm_v)
-    rm_ok = rm_ok & (rlo != rhi)
-    rfound, rslot = lookup(edge_key(rlo, rhi, n))
-    found = rfound & rm_ok
-    # commutative scatter-max: not-found rows are no-ops; each device
-    # tombstones only its own slots
-    rm_mask = jnp.zeros(capacity, dtype=bool).at[rslot].max(found)
-    valid = valid & ~rm_mask
-    n_removed = allsum(jnp.sum(rm_mask, dtype=jnp.int32))
+        # ---- 1. removals: vectorized slot lookup + tombstoning -----------
+        rlo = jnp.minimum(rm_u, rm_v)
+        rhi = jnp.maximum(rm_u, rm_v)
+        rm_ok = rm_ok & (rlo != rhi)
+        rfound, rslot = lookup(edge_key(rlo, rhi, n))
+        found = rfound & rm_ok
+        # commutative scatter-max: not-found rows are no-ops; each device
+        # tombstones only its own slots
+        rm_mask = jnp.zeros(capacity, dtype=bool).at[rslot].max(found)
+        valid = valid & ~rm_mask
+        n_removed = allsum(jnp.sum(rm_mask, dtype=jnp.int32))
 
     core_pre_rm = core
-    if w is not None:
-        core, rm_rounds, rm_fmax = weighted_core_fixpoint_pass(
-            src, dst, valid, w, core, n, layout=layout,
-            kernel_backend=kernel_backend,
-        )
-        hi = dout_same = layout.zeros()
-    else:
-        core, label, rm_rounds, hi, dout_same, rm_fmax = removal_fixpoint(
-            src, dst, valid, core, label, n, n_levels, layout=layout,
-            kernel_backend=kernel_backend,
-        )
-    n_dropped = jnp.sum(core != core_pre_rm, dtype=jnp.int32)
+    with jax.named_scope("coremaint.remove.stats"):
+        if w is not None:
+            core, rm_rounds, rm_fmax = weighted_core_fixpoint_pass(
+                src, dst, valid, w, core, n, layout=layout,
+                kernel_backend=kernel_backend,
+            )
+            hi = dout_same = layout.zeros()
+        else:
+            (core, label, rm_rounds, hi, dout_same,
+             rm_fmax) = removal_fixpoint(
+                src, dst, valid, core, label, n, n_levels, layout=layout,
+                kernel_backend=kernel_backend,
+            )
+        n_dropped = jnp.sum(core != core_pre_rm, dtype=jnp.int32)
 
-    # ---- 2. insert dedup + membership against the post-removal table ----
-    ilo, ihi, iok, key = batch_dedup(ins_u, ins_v, ins_ok, n)
-    # membership against the POST-removal table: the sorted view predates
-    # the tombstoning, so mask out slots removed in step 1 — this is what
-    # lets an edge removed and re-inserted in the same batch round-trip
-    ifound, islot_hit = lookup(key)
-    exists = allsum((ifound & ~rm_mask[islot_hit]).astype(jnp.int32)) > 0
-    iok = iok & ~exists
+    with jax.named_scope("coremaint.table"):
+        # ---- 2. insert dedup + membership against the post-removal table
+        ilo, ihi, iok, key = batch_dedup(ins_u, ins_v, ins_ok, n)
+        # membership against the POST-removal table: the sorted view
+        # predates the tombstoning, so mask out slots removed in step 1 —
+        # this is what lets an edge removed and re-inserted in the same
+        # batch round-trip
+        ifound, islot_hit = lookup(key)
+        exists = allsum(
+            (ifound & ~rm_mask[islot_hit]).astype(jnp.int32)
+        ) > 0
+        iok = iok & ~exists
 
-    # ---- 3. batch slot allocation from the free-list: dead slots (the
-    # step-1 tombstones included) are ranked lowest-local-index-first,
-    # interleaved across shards, and the batch cumsum assigns insert
-    # rank r to the r-th free slot; each device writes the ranks landing
-    # in its own shard and drops the rest (masked lanes included) via
-    # out-of-bounds scatter semantics. The host guarantees enough free
-    # slots in the active window (api.py), so the slot table recycles
-    # tombstones without ever syncing.
-    lpos, iok = freelist_alloc(valid, iok, axis=axis,
-                               hierarchical=(freelist == "hierarchical"))
-    src = src.at[lpos].set(ilo.astype(src.dtype), mode="drop")
-    dst = dst.at[lpos].set(ihi.astype(dst.dtype), mode="drop")
-    valid = valid.at[lpos].set(True, mode="drop")
-    if w is not None:
-        # the weight column rides the same allocation: dedup's stable
-        # argsort keeps the FIRST occurrence of an in-batch duplicate,
-        # so that lane's weight is the one written; re-inserting a live
-        # edge was masked by the membership test above (old weight kept)
-        w = w.at[lpos].set(ins_w.astype(w.dtype), mode="drop")
-    n_inserted = jnp.sum(iok, dtype=jnp.int32)
-    n_recycled = allsum(jnp.sum(lpos < hwm0, dtype=jnp.int32))
-    # n_edges is the LIVE edge count (not a bump pointer): removals and
-    # insertions both land in it, so it tracks the paper's workload size
-    n_edges = n_edges - n_removed + n_inserted
+        # ---- 3. batch slot allocation from the free-list: dead slots
+        # (the step-1 tombstones included) are ranked
+        # lowest-local-index-first, interleaved across shards, and the
+        # batch cumsum assigns insert rank r to the r-th free slot; each
+        # device writes the ranks landing in its own shard and drops the
+        # rest (masked lanes included) via out-of-bounds scatter
+        # semantics. The host guarantees enough free slots in the active
+        # window (api.py), so the slot table recycles tombstones without
+        # ever syncing.
+        lpos, iok = freelist_alloc(valid, iok, axis=axis,
+                                   hierarchical=(freelist == "hierarchical"))
+        src = src.at[lpos].set(ilo.astype(src.dtype), mode="drop")
+        dst = dst.at[lpos].set(ihi.astype(dst.dtype), mode="drop")
+        valid = valid.at[lpos].set(True, mode="drop")
+        if w is not None:
+            # the weight column rides the same allocation: dedup's stable
+            # argsort keeps the FIRST occurrence of an in-batch duplicate,
+            # so that lane's weight is the one written; re-inserting a
+            # live edge was masked by the membership test above (old
+            # weight kept)
+            w = w.at[lpos].set(ins_w.astype(w.dtype), mode="drop")
+        n_inserted = jnp.sum(iok, dtype=jnp.int32)
+        n_recycled = allsum(jnp.sum(lpos < hwm0, dtype=jnp.int32))
+        # n_edges is the LIVE edge count (not a bump pointer): removals
+        # and insertions both land in it, so it tracks the paper's
+        # workload size
+        n_edges = n_edges - n_removed + n_inserted
 
     core_pre_ins = core
     if w is not None:
-        # total inserted batch weight: iok is a replicated verdict under
-        # sharding (freelist_alloc narrows it from all-gathered counts),
-        # so the sum needs no collective
-        total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
-        core, ins_rounds, ins_fmax = weighted_promotion_fixpoint(
-            src, dst, valid, w, core, total_w, n, layout=layout,
-            kernel_backend=kernel_backend,
-        )
-        v_plus = core != core_pre_ins
+        with jax.named_scope("coremaint.promote.stats"):
+            # total inserted batch weight: iok is a replicated verdict
+            # under sharding (freelist_alloc narrows it from all-gathered
+            # counts), so the sum needs no collective
+            total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
+            core, ins_rounds, ins_fmax = weighted_promotion_fixpoint(
+                src, dst, valid, w, core, total_w, n, layout=layout,
+                kernel_backend=kernel_backend,
+            )
+            v_plus = core != core_pre_ins
+        fwd_waves = ev_waves = jnp.int32(0)
     else:
-        # O(batch) delta keeps the shared (hi, dout_same) statistics
-        # exact for the table with the new edges — same per-edge
-        # predicate as the full passes (graph_ops.hi_dout_indicators);
-        # the batch is replicated under sharding, so the delta needs no
-        # collective (a range-sharded layout scatters each row into its
-        # owner's slice and drops the rest OOB)
-        hi_u, hi_v, do_u, do_v = G.hi_dout_indicators(
-            core, label, ilo, ihi, iok
-        )
-        hi = layout.add_at(hi, ilo, hi_u.astype(jnp.int32))
-        hi = layout.add_at(hi, ihi, hi_v.astype(jnp.int32))
-        dout_same = layout.add_at(dout_same, ilo, do_u.astype(jnp.int32))
-        dout_same = layout.add_at(dout_same, ihi, do_v.astype(jnp.int32))
+        with jax.named_scope("coremaint.promote.seed"):
+            # O(batch) delta keeps the shared (hi, dout_same) statistics
+            # exact for the table with the new edges — same per-edge
+            # predicate as the full passes (graph_ops.hi_dout_indicators);
+            # the batch is replicated under sharding, so the delta needs
+            # no collective (a range-sharded layout scatters each row
+            # into its owner's slice and drops the rest OOB)
+            hi_u, hi_v, do_u, do_v = G.hi_dout_indicators(
+                core, label, ilo, ihi, iok
+            )
+            hi = layout.add_at(hi, ilo, hi_u.astype(jnp.int32))
+            hi = layout.add_at(hi, ihi, hi_v.astype(jnp.int32))
+            dout_same = layout.add_at(dout_same, ilo,
+                                      do_u.astype(jnp.int32))
+            dout_same = layout.add_at(dout_same, ihi,
+                                      do_v.astype(jnp.int32))
 
-        core, label, ins_rounds, v_plus, ins_fmax = promotion_fixpoint(
+        (core, label, ins_rounds, v_plus, ins_fmax, fwd_waves,
+         ev_waves) = promotion_fixpoint(
             src, dst, valid, core, label, ilo, ihi, iok,
             hi, dout_same, n, n_levels, layout=layout,
             kernel_backend=kernel_backend,
         )
-    n_promoted = jnp.sum(core != core_pre_ins, dtype=jnp.int32)
+    with jax.named_scope("coremaint.promote.stats"):
+        n_promoted = jnp.sum(core != core_pre_ins, dtype=jnp.int32)
+        n_v_plus = jnp.sum(v_plus, dtype=jnp.int32)
+        # observed peak per-shard frontier across both fixpoints — the
+        # datum the sparse frontier_cap planner is tuned from (§4.3)
+        max_frontier = jnp.maximum(rm_fmax, ins_fmax)
+        # weighted mode froze the labels through both fixpoints (no
+        # bucketed place_block — weighted levels are unbounded in maxW),
+        # so it forces ONE bucket-free relabel whenever any core moved;
+        # force=None keeps the unweighted gate byte-identical
+        force = ((n_dropped > 0) | (n_promoted > 0)) if w is not None \
+            else None
 
     # ---- 4. in-program renumber gate (no host sync) ----------------------
-    # weighted mode froze the labels through both fixpoints (no bucketed
-    # place_block — weighted levels are unbounded in maxW), so it forces
-    # ONE bucket-free relabel whenever any core moved; force=None keeps
-    # the unweighted gate byte-identical
-    force = ((n_dropped > 0) | (n_promoted > 0)) if w is not None else None
     label, renumbered = maybe_renumber(core, label, force=force)
 
+    with jax.named_scope("coremaint.table"):
+        # exact post-batch bound the host refreshes its sync-free window
+        # planning from (max over shards of the LOCAL high-water mark)
+        high_water = G.slot_high_water(valid, axis)
     stats = BatchStats(
         n_inserted=n_inserted,
         n_removed=n_removed,
         insert_rounds=ins_rounds,
         n_promoted=n_promoted,
-        v_plus=jnp.sum(v_plus, dtype=jnp.int32),
+        v_plus=n_v_plus,
         remove_rounds=rm_rounds,
         n_dropped=n_dropped,
         renumbered=renumbered,
         n_recycled=n_recycled,
-        # exact post-batch bound the host refreshes its sync-free window
-        # planning from (max over shards of the LOCAL high-water mark)
-        high_water=G.slot_high_water(valid, axis),
-        # observed peak per-shard frontier across both fixpoints — the
-        # datum the sparse frontier_cap planner is tuned from (§4.3)
-        max_frontier=jnp.maximum(rm_fmax, ins_fmax),
+        high_water=high_water,
+        max_frontier=max_frontier,
         # the replicated/range paths have no per-round sparse halo
         # refresh; overflow rounds exist only in the halo program below
         n_overflow=jnp.int32(0),
+        forward_waves=fwd_waves,
+        evict_waves=ev_waves,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats
@@ -458,128 +493,147 @@ def batch_program_halo(
     def vsum(x):    # owned-vertex domain: owner axis only
         return jax.lax.psum(x, layout.axis)
 
-    hwm0 = G.slot_high_water(valid)
-    lookup = table_lookup(src, dst, valid, n)
+    with jax.named_scope("coremaint.table"):
+        hwm0 = G.slot_high_water(valid)
+        lookup = table_lookup(src, dst, valid, n)
 
-    # ---- 1. removals: vectorized slot lookup + tombstoning ---------------
-    rlo = jnp.minimum(rm_u, rm_v)
-    rhi = jnp.maximum(rm_u, rm_v)
-    rm_ok = rm_ok & (rlo != rhi)
-    rfound, rslot = lookup(edge_key(rlo, rhi, n))
-    found = rfound & rm_ok
-    rm_mask = jnp.zeros(capacity, dtype=bool).at[rslot].max(found)
-    valid = valid & ~rm_mask
-    n_removed = allsum(jnp.sum(rm_mask, dtype=jnp.int32))
+        # ---- 1. removals: vectorized slot lookup + tombstoning -----------
+        rlo = jnp.minimum(rm_u, rm_v)
+        rhi = jnp.maximum(rm_u, rm_v)
+        rm_ok = rm_ok & (rlo != rhi)
+        rfound, rslot = lookup(edge_key(rlo, rhi, n))
+        found = rfound & rm_ok
+        rm_mask = jnp.zeros(capacity, dtype=bool).at[rslot].max(found)
+        valid = valid & ~rm_mask
+        n_removed = allsum(jnp.sum(rm_mask, dtype=jnp.int32))
 
-    # ---- halo working set: ONE membership gather + ONE bounded value
-    # regather per batch replace the deleted O(n) entry state gather
-    halo_ids = build_halo_ids(layout, src, dst, ins_u, ins_v, rm_u, rm_v, n)
-    session = layout.bind(halo_ids)
-    core_h = session.gather_values(core)
-    # weighted mode freezes labels through both fixpoints — no edge pass
-    # ever reads a halo label, so the label regather is skipped entirely
-    label_h = None if w is not None else session.gather_values(label)
-    src_h = session.locate(src)
-    dst_h = session.locate(dst)
+        # ---- halo working set: ONE membership gather + ONE bounded value
+        # regather per batch replace the deleted O(n) entry state gather
+        halo_ids = build_halo_ids(layout, src, dst, ins_u, ins_v, rm_u,
+                                  rm_v, n)
+        session = layout.bind(halo_ids)
+        core_h = session.gather_values(core)
+        # weighted mode freezes labels through both fixpoints — no edge
+        # pass ever reads a halo label, so the label regather is skipped
+        label_h = None if w is not None else session.gather_values(label)
+        src_h = session.locate(src)
+        dst_h = session.locate(dst)
 
     core_pre_rm = core
-    if w is not None:
-        core, core_h, rm_rounds, rm_fmax = weighted_core_fixpoint_pass_halo(
-            src_h, dst_h, valid, w, core, core_h, session,
-            kernel_backend=kernel_backend,
-        )
-        hi = dout_same = session.zeros()
-        rm_ovf = jnp.int32(0)
-    else:
-        (core, label, core_h, label_h, rm_rounds, hi, dout_same, rm_fmax,
-         rm_ovf) = removal_fixpoint_halo(
-            src_h, dst_h, valid, core, label, core_h, label_h, session,
-            n_levels, kernel_backend=kernel_backend,
-        )
-    n_dropped = vsum(jnp.sum(core != core_pre_rm, dtype=jnp.int32))
+    with jax.named_scope("coremaint.remove.stats"):
+        if w is not None:
+            (core, core_h, rm_rounds,
+             rm_fmax) = weighted_core_fixpoint_pass_halo(
+                src_h, dst_h, valid, w, core, core_h, session,
+                kernel_backend=kernel_backend,
+            )
+            hi = dout_same = session.zeros()
+            rm_ovf = jnp.int32(0)
+        else:
+            (core, label, core_h, label_h, rm_rounds, hi, dout_same,
+             rm_fmax, rm_ovf) = removal_fixpoint_halo(
+                src_h, dst_h, valid, core, label, core_h, label_h, session,
+                n_levels, kernel_backend=kernel_backend,
+            )
+        n_dropped = vsum(jnp.sum(core != core_pre_rm, dtype=jnp.int32))
 
-    # ---- 2. insert dedup + membership against the post-removal table ----
-    ilo, ihi, iok, key = batch_dedup(ins_u, ins_v, ins_ok, n)
-    ifound, islot_hit = lookup(key)
-    exists = allsum((ifound & ~rm_mask[islot_hit]).astype(jnp.int32)) > 0
-    iok = iok & ~exists
+    with jax.named_scope("coremaint.table"):
+        # ---- 2. insert dedup + membership against the post-removal table
+        ilo, ihi, iok, key = batch_dedup(ins_u, ins_v, ins_ok, n)
+        ifound, islot_hit = lookup(key)
+        exists = allsum(
+            (ifound & ~rm_mask[islot_hit]).astype(jnp.int32)
+        ) > 0
+        iok = iok & ~exists
 
-    # ---- 3. slot allocation + table writes (identical to batch_program;
-    # the free-list ranks dead slots over the WHOLE mesh product) -------
-    lpos, iok = freelist_alloc(valid, iok, axis=table_axis,
-                               hierarchical=(freelist == "hierarchical"))
-    src = src.at[lpos].set(ilo.astype(src.dtype), mode="drop")
-    dst = dst.at[lpos].set(ihi.astype(dst.dtype), mode="drop")
-    valid = valid.at[lpos].set(True, mode="drop")
-    if w is not None:
-        w = w.at[lpos].set(ins_w.astype(w.dtype), mode="drop")
-    n_inserted = jnp.sum(iok, dtype=jnp.int32)
-    n_recycled = allsum(jnp.sum(lpos < hwm0, dtype=jnp.int32))
-    n_edges = n_edges - n_removed + n_inserted
+        # ---- 3. slot allocation + table writes (identical to
+        # batch_program; the free-list ranks dead slots over the WHOLE
+        # mesh product) ---------------------------------------------------
+        lpos, iok = freelist_alloc(valid, iok, axis=table_axis,
+                                   hierarchical=(freelist == "hierarchical"))
+        src = src.at[lpos].set(ilo.astype(src.dtype), mode="drop")
+        dst = dst.at[lpos].set(ihi.astype(dst.dtype), mode="drop")
+        valid = valid.at[lpos].set(True, mode="drop")
+        if w is not None:
+            w = w.at[lpos].set(ins_w.astype(w.dtype), mode="drop")
+        n_inserted = jnp.sum(iok, dtype=jnp.int32)
+        n_recycled = allsum(jnp.sum(lpos < hwm0, dtype=jnp.int32))
+        n_edges = n_edges - n_removed + n_inserted
 
-    # the newly written slots reference only lane endpoints — already in
-    # the halo by construction — so relocating the window is pure local
-    # compute, no new gather
-    src_h = session.locate(src)
-    dst_h = session.locate(dst)
+        # the newly written slots reference only lane endpoints — already
+        # in the halo by construction — so relocating the window is pure
+        # local compute, no new gather
+        src_h = session.locate(src)
+        dst_h = session.locate(dst)
 
     core_pre_ins = core
     if w is not None:
-        total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
-        (core, core_h, ins_rounds,
-         ins_fmax) = weighted_promotion_fixpoint_halo(
-            src_h, dst_h, valid, w, core, core_h, total_w, session,
-            kernel_backend=kernel_backend,
-        )
-        v_plus = core != core_pre_ins
-        ins_ovf = jnp.int32(0)
+        with jax.named_scope("coremaint.promote.stats"):
+            total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
+            (core, core_h, ins_rounds,
+             ins_fmax) = weighted_promotion_fixpoint_halo(
+                src_h, dst_h, valid, w, core, core_h, total_w, session,
+                kernel_backend=kernel_backend,
+            )
+            v_plus = core != core_pre_ins
+        ins_ovf = fwd_waves = ev_waves = jnp.int32(0)
     else:
-        u_pos = session.locate(ilo)
-        v_pos = session.locate(ihi)
+        with jax.named_scope("coremaint.promote.seed"):
+            u_pos = session.locate(ilo)
+            v_pos = session.locate(ihi)
 
-        # O(batch) delta on the shared (hi, dout_same): the per-edge
-        # predicate reads lane endpoint values from the halo (replicated
-        # verdicts), the scatter lands in each owner's slice and drops OOB
-        hi_u, hi_v, do_u, do_v = G.hi_dout_indicators(
-            core_h, label_h, u_pos, v_pos, iok
-        )
-        hi = layout.add_at(hi, ilo, hi_u.astype(jnp.int32))
-        hi = layout.add_at(hi, ihi, hi_v.astype(jnp.int32))
-        dout_same = layout.add_at(dout_same, ilo, do_u.astype(jnp.int32))
-        dout_same = layout.add_at(dout_same, ihi, do_v.astype(jnp.int32))
+            # O(batch) delta on the shared (hi, dout_same): the per-edge
+            # predicate reads lane endpoint values from the halo
+            # (replicated verdicts), the scatter lands in each owner's
+            # slice and drops OOB
+            hi_u, hi_v, do_u, do_v = G.hi_dout_indicators(
+                core_h, label_h, u_pos, v_pos, iok
+            )
+            hi = layout.add_at(hi, ilo, hi_u.astype(jnp.int32))
+            hi = layout.add_at(hi, ihi, hi_v.astype(jnp.int32))
+            dout_same = layout.add_at(dout_same, ilo,
+                                      do_u.astype(jnp.int32))
+            dout_same = layout.add_at(dout_same, ihi,
+                                      do_v.astype(jnp.int32))
 
         (core, label, core_h, label_h, ins_rounds, v_plus, ins_fmax,
-         ins_ovf) = promotion_fixpoint_halo(
+         ins_ovf, fwd_waves, ev_waves) = promotion_fixpoint_halo(
             src_h, dst_h, valid, core, label, core_h, label_h,
             ilo, ihi, u_pos, v_pos, iok, hi, dout_same, session, n_levels,
             kernel_backend=kernel_backend,
         )
-    n_promoted = vsum(jnp.sum(core != core_pre_ins, dtype=jnp.int32))
+    with jax.named_scope("coremaint.promote.stats"):
+        n_promoted = vsum(jnp.sum(core != core_pre_ins, dtype=jnp.int32))
+        n_v_plus = vsum(jnp.sum(v_plus, dtype=jnp.int32))
+        # per-round peaks were tracked locally; ONE pmax completes them
+        max_frontier = session.pmax_scalar(jnp.maximum(rm_fmax, ins_fmax))
+        force = ((n_dropped > 0) | (n_promoted > 0)) if w is not None \
+            else None
 
     # ---- 4. in-program renumber gate (ring relabel over owner axis) ------
-    force = ((n_dropped > 0) | (n_promoted > 0)) if w is not None else None
     label, renumbered = maybe_renumber_ring(
         core, label, layout.axis, layout.n_shards, note=_note, force=force
     )
 
+    with jax.named_scope("coremaint.table"):
+        high_water = G.slot_high_water(valid, table_axis)
     stats = BatchStats(
         n_inserted=n_inserted,
         n_removed=n_removed,
         insert_rounds=ins_rounds,
         n_promoted=n_promoted,
-        v_plus=vsum(jnp.sum(v_plus, dtype=jnp.int32)),
+        v_plus=n_v_plus,
         remove_rounds=rm_rounds,
         n_dropped=n_dropped,
         renumbered=renumbered,
         n_recycled=n_recycled,
-        high_water=G.slot_high_water(valid, table_axis),
-        # per-round peaks were tracked locally; ONE pmax completes them
-        max_frontier=session.pmax_scalar(
-            jnp.maximum(rm_fmax, ins_fmax)
-        ),
+        high_water=high_water,
+        max_frontier=max_frontier,
         # overflow verdicts are replicated (gathered count columns), so
         # the local sum IS the global round count
         n_overflow=rm_ovf + ins_ovf,
+        forward_waves=fwd_waves,
+        evict_waves=ev_waves,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats
@@ -625,17 +679,20 @@ def apply_batch(
     n_edges, stats)``.
     """
     full_src, full_dst, full_valid = src, dst, valid
+    with jax.named_scope("coremaint.table"):
+        src, dst, valid = (src[:active_cap], dst[:active_cap],
+                           valid[:active_cap])
     src, dst, valid, core, label, n_edges, stats = batch_program(
-        src[:active_cap], dst[:active_cap], valid[:active_cap],
-        core, label, n_edges,
+        src, dst, valid, core, label, n_edges,
         ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
         n, n_levels, kernel_backend=kernel_backend,
     )
-    # splice the active region back into the full-capacity buffers (the
-    # inactive tail is untouched: all-invalid headroom)
-    src = jnp.concatenate([src, full_src[active_cap:]])
-    dst = jnp.concatenate([dst, full_dst[active_cap:]])
-    valid = jnp.concatenate([valid, full_valid[active_cap:]])
+    with jax.named_scope("coremaint.table"):
+        # splice the active region back into the full-capacity buffers
+        # (the inactive tail is untouched: all-invalid headroom)
+        src = jnp.concatenate([src, full_src[active_cap:]])
+        dst = jnp.concatenate([dst, full_dst[active_cap:]])
+        valid = jnp.concatenate([valid, full_valid[active_cap:]])
     return src, dst, valid, core, label, n_edges, stats
 
 
@@ -670,15 +727,17 @@ def apply_batch_weighted(
     the weighted program body. Returns ``(src, dst, valid, w, core,
     label, n_edges, stats)``."""
     full_src, full_dst, full_valid, full_w = src, dst, valid, w
+    with jax.named_scope("coremaint.table"):
+        src, dst, valid, w = (src[:active_cap], dst[:active_cap],
+                              valid[:active_cap], w[:active_cap])
     src, dst, valid, w, core, label, n_edges, stats = batch_program(
-        src[:active_cap], dst[:active_cap], valid[:active_cap],
-        core, label, n_edges,
+        src, dst, valid, core, label, n_edges,
         ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
-        n, n_levels, kernel_backend=kernel_backend,
-        w=w[:active_cap], ins_w=ins_w,
+        n, n_levels, kernel_backend=kernel_backend, w=w, ins_w=ins_w,
     )
-    src = jnp.concatenate([src, full_src[active_cap:]])
-    dst = jnp.concatenate([dst, full_dst[active_cap:]])
-    valid = jnp.concatenate([valid, full_valid[active_cap:]])
-    w = jnp.concatenate([w, full_w[active_cap:]])
+    with jax.named_scope("coremaint.table"):
+        src = jnp.concatenate([src, full_src[active_cap:]])
+        dst = jnp.concatenate([dst, full_dst[active_cap:]])
+        valid = jnp.concatenate([valid, full_valid[active_cap:]])
+        w = jnp.concatenate([w, full_w[active_cap:]])
     return src, dst, valid, w, core, label, n_edges, stats

@@ -52,6 +52,8 @@ class InsertStats(NamedTuple):
     n_promoted: Array    # |V*| over the whole batch
     v_plus: Array        # |V+| — vertices ever reached by FORWARD
     max_frontier: Array  # max per-shard exchanged-mask count over all rounds
+    forward_waves: Array  # FORWARD wave iterations, summed over rounds
+    evict_waves: Array    # EVICT iterations, summed over rounds
 
 
 def freelist_alloc(
@@ -171,6 +173,7 @@ def write_edge_slots(
     return src, dst, valid, n_edges + jnp.sum(new_ok, dtype=jnp.int32)
 
 
+@jax.named_scope("coremaint.promote.seed")
 def promotion_fixpoint(
     src: Array,
     dst: Array,
@@ -208,10 +211,18 @@ def promotion_fixpoint(
     core/label stay replicated values, so the seed scatter and the label
     placement need no collective.
 
-    Returns ``(core, label, rounds, v_plus_mask, max_frontier)``;
-    ``max_frontier`` is the max per-shard count over every exchanged mask
-    (``layout.frontier_peak``) — the observed datum the sparse
-    ``frontier_cap`` planner is tuned from (docs/DESIGN.md §4.3).
+    Returns ``(core, label, rounds, v_plus_mask, max_frontier,
+    forward_waves, evict_waves)``; ``max_frontier`` is the max per-shard
+    count over every exchanged mask (``layout.frontier_peak``) — the
+    observed datum the sparse ``frontier_cap`` planner is tuned from
+    (docs/DESIGN.md §4.3); the wave counts are the iterations of the
+    FORWARD and EVICT loops summed over the rounds, each one a pass over
+    the slot table.
+
+    Every op runs under a ``coremaint.*`` named scope (the round's seed
+    here, ``promote.forward`` / ``promote.evict`` / ``labels`` in the
+    helpers, ``promote.stats`` for the closing statistics pass), so a
+    profile splits device time by phase.
 
     ``kernel_backend="pallas"`` runs every wave/evict/terminating
     statistic through the fused COO kernels (kernels/coremaint.py) —
@@ -230,7 +241,7 @@ def promotion_fixpoint(
 
     def round_body(state):
         (core, label, _, promoted_prev, rounds, v_plus, hi, dout_same,
-         fmax) = state
+         fmax, fwd, ev) = state
 
         # SEED: roots of pending edges (order-min endpoint at current state)
         e_src_lt = (core[new_src] < core[new_dst]) | (
@@ -247,12 +258,12 @@ def promotion_fixpoint(
         fmax = jnp.maximum(fmax, layout.frontier_peak(viol))
         seed = seed | viol | promoted_prev
 
-        reach, passing, wave_fmax = _forward_reach(
+        reach, passing, wave_fmax, fwd_waves = _forward_reach(
             src, dst, valid, core, label, seed, hi, dout_same, n, layout,
             kernel_backend=kernel_backend,
         )
         cand0 = reach & passing
-        cand, evict_round, ev_fmax = _evict_fixpoint(
+        cand, evict_round, ev_fmax, ev_waves = _evict_fixpoint(
             src, dst, valid, core, cand0, hi, n, layout,
             kernel_backend=kernel_backend,
         )
@@ -274,21 +285,24 @@ def promotion_fixpoint(
         # candidates (docs/DESIGN.md §2.3) — this skips the seed
         # implementation's trailing confirm round (a full forward + evict
         # + stats pass) entirely.
-        if fuse_decision:
-            # ONE pallas_call: stats + the violator threshold mask that
-            # decides fixpoint termination
-            new_hi, new_dout, viol_next = coremaint.fused_promotion_stats(
-                src, dst, valid, new_core, label, n
-            )
-            changed = jnp.any(viol_next)
-        else:
-            new_hi, new_dout = G.hi_and_dout_same(
-                src, dst, valid, new_core, label, n, layout,
-                backend=kernel_backend,
-            )
-            changed = layout.any_owned(
-                (new_hi + new_dout) > layout.own(new_core)
-            )
+        with jax.named_scope("coremaint.promote.stats"):
+            if fuse_decision:
+                # ONE pallas_call: stats + the violator threshold mask
+                # that decides fixpoint termination
+                new_hi, new_dout, viol_next = (
+                    coremaint.fused_promotion_stats(
+                        src, dst, valid, new_core, label, n
+                    )
+                )
+                changed = jnp.any(viol_next)
+            else:
+                new_hi, new_dout = G.hi_and_dout_same(
+                    src, dst, valid, new_core, label, n, layout,
+                    backend=kernel_backend,
+                )
+                changed = layout.any_owned(
+                    (new_hi + new_dout) > layout.own(new_core)
+                )
         return (
             new_core,
             label,
@@ -299,18 +313,22 @@ def promotion_fixpoint(
             new_hi,
             new_dout,
             fmax,
+            fwd + fwd_waves,
+            ev + ev_waves,
         )
 
-    core, label, _, _, rounds, v_plus, _, _, fmax = jax.lax.while_loop(
+    z = jnp.int32(0)
+    (core, label, _, _, rounds, v_plus, _, _, fmax, fwd,
+     ev) = jax.lax.while_loop(
         round_cond,
         round_body,
         (core, label, jnp.bool_(True), jnp.zeros(n, dtype=bool),
-         jnp.int32(0), jnp.zeros(n, dtype=bool), hi, dout_same,
-         jnp.int32(0)),
+         z, jnp.zeros(n, dtype=bool), hi, dout_same, z, z, z),
     )
-    return core, label, rounds, v_plus, fmax
+    return core, label, rounds, v_plus, fmax, fwd, ev
 
 
+@jax.named_scope("coremaint.promote.seed")
 def promotion_fixpoint_halo(
     src_h: Array,
     dst_h: Array,
@@ -345,9 +363,12 @@ def promotion_fixpoint_halo(
     labels to ``promotion_fixpoint`` on the assembled global state.
 
     Returns ``(core_own, label_own, core_h, label_h, rounds, v_plus_own,
-    max_frontier, n_overflow)`` — ``max_frontier`` is the LOCAL running
-    per-round owned frontier count (engine completes with one pmax),
-    ``n_overflow`` counts sparse exchanges that fell back dense.
+    max_frontier, n_overflow, forward_waves, evict_waves)`` —
+    ``max_frontier`` is the LOCAL running per-round owned frontier count
+    (engine completes with one pmax), ``n_overflow`` counts sparse
+    exchanges that fell back dense, and the wave counts are the halo
+    loops' own iterations (replicated: every shard runs the same trip
+    count).
     """
     hcap = session.halo_cap
     d_v = session.layout.n_shards
@@ -357,7 +378,7 @@ def promotion_fixpoint_halo(
 
     def round_body(state):
         (core_own, label_own, core_h, label_h, _, promoted_prev, rounds,
-         v_plus, hi, dout_same, fmax, n_ovf) = state
+         v_plus, hi, dout_same, fmax, n_ovf, fwd, ev) = state
 
         # SEED: roots of pending edges at the current state — the lane
         # endpoints' halo values are identical on every device, so the
@@ -374,12 +395,13 @@ def promotion_fixpoint_halo(
         fmax = jnp.maximum(fmax, session.frontier_peak(viol))
         seed = seed | viol | promoted_prev
 
-        reach, passing, wave_fmax, wave_ovf = _forward_reach_halo(
+        (reach, passing, wave_fmax, wave_ovf,
+         fwd_waves) = _forward_reach_halo(
             src_h, dst_h, valid, core_own, core_h, label_h, seed,
             hi, dout_same, session, kernel_backend=kernel_backend,
         )
         cand0 = reach & passing
-        cand, evict_round, ev_fmax, ev_ovf = _evict_fixpoint_halo(
+        cand, evict_round, ev_fmax, ev_ovf, ev_waves = _evict_fixpoint_halo(
             src_h, dst_h, valid, core_own, core_h, cand0, hi, session,
             kernel_backend=kernel_backend,
         )
@@ -405,28 +427,32 @@ def promotion_fixpoint_halo(
         core_h, label_h, ovf = session.refresh_values(
             new_core, label_own, cand0, core_h, label_h
         )
-        new_hi, new_dout = G.hi_and_dout_same(
-            src_h, dst_h, valid, core_h, label_h, hcap, session,
-            backend=kernel_backend,
-        )
-        changed = session.any_owned((new_hi + new_dout) > new_core)
+        with jax.named_scope("coremaint.promote.stats"):
+            new_hi, new_dout = G.hi_and_dout_same(
+                src_h, dst_h, valid, core_h, label_h, hcap, session,
+                backend=kernel_backend,
+            )
+            changed = session.any_owned((new_hi + new_dout) > new_core)
         return (
             new_core, label_own, core_h, label_h, changed, cand,
             rounds + 1, v_plus | reach, new_hi, new_dout, fmax,
             n_ovf + wave_ovf + ev_ovf + ovf.astype(jnp.int32),
+            fwd + fwd_waves, ev + ev_waves,
         )
 
     zmask = jnp.zeros(session.n_owned, dtype=bool)
+    z = jnp.int32(0)
     (core_own, label_own, core_h, label_h, _, _, rounds, v_plus, _, _,
-     fmax, n_ovf) = jax.lax.while_loop(
+     fmax, n_ovf, fwd, ev) = jax.lax.while_loop(
         round_cond, round_body,
         (core_own, label_own, core_h, label_h, jnp.bool_(True), zmask,
-         jnp.int32(0), zmask, hi, dout_same, jnp.int32(0), jnp.int32(0)),
+         z, zmask, hi, dout_same, z, z, z, z),
     )
     return (core_own, label_own, core_h, label_h, rounds, v_plus, fmax,
-            n_ovf)
+            n_ovf, fwd, ev)
 
 
+@jax.named_scope("coremaint.promote.forward")
 def _forward_reach_halo(
     src_h: Array,
     dst_h: Array,
@@ -442,14 +468,14 @@ def _forward_reach_halo(
 ):
     """``_forward_reach`` with OWNED loop masks and a per-wave halo
     refresh of the reached-and-passing frontier. Returns ``(reach,
-    passing, max_frontier, n_overflow)`` — owned masks."""
+    passing, max_frontier, n_overflow, waves)`` — owned masks."""
     hcap = session.halo_cap
 
     def cond(state):
         return state[2]
 
     def body(state):
-        reach, passing, _, fmax, n_ovf = state
+        reach, passing, _, fmax, n_ovf, waves = state
         rp = reach & passing
         rp_h, ovf = session.refresh_mask(rp)
         din, grow = G.din_and_expand(
@@ -466,17 +492,18 @@ def _forward_reach_halo(
             (new_reach != reach) | (new_passing != passing)
         )
         return (new_reach, new_passing, changed, fmax,
-                n_ovf + ovf.astype(jnp.int32))
+                n_ovf + ovf.astype(jnp.int32), waves + 1)
 
     init_pass = (hi + dout_same) > core_own
-    reach, passing, _, fmax, n_ovf = jax.lax.while_loop(
+    reach, passing, _, fmax, n_ovf, waves = jax.lax.while_loop(
         cond, body,
         (seed, init_pass, jnp.bool_(True),
-         session.frontier_peak(init_pass), jnp.int32(0)),
+         session.frontier_peak(init_pass), jnp.int32(0), jnp.int32(0)),
     )
-    return reach, passing, fmax, n_ovf
+    return reach, passing, fmax, n_ovf, waves
 
 
+@jax.named_scope("coremaint.promote.evict")
 def _evict_fixpoint_halo(
     src_h: Array,
     dst_h: Array,
@@ -490,7 +517,7 @@ def _evict_fixpoint_halo(
 ):
     """``_evict_fixpoint`` with OWNED candidate masks and a per-round
     halo refresh. Returns ``(cand, evict_round, max_frontier,
-    n_overflow)`` — owned arrays."""
+    n_overflow, waves)`` — owned arrays."""
     hcap = session.halo_cap
 
     def cond(state):
@@ -512,14 +539,16 @@ def _evict_fixpoint_halo(
         return (new_cand, evict_round, rnd + 1, changed, fmax,
                 n_ovf + ovf.astype(jnp.int32))
 
-    cand, evict_round, _, _, fmax, n_ovf = jax.lax.while_loop(
+    # the round counter starts at 1, so it ends one past the iterations
+    cand, evict_round, rnd, _, fmax, n_ovf = jax.lax.while_loop(
         cond, body,
         (cand, jnp.zeros(session.n_owned, dtype=jnp.int32),
          jnp.int32(1), jnp.bool_(True), jnp.int32(0), jnp.int32(0)),
     )
-    return cand, evict_round, fmax, n_ovf
+    return cand, evict_round, fmax, n_ovf, rnd - 1
 
 
+@jax.named_scope("coremaint.promote.forward")
 def _forward_reach(
     src: Array,
     dst: Array,
@@ -532,27 +561,27 @@ def _forward_reach(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
-) -> Tuple[Array, Array, Array]:
+) -> Tuple[Array, Array, Array, Array]:
     """Monotone fixpoint of gated forward expansion.
 
-    Returns (reach, passing, max_frontier) — boolean masks (full [n],
-    replicated) plus the max per-shard count over the exchanged wave
-    masks. ``passing`` uses the optimistic test with din counted over
-    reached-and-passing predecessors only. Under a range-sharded layout
-    each wave moves one reduce_scatter (din, owned) plus the two wave
-    bitmasks; the loop state stays full/replicated so the edge pass can
-    index it at arbitrary endpoints.
+    Returns (reach, passing, max_frontier, waves) — boolean masks (full
+    [n], replicated), the max per-shard count over the exchanged wave
+    masks, and the number of waves run. ``passing`` uses the optimistic
+    test with din counted over reached-and-passing predecessors only.
+    Under a range-sharded layout each wave moves one reduce_scatter
+    (din, owned) plus the two wave bitmasks; the loop state stays
+    full/replicated so the edge pass can index it at arbitrary
+    endpoints.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
     core_own = layout.own(core)
 
     def cond(state):
-        _, _, changed, _ = state
-        return changed
+        return state[2]
 
     def body(state):
-        reach, passing, _, fmax = state
+        reach, passing, _, fmax, waves = state
         rp = reach & passing
         # one fused scatter per wave: din and frontier growth (C1)
         din, grow = G.din_and_expand(src, dst, valid, core, label, rp, n,
@@ -566,16 +595,18 @@ def _forward_reach(
         ))
         new_reach = reach | grow_full
         changed = jnp.any(new_reach != reach) | jnp.any(new_passing != passing)
-        return new_reach, new_passing, changed, fmax
+        return new_reach, new_passing, changed, fmax, waves + 1
 
     init_pass = layout.gather_mask((hi + dout_same) > core_own)
-    reach, passing, _, fmax = jax.lax.while_loop(
+    reach, passing, _, fmax, waves = jax.lax.while_loop(
         cond, body,
-        (seed, init_pass, jnp.bool_(True), layout.frontier_peak(init_pass)),
+        (seed, init_pass, jnp.bool_(True), layout.frontier_peak(init_pass),
+         jnp.int32(0)),
     )
-    return reach, passing, fmax
+    return reach, passing, fmax, waves
 
 
+@jax.named_scope("coremaint.promote.evict")
 def _evict_fixpoint(
     src: Array,
     dst: Array,
@@ -586,12 +617,12 @@ def _evict_fixpoint(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
-) -> Tuple[Array, Array, Array]:
+) -> Tuple[Array, Array, Array, Array]:
     """Greatest fixpoint of the candidate support test (sound + complete
     for any starting superset of V*).
 
     Returns (surviving candidates, eviction round per vertex,
-    max_frontier), masks full [n]. The round numbers order the Backward
+    max_frontier, waves), masks full [n]. The round numbers order the Backward
     tail placement (never-evicted keep 0); they are maintained
     replicated from the gathered candidate masks, so no integer array
     crosses the mesh.
@@ -617,13 +648,14 @@ def _evict_fixpoint(
         return (new_cand, evict_round, rnd + 1, jnp.any(new_cand != cand),
                 fmax)
 
-    cand, evict_round, _, _, fmax = jax.lax.while_loop(
+    # the round counter starts at 1, so it ends one past the iterations
+    cand, evict_round, rnd, _, fmax = jax.lax.while_loop(
         cond,
         body,
         (cand, jnp.zeros(n, dtype=jnp.int32), jnp.int32(1), jnp.bool_(True),
          jnp.int32(0)),
     )
-    return cand, evict_round, fmax
+    return cand, evict_round, fmax, rnd - 1
 
 
 def weighted_promotion_fixpoint(
@@ -700,7 +732,7 @@ def insert_batch(
     core0 = core
     # fused (hi, dout_same) — one scatter-add / one collective (C1)
     hi, dout_same = G.hi_and_dout_same(src, dst, valid, core, label, n)
-    core, label, rounds, v_plus, fmax = promotion_fixpoint(
+    core, label, rounds, v_plus, fmax, fwd, ev = promotion_fixpoint(
         src, dst, valid, core, label, new_src, new_dst, new_ok,
         hi, dout_same, n, n_levels,
     )
@@ -709,5 +741,7 @@ def insert_batch(
         n_promoted=jnp.sum(core != core0, dtype=jnp.int32),
         v_plus=jnp.sum(v_plus, dtype=jnp.int32),
         max_frontier=fmax,
+        forward_waves=fwd,
+        evict_waves=ev,
     )
     return src, dst, valid, n_edges, core, label, stats

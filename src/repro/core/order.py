@@ -36,6 +36,7 @@ def level_max_labels(core: Array, label: Array, exclude: Array, n_levels: int) -
     return jax.ops.segment_max(vals, core, num_segments=n_levels)
 
 
+@jax.named_scope("coremaint.labels")
 def place_block(
     core_new: Array,
     label: Array,
@@ -113,6 +114,7 @@ def _ring_visiting(payload, axis: str, n_shards: int, note=None):
     return tuple(out)
 
 
+@jax.named_scope("coremaint.labels")
 def place_block_ring(
     core_new: Array,
     label: Array,
@@ -251,6 +253,7 @@ def renumber_ring(core: Array, label: Array, axis: str, n_shards: int,
     return rank * LABEL_GAP
 
 
+@jax.named_scope("coremaint.labels")
 def maybe_renumber_ring(core: Array, label: Array, axis: str,
                         n_shards: int, note=None,
                         force: Array | None = None) -> Tuple[Array, Array]:
@@ -293,6 +296,7 @@ def needs_renumber(label: Array) -> Array:
     return (jnp.min(label) < -lim) | (jnp.max(label) > lim)
 
 
+@jax.named_scope("coremaint.labels")
 def maybe_renumber(core: Array, label: Array,
                    force: Array | None = None) -> Tuple[Array, Array]:
     """Device-side renumber gate: relabel iff the label space is out of

@@ -51,6 +51,7 @@ class RemoveStats(NamedTuple):
     max_frontier: Array  # max per-shard drop-mask count over all rounds
 
 
+@jax.named_scope("coremaint.remove.stats")
 def removal_fixpoint(
     src: Array,
     dst: Array,
@@ -144,6 +145,7 @@ def removal_fixpoint(
     return core, label, rounds, hi, dout_same, fmax
 
 
+@jax.named_scope("coremaint.remove.stats")
 def removal_fixpoint_halo(
     src_h: Array,
     dst_h: Array,

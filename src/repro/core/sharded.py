@@ -268,9 +268,10 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                     freelist=freelist, kernel_backend=kernel_backend,
                 )
             )
-        src = jnp.concatenate([src, full_src[w:]])
-        dst = jnp.concatenate([dst, full_dst[w:]])
-        valid = jnp.concatenate([valid, full_valid[w:]])
+        with jax.named_scope("coremaint.table"):
+            src = jnp.concatenate([src, full_src[w:]])
+            dst = jnp.concatenate([dst, full_dst[w:]])
+            valid = jnp.concatenate([valid, full_valid[w:]])
         return src, dst, valid, core, label, n_edges, stats
 
     def _kernel_weighted(src, dst, valid, ew, core, label, n_edges,
@@ -301,10 +302,11 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                     w=ew[:win], ins_w=ins_w,
                 )
             )
-        src = jnp.concatenate([src, full_src[win:]])
-        dst = jnp.concatenate([dst, full_dst[win:]])
-        valid = jnp.concatenate([valid, full_valid[win:]])
-        ew = jnp.concatenate([ew, full_ew[win:]])
+        with jax.named_scope("coremaint.table"):
+            src = jnp.concatenate([src, full_src[win:]])
+            dst = jnp.concatenate([dst, full_dst[win:]])
+            valid = jnp.concatenate([valid, full_valid[win:]])
+            ew = jnp.concatenate([ew, full_ew[win:]])
         return src, dst, valid, ew, core, label, n_edges, stats
 
     espec = P(all_axes if len(all_axes) > 1 else axis)

@@ -166,6 +166,17 @@ def _run_churn_differential(m0, graph_seed, stream_seed, n_batches,
         for e, st_ in stats.items():
             assert int(st_.n_inserted) == len(inserted), e
             assert int(st_.n_removed) == len(removed), e
+        # the order-based engines run the same promotion passes: equal
+        # FORWARD / EVICT wave counts across engines, layouts and kernel
+        # backends; the weighted engines run no such waves
+        for e, st_ in stats.items():
+            got = (int(st_.forward_waves), int(st_.evict_waves))
+            if e in WEIGHTED_CONFIGS:
+                assert got == (0, 0), e
+            else:
+                want = stats["unified"]
+                assert got == (int(want.forward_waves),
+                               int(want.evict_waves)), e
         # the recycling invariant: the slot high-water mark never outruns
         # the running max of the live count (holes are filled first)
         assert int(stats["unified"].high_water) <= hwm_bound
@@ -684,8 +695,15 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
     live = set(norm(g.edge_array()))
     events = list(churn_stream(g, 8, 24, seed=5))
     for ev in events[:6]:
+        waves = set()
         for m in (ms, mu, mv, mh, mf, mp, *halos):
-            m.apply_batch(insert_edges=ev.edges, remove_edges=ev.removals)
+            st = m.apply_batch(insert_edges=ev.edges,
+                               remove_edges=ev.removals)
+            waves.add((int(st.insert_rounds), int(st.forward_waves),
+                       int(st.evict_waves)))
+        # the wave counters count the same passes on every layout, mesh
+        # and kernel backend
+        assert len(waves) == 1, waves
         for e in norm(ev.removals):
             live.discard(e)
         for e in norm(ev.edges):
