@@ -33,7 +33,7 @@ stay bit-identical (integer adds in a different order).
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +136,47 @@ def hi_dout_indicators(
     return hi_to_u, hi_to_v, dout_to_u, dout_to_v
 
 
+class WaveMasks(NamedTuple):
+    """Per-edge masks of one promotion round's (core, label, valid): they
+    hold through every FORWARD and EVICT wave of the round, so the waves
+    read them instead of gathering core and label again."""
+    same: Array  # valid & core[src] == core[dst]
+    s2d: Array   # same & label[src] < label[dst]: src precedes dst
+    d2s: Array   # same & label[dst] < label[src]: dst precedes src
+
+
+def _wave_masks_of(valid: Array, indicators) -> WaveMasks:
+    hi_s, hi_d, do_s, do_d = indicators
+    return WaveMasks(valid & ~(hi_s | hi_d), do_s, do_d)
+
+
+def wave_masks(
+    src: Array, dst: Array, valid: Array, core: Array, label: Array,
+) -> WaveMasks:
+    """The round's ``WaveMasks``, from the same per-edge indicators as the
+    (hi, dout_same) statistic (``hi_dout_indicators``)."""
+    return _wave_masks_of(
+        valid, hi_dout_indicators(core, label, src, dst, valid)
+    )
+
+
+def _hi_dout_sums(indicators, src: Array, dst: Array, n: int,
+                  layout: Optional[VertexLayout]):
+    hi_s, hi_d, do_s, do_d = indicators
+    to_src = jnp.stack(
+        [hi_s.astype(jnp.int32), do_s.astype(jnp.int32)], axis=-1
+    )
+    to_dst = jnp.stack(
+        [hi_d.astype(jnp.int32), do_d.astype(jnp.int32)], axis=-1
+    )
+    out = _complete(
+        jax.ops.segment_sum(to_src, src, num_segments=n)
+        + jax.ops.segment_sum(to_dst, dst, num_segments=n),
+        layout,
+    )
+    return out[:, 0], out[:, 1]
+
+
 def hi_and_dout_same(
     src: Array, dst: Array, valid: Array, core: Array, label: Array, n: int,
     layout: Optional[VertexLayout] = None, backend: str = "lax",
@@ -150,19 +191,21 @@ def hi_and_dout_same(
             layout,
         )
         return out[:, 0], out[:, 1]
-    hi_s, hi_d, do_s, do_d = hi_dout_indicators(core, label, src, dst, valid)
-    to_src = jnp.stack(
-        [hi_s.astype(jnp.int32), do_s.astype(jnp.int32)], axis=-1
+    return _hi_dout_sums(
+        hi_dout_indicators(core, label, src, dst, valid), src, dst, n, layout
     )
-    to_dst = jnp.stack(
-        [hi_d.astype(jnp.int32), do_d.astype(jnp.int32)], axis=-1
-    )
-    out = _complete(
-        jax.ops.segment_sum(to_src, src, num_segments=n)
-        + jax.ops.segment_sum(to_dst, dst, num_segments=n),
-        layout,
-    )
-    return out[:, 0], out[:, 1]
+
+
+def hi_dout_same_and_masks(
+    src: Array, dst: Array, valid: Array, core: Array, label: Array, n: int,
+    layout: Optional[VertexLayout] = None,
+):
+    """``hi_and_dout_same`` (lax) plus the ``WaveMasks`` of the same
+    (core, label, valid), built from the same gathers: a promotion round's
+    closing statistics pass hands the next round both."""
+    ind = hi_dout_indicators(core, label, src, dst, valid)
+    hi, dout_same = _hi_dout_sums(ind, src, dst, n, layout)
+    return hi, dout_same, _wave_masks_of(valid, ind)
 
 
 def mcd_hi_dout(
@@ -252,6 +295,17 @@ def count_same_level_in(
     return _seg2(to_src, to_dst, src, dst, n, layout)
 
 
+def count_same_level_in_masked(
+    masks: WaveMasks, mask: Array, src: Array, dst: Array, n: int,
+    layout: Optional[VertexLayout] = None,
+) -> Array:
+    """``count_same_level_in`` with the round's ``WaveMasks``: the only
+    gathers left are the two of the boolean ``mask``."""
+    to_src = (masks.same & mask[dst]).astype(jnp.int32)
+    to_dst = (masks.same & mask[src]).astype(jnp.int32)
+    return _seg2(to_src, to_dst, src, dst, n, layout)
+
+
 def din_and_expand(
     src: Array,
     dst: Array,
@@ -278,6 +332,20 @@ def din_and_expand(
     fwd_to_src = same & (label[dst] < label[src]) & rp[dst]
     din = _seg2(
         fwd_to_src.astype(jnp.int32), fwd_to_dst.astype(jnp.int32),
+        src, dst, n, layout,
+    )
+    return din, din > 0
+
+
+def din_and_expand_masked(
+    masks: WaveMasks, rp: Array, src: Array, dst: Array, n: int,
+    layout: Optional[VertexLayout] = None,
+):
+    """``din_and_expand`` with the round's ``WaveMasks``: the only gathers
+    left are the two of the boolean ``rp``."""
+    din = _seg2(
+        (masks.d2s & rp[dst]).astype(jnp.int32),
+        (masks.s2d & rp[src]).astype(jnp.int32),
         src, dst, n, layout,
     )
     return din, din > 0

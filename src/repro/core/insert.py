@@ -224,24 +224,33 @@ def promotion_fixpoint(
     helpers, ``promote.stats`` for the closing statistics pass), so a
     profile splits device time by phase.
 
+    With the lax backend a round's FORWARD and EVICT waves read the
+    round's per-edge ``G.WaveMasks`` instead of gathering core and label
+    on every wave: (core, label, valid) hold through the round, so the
+    masks are built once before the first round and then by each round's
+    closing statistics pass, from the gathers that pass already makes,
+    and carried to the next round.
+
     ``kernel_backend="pallas"`` runs every wave/evict/terminating
     statistic through the fused COO kernels (kernels/coremaint.py) —
     bit-identical partials, fewer launches; where the layout completes
     locally the terminating violator check folds into the same launch
-    as its statistics (``fused_promotion_stats``).
+    as its statistics (``fused_promotion_stats``). The fused kernels
+    make their own gathers, so that backend carries no masks.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
     fuse_decision = (
         kernel_backend == "pallas" and G.completes_locally(layout)
     )
+    masked = kernel_backend == "lax"
 
     def round_cond(state):
         return state[2]
 
     def round_body(state):
         (core, label, _, promoted_prev, rounds, v_plus, hi, dout_same,
-         fmax, fwd, ev) = state
+         masks, fmax, fwd, ev) = state
 
         # SEED: roots of pending edges (order-min endpoint at current state)
         e_src_lt = (core[new_src] < core[new_dst]) | (
@@ -260,12 +269,12 @@ def promotion_fixpoint(
 
         reach, passing, wave_fmax, fwd_waves = _forward_reach(
             src, dst, valid, core, label, seed, hi, dout_same, n, layout,
-            kernel_backend=kernel_backend,
+            kernel_backend=kernel_backend, masks=masks,
         )
         cand0 = reach & passing
         cand, evict_round, ev_fmax, ev_waves = _evict_fixpoint(
             src, dst, valid, core, cand0, hi, n, layout,
-            kernel_backend=kernel_backend,
+            kernel_backend=kernel_backend, masks=masks,
         )
         fmax = jnp.maximum(fmax, jnp.maximum(wave_fmax, ev_fmax))
 
@@ -295,6 +304,14 @@ def promotion_fixpoint(
                     )
                 )
                 changed = jnp.any(viol_next)
+            elif masked:
+                # the next round's masks come from this pass's gathers
+                new_hi, new_dout, masks = G.hi_dout_same_and_masks(
+                    src, dst, valid, new_core, label, n, layout,
+                )
+                changed = layout.any_owned(
+                    (new_hi + new_dout) > layout.own(new_core)
+                )
             else:
                 new_hi, new_dout = G.hi_and_dout_same(
                     src, dst, valid, new_core, label, n, layout,
@@ -312,18 +329,20 @@ def promotion_fixpoint(
             v_plus | reach,
             new_hi,
             new_dout,
+            masks,
             fmax,
             fwd + fwd_waves,
             ev + ev_waves,
         )
 
+    masks = G.wave_masks(src, dst, valid, core, label) if masked else None
     z = jnp.int32(0)
-    (core, label, _, _, rounds, v_plus, _, _, fmax, fwd,
+    (core, label, _, _, rounds, v_plus, _, _, _, fmax, fwd,
      ev) = jax.lax.while_loop(
         round_cond,
         round_body,
         (core, label, jnp.bool_(True), jnp.zeros(n, dtype=bool),
-         z, jnp.zeros(n, dtype=bool), hi, dout_same, z, z, z),
+         z, jnp.zeros(n, dtype=bool), hi, dout_same, masks, z, z, z),
     )
     return core, label, rounds, v_plus, fmax, fwd, ev
 
@@ -561,6 +580,7 @@ def _forward_reach(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
+    masks: G.WaveMasks | None = None,
 ) -> Tuple[Array, Array, Array, Array]:
     """Monotone fixpoint of gated forward expansion.
 
@@ -571,7 +591,9 @@ def _forward_reach(
     Under a range-sharded layout each wave moves one reduce_scatter
     (din, owned) plus the two wave bitmasks; the loop state stays
     full/replicated so the edge pass can index it at arbitrary
-    endpoints.
+    endpoints. With the round's ``masks`` (lax backend) a wave gathers
+    only ``rp``; without them (pallas) the fused kernel gathers core and
+    label itself.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
@@ -584,8 +606,12 @@ def _forward_reach(
         reach, passing, _, fmax, waves = state
         rp = reach & passing
         # one fused scatter per wave: din and frontier growth (C1)
-        din, grow = G.din_and_expand(src, dst, valid, core, label, rp, n,
-                                     layout, backend=kernel_backend)
+        if masks is None:
+            din, grow = G.din_and_expand(src, dst, valid, core, label, rp,
+                                         n, layout, backend=kernel_backend)
+        else:
+            din, grow = G.din_and_expand_masked(masks, rp, src, dst, n,
+                                                layout)
         new_passing = layout.gather_mask(
             (hi + dout_same + din) > core_own
         )
@@ -617,6 +643,7 @@ def _evict_fixpoint(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
+    masks: G.WaveMasks | None = None,
 ) -> Tuple[Array, Array, Array, Array]:
     """Greatest fixpoint of the candidate support test (sound + complete
     for any starting superset of V*).
@@ -625,7 +652,8 @@ def _evict_fixpoint(
     max_frontier, waves), masks full [n]. The round numbers order the Backward
     tail placement (never-evicted keep 0); they are maintained
     replicated from the gathered candidate masks, so no integer array
-    crosses the mesh.
+    crosses the mesh. With the round's ``masks`` (lax backend) a wave
+    gathers only ``cand``.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
@@ -637,9 +665,13 @@ def _evict_fixpoint(
 
     def body(state):
         cand, evict_round, rnd, _, fmax = state
-        support = hi + G.count_same_level_in(src, dst, valid, core, cand, n,
-                                             layout,
-                                             backend=kernel_backend)
+        if masks is None:
+            same_in = G.count_same_level_in(src, dst, valid, core, cand, n,
+                                            layout, backend=kernel_backend)
+        else:
+            same_in = G.count_same_level_in_masked(masks, cand, src, dst, n,
+                                                   layout)
+        support = hi + same_in
         keep = layout.gather_mask(support > core_own)
         fmax = jnp.maximum(fmax, layout.frontier_peak(keep))
         new_cand = cand & keep
