@@ -10,6 +10,20 @@ a file found by its name: ``configs/<config>.json`` (through
 names, ``streams/<stream>.py``, and ``metrics/<metric>.py``. A metric
 file defines ``read(run) -> float | None`` over a ``RunData``; ``None``
 leaves the metric out of the line.
+
+A generator file defines ``generate(params, rng)``, which returns ``(n,
+edges)``: the unique undirected edges ``lo < hi`` as an ``[m, 2]`` int
+array sorted by ``lo * n + hi``. A configuration whose ``maintainer``
+has ``"weighted": true`` names a generator that returns ``(n, edges,
+weights)``, ``weights`` positive integers row for row with ``edges``,
+and also defines ``draw_weights(params, rng, k)``: ``k`` weights for the
+fresh pairs that its stream inserts. The configuration's generator fixes
+the weight law; the harness carries the weights with their edges
+through the relabelling, into ``CoreMaintainer`` and into the checks.
+
+A configuration's ``checks`` names the numbers that decide ``correct``,
+those its guarantees imply, from ``reference.CHECKS``; the result line
+carries exactly those.
 """
 from __future__ import annotations
 
@@ -165,18 +179,34 @@ def seeded(seed: int, k: int) -> list:
     return [np.random.default_rng(s) for s in ss.spawn(k)]
 
 
-def canonical(n: int, edges: np.ndarray) -> np.ndarray:
-    """Unique undirected edges ``lo < hi`` sorted by ``lo * n + hi``."""
+def canonical(n: int, edges: np.ndarray, weights=None):
+    """Unique undirected edges ``lo < hi`` sorted by ``lo * n + hi``;
+    with ``weights``, ``(edges, weights)``, each edge's weight (its first
+    row's) moved with it."""
     lo, hi = edges.min(axis=1), edges.max(axis=1)
-    key = np.unique(lo * n + hi)
-    return np.stack([key // n, key % n], axis=1)
+    if weights is None:
+        key = np.unique(lo * n + hi)
+        return np.stack([key // n, key % n], axis=1)
+    key, first = np.unique(lo * n + hi, return_index=True)
+    return np.stack([key // n, key % n], axis=1), np.asarray(weights)[first]
+
+
+def checks_of(cfg: dict) -> tuple:
+    """The checks a configuration names, refused where one is unknown."""
+    checks = tuple(cfg["checks"])
+    unknown = sorted(set(checks) - set(reference.CHECKS))
+    if unknown or not checks:
+        raise ValueError(f"configuration {cfg['name']!r} names checks "
+                         f"{unknown or 'none'}; the harness knows "
+                         f"{reference.CHECKS}")
+    return checks
 
 
 def _block(mt, st) -> None:
     import jax
 
-    jax.block_until_ready((mt.src, mt.dst, mt.valid, mt.core, mt.label,
-                           mt.n_edges, st))
+    jax.block_until_ready((mt.src, mt.dst, mt.valid, mt.w, mt.core,
+                           mt.label, mt.n_edges, st))
 
 
 def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
@@ -193,6 +223,8 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
 
     cell = bench.cell(cell_name)
     cfg = bench.config(cell["config"])
+    checks = checks_of(cfg)
+    weighted = bool(cfg["maintainer"].get("weighted", False))
     traffic = bench.traffic(cell["traffic"])
     # the graph and the bursts are the cell's own, fixed by the seeds in
     # its files; the run's seed draws the vertex labels and the probe
@@ -201,11 +233,25 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
     l_rng, p_rng = seeded(seed, 2)
 
     t0 = time.perf_counter()
-    n, edges = bench.generator(cfg["generator"]).generate(cfg, g_rng)
+    gen = bench.generator(cfg["generator"])
+    n, edges, *weights = gen.generate(cfg, g_rng)
+    weights = weights[0] if weights else None
+    if weighted != (weights is not None):
+        raise ValueError(f"configuration {cfg['name']!r}: a weighted "
+                         f"maintainer needs a generator that returns "
+                         f"weights, and only it takes them")
     perm = l_rng.permutation(n)
-    st = bench.stream(traffic["stream"]).build(n, edges, traffic, s_rng,
-                                               perm)
-    edges = canonical(n, perm[edges])
+    stream = bench.stream(traffic["stream"])
+    if weights is None:
+        st = stream.build(n, edges, traffic, s_rng, perm)
+        edges = canonical(n, perm[edges])
+    else:
+        # fresh pairs' weights from a generator spawned apart from s_rng
+        w_rng = seeded(traffic["stream_seed"], 1)[0]
+        st = stream.build(n, edges, traffic, s_rng, perm, weights=weights,
+                          draw_weights=lambda k: gen.draw_weights(
+                              cfg, w_rng, k))
+        edges, weights = canonical(n, perm[edges], weights)
     if st.max_live >= cfg["capacity"]:
         raise ValueError(f"the stream holds up to {st.max_live} edges, the "
                          f"capacity is {cfg['capacity']}")
@@ -219,13 +265,15 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
         t0 = time.perf_counter()
         mt = CoreMaintainer.from_graph(build_csr(n, edges),
                                        capacity=cfg["capacity"],
+                                       weights=weights,
                                        **cfg["maintainer"])
         mt.core.block_until_ready()
         log(f"from_graph: {time.perf_counter() - t0:.3f} s, "
             f"capacity={mt.capacity}")
         t0 = time.perf_counter()
-        ins, rm = st.warmup
-        s = mt.apply_batch(insert_edges=ins, remove_edges=rm)
+        ins, rm, ins_w = st.warmup
+        s = mt.apply_batch(insert_edges=ins, remove_edges=rm,
+                           insert_weights=ins_w)
         _block(mt, s)
         s = jax.device_get(s)
         counts_off += abs(int(s.n_removed) - len(rm)) + abs(
@@ -258,10 +306,11 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
                 log(f"the stream ran out after {i} bursts: the window ends "
                     f"at {time.perf_counter() - w0:.3f} s")
                 break
-            ins, rm = st.burst_edges(i)
+            ins, rm, ins_w = st.burst_edges(i)
             b0 = time.perf_counter()
             with TraceAnnotation("bench.plan"):
-                s = mt.apply_batch(insert_edges=ins, remove_edges=rm)
+                s = mt.apply_batch(insert_edges=ins, remove_edges=rm,
+                                   insert_weights=ins_w)
             b1 = time.perf_counter()
             with TraceAnnotation("bench.wait"):
                 _block(mt, s)
@@ -290,7 +339,8 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
     counts_off += sum(abs(b.removed - b.sent_removed)
                       + abs(b.inserted - b.sent_inserted) for b in bursts)
     state = {k: np.asarray(getattr(mt, k)) for k in
-             ("src", "dst", "valid", "core", "label", "n_edges")}
+             ("src", "dst", "valid", "core", "label", "n_edges")
+             + (("w",) if weighted else ())}
     last = len(bursts) - 1
     k = int(p_rng.integers(0, last)) if last > 0 else None
     probe = jax.device_get(snaps[k]) if k is not None else None
@@ -303,21 +353,34 @@ def run_cell(bench: Bench, cell_name: str, seed: int, seconds: float,
             f"{b.insert_rounds}, |V+| {b.v_plus}, applied {b.removed}+"
             f"{b.inserted} of {b.sent_removed}+{b.sent_inserted}")
 
-    live = st.live_after(last)
+    def edge_set(i):
+        """The host's edge keys after burst ``i``, with their weights
+        (None unweighted), and the reference's cores of them."""
+        if not weighted:
+            keys = st.live_after(i)
+            return keys, None, reference.core_numbers(n, keys)
+        keys, w = st.live_after(i, weights=True)
+        return keys, w, reference.weighted_core_numbers(n, keys, w)
+
     t0 = time.perf_counter()
-    want = reference.core_numbers(n, live)
+    live, live_w, want = edge_set(last)
     if probe is not None:
-        then = st.live_after(k)
-        probe = (*probe, then, reference.core_numbers(n, then))
+        then, _, want_then = edge_set(k)
+        probe = (*probe, then, want_then)
         log(f"probe: burst {k} of {last + 1}")
-    read = reference.readings(n, state, live, want, counts_off, probe)
+
+    def readings():
+        return reference.readings(n, state, live, want, counts_off, probe,
+                                  checks=checks, live_w=live_w)
+
+    read = readings()
     if control == "stale":
         # the reference in the program's place, one burst behind; the
         # program's own verdict is logged beside it
         log(f"control {control}: the program's own readings {read}, "
             f"correct={reference.verdict(read)}")
-        state["core"] = reference.core_numbers(n, st.live_after(last - 1))
-        read = reference.readings(n, state, live, want, counts_off, probe)
+        state["core"] = edge_set(last - 1)[2]
+        read = readings()
     elif control is not None:
         raise ValueError(f"unknown control {control!r}; know {CONTROLS}")
     correct = reference.verdict(read)

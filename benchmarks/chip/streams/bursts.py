@@ -23,6 +23,13 @@ absent ones: the same lanes as every later burst, all of them applied.
 Every burst's removals are live and its insertions absent when it is
 sent, so the count of edges applied equals the count sent.
 
+Given the graph's edge ``weights``, the stream keeps one weight per key
+it touches: the graph's edges keep their own, an edge re-inserted
+(``"removed"``) comes back with the weight it had, and the fresh absent
+pairs take ``draw_weights(k)`` in the order they were drawn. The
+weights come from a generator of their own, so ``rng`` draws the same
+numbers in the same order with weights as without.
+
 The stream is drawn over the graph as the generator made it, and
 ``perm`` then relabels the vertices of everything it returns: the
 harness draws the bursts from the traffic's own ``stream_seed`` and the
@@ -47,10 +54,12 @@ class Stream:
     pool: np.ndarray      # sorted keys of every edge the stream touches
     live0: np.ndarray     # bool mask over ``pool`` after the warm-up
     steps: list           # per burst (insert, remove) as indices into pool
-    edges: list           # per burst (insert_edges, remove_edges)
-    warmup: tuple         # (insert_edges, remove_edges) of the warm-up
+    edges: list           # per burst (insert_edges, remove_edges,
+    #                       insert_weights); the weights None unweighted
+    warmup: tuple         # the same three of the warm-up
     period: Optional[int]  # bursts repeat with this period; None: finite
     max_live: int         # the most edges live after any burst
+    weights: Optional[np.ndarray] = None  # per pool key; None: unweighted
 
     @property
     def n_bursts(self) -> Optional[int]:
@@ -58,18 +67,22 @@ class Stream:
         return None if self.period else len(self.steps)
 
     def burst_edges(self, i: int):
-        """``(insert_edges, remove_edges)`` of window burst ``i``."""
+        """``(insert_edges, remove_edges, insert_weights)`` of window
+        burst ``i``; ``insert_weights`` is None on an unweighted
+        stream."""
         return self.edges[i % self.period if self.period else i]
 
-    def live_after(self, i: int) -> np.ndarray:
+    def live_after(self, i: int, weights: bool = False):
         """Sorted keys of the edge set after window burst ``i`` (``-1``:
-        after the warm-up)."""
+        after the warm-up); with ``weights``, ``(keys, their
+        weights)``."""
         mask = self.live0.copy()
         last = i % self.period if self.period and i >= 0 else i
         for ins, rm in self.steps[: last + 1]:
             mask[rm] = False
             mask[ins] = True
-        return self.pool[mask]
+        return (self.pool[mask], self.weights[mask]) if weights else \
+            self.pool[mask]
 
 
 def _sample(rng, mask: np.ndarray, k: int, excluded=None) -> np.ndarray:
@@ -107,10 +120,13 @@ def _absent(rng, n: int, live: np.ndarray, k: int) -> np.ndarray:
 
 
 def build(n: int, edges: np.ndarray, traffic: dict,
-          rng: np.random.Generator, perm: np.ndarray) -> Stream:
+          rng: np.random.Generator, perm: np.ndarray, weights=None,
+          draw_weights=None) -> Stream:
     """The stream of one run over the graph ``edges`` (unique, sorted by
     key, as the generators return them), with vertex ``v`` renamed
-    ``perm[v]`` in all it returns."""
+    ``perm[v]`` in all it returns. A weighted stream takes the edges'
+    positive integer ``weights``, row for row, and ``draw_weights(k)``,
+    ``k`` weights for fresh pairs from a generator apart from ``rng``."""
     n_rm, n_ins = int(traffic["remove"]), int(traffic["insert"])
     rm_from = traffic.get("remove_from", "live")
     ins_from = traffic.get("insert_from", "absent")
@@ -137,6 +153,13 @@ def build(n: int, edges: np.ndarray, traffic: dict,
     fresh_idx = np.searchsorted(pool, fresh)
     mask = np.zeros(pool.size, dtype=bool)
     mask[np.searchsorted(pool, live)] = True
+    pool_w = None
+    if weights is not None:
+        pool_w = np.zeros(pool.size, dtype=np.int64)
+        pool_w[np.searchsorted(pool, live)] = weights
+        pool_w[fresh_idx] = draw_weights(fresh.size)
+        if (pool_w < 1).any():
+            raise ValueError("edge weights must be positive integers")
     # sliding-window expiry: live edges in the order they expire
     queue = np.concatenate([rng.permutation(np.flatnonzero(mask)),
                             np.zeros(n_ins * (count + 1), dtype=np.int64)])
@@ -186,14 +209,22 @@ def build(n: int, edges: np.ndarray, traffic: dict,
     new[order] = np.arange(order.size)
     pool = keys[order]
 
+    if pool_w is not None:
+        pool_w = pool_w[order]
+
     def as_edges(idx):
         k = pool[new[idx]]
         return np.stack([k // n, k % n], axis=1)
 
+    def burst(ins, rm):
+        w = None if pool_w is None else pool_w[new[ins]]
+        return as_edges(ins), as_edges(rm), w
+
     return Stream(
         n=n, pool=pool, live0=live0[order],
         steps=[(new[i], new[r]) for i, r in steps],
-        edges=[(as_edges(i), as_edges(r)) for i, r in steps],
-        warmup=(as_edges(warm[0]), as_edges(warm[1])),
+        edges=[burst(i, r) for i, r in steps],
+        warmup=burst(*warm),
         period=count if cyclic else None, max_live=max_live,
+        weights=pool_w,
     )

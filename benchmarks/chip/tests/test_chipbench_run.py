@@ -19,6 +19,10 @@ from benchmarks.chip import harness  # noqa: E402
 
 SEED = 2**31 + 12345  # past 32 signed bits, as the benchmark's seeds are
 CELL = "tiny.burst"
+WCELL = "tinyw.burst"  # the same graph and bursts with edge weights
+DATA = Path(__file__).resolve().parent / "data"
+WEIGHTED_CHECKS = ["core_mismatch", "slot_table_diff", "n_edges_diff",
+                   "burst_count_diff", "probe_core_mismatch"]
 
 
 @pytest.fixture(scope="module")
@@ -32,6 +36,22 @@ def bench(tmp_path_factory):
     cfg = json.loads((root / "configs" / "rmat16.json").read_text())
     cfg.update(name="tiny", scale=9, capacity=1 << 14)
     (root / "configs" / "tiny.json").write_text(json.dumps(cfg))
+    # a weighted configuration: its generator draws the weights, and it
+    # names the checks the weighted engine's guarantees imply
+    shutil.copy(DATA / "rmat_uniform_weights.py", root / "generators")
+    weighted = dict(cfg, name="tinyw", generator="rmat_uniform_weights",
+                    max_weight=8, checks=WEIGHTED_CHECKS,
+                    maintainer=dict(cfg["maintainer"], weighted=True))
+    # refused: a check the harness does not know (before the generator,
+    # which would raise, is called), and a weighted maintainer fed by a
+    # generator that returns no weights
+    (root / "generators" / "boom.py").write_text(
+        "def generate(params, rng):\n    raise RuntimeError('called')\n")
+    unknown = dict(cfg, name="tinyu", generator="boom",
+                   checks=cfg["checks"] + ["no_such_check"])
+    unweighed = dict(weighted, name="tinyv", generator="rmat")
+    for c in (weighted, unknown, unweighed):
+        (root / "configs" / f"{c['name']}.json").write_text(json.dumps(c))
     (root / "traffic" / "tiny.json").write_text(json.dumps(
         {"stream": "bursts", "stream_seed": 5, "remove": 48, "insert": 48,
          "insert_from": "removed", "cycle": 6}))
@@ -51,11 +71,14 @@ def bench(tmp_path_factory):
     (root / "metrics" / "extra.bursts.py").write_text(
         "def read(run):\n    return len(run.bursts)\n")
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "tiny",
-                            "file": str(root / "configs" / "tiny.json")})
+    for name in ("tiny", "tinyw", "tinyu", "tinyv"):
+        spec["configs"].append({"name": name, "file": str(
+            root / "configs" / f"{name}.json")})
     for cell, traffic in ((CELL, "tiny"), ("tiny.insert", "tiny_insert"),
-                          ("tiny.remove", "tiny_remove")):
-        spec["workloads"].append({"name": cell, "config": "tiny",
+                          ("tiny.remove", "tiny_remove"), (WCELL, "tiny"),
+                          ("tinyw.insert", "tiny_insert"),
+                          ("tinyu.burst", "tiny"), ("tinyv.burst", "tiny")):
+        spec["workloads"].append({"name": cell, "config": cell.split(".")[0],
                                   "traffic": traffic, "chips": 1})
     # an end-to-end metric that only the new cell reports
     (root / "metrics" / "burst_max_s.py").write_text(
@@ -80,13 +103,19 @@ def _run(bench, traced=False, control=None, seconds=0.3, seed=SEED,
                             control=control)
 
 
-def test_result_line_has_the_contract_keys(bench):
-    line = _run(bench)
+@pytest.mark.parametrize("cell,metrics,checks", [
+    (CELL, {"edges_per_s", "burst_max_s", "setup_s"}, harness.reference.CHECKS),
+    (WCELL, {"edges_per_s", "setup_s"}, WEIGHTED_CHECKS),
+], ids=["unweighted", "weighted"])
+def test_result_line_has_the_contract_keys(bench, cell, metrics, checks):
+    line = _run(bench, cell=cell)
     assert list(line) == ["correct", "attempted", "failed", "metrics",
                           "device", "checks"]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
-    assert set(line["metrics"]) == {"edges_per_s", "burst_max_s", "setup_s"}
+    assert set(line["metrics"]) == metrics
+    # exactly the checks the configuration names, probe's among them
+    assert list(line["checks"]) == list(checks)
     for m in line["metrics"].values():
         assert m["value"] > 0 and m["unit"]
     assert set(line["device"]) >= {"platform", "kind", "count",
@@ -98,7 +127,8 @@ def test_result_line_has_the_contract_keys(bench):
 
 
 @pytest.mark.parametrize("cell,seconds", [("tiny.insert", 0.3),
-                                          ("tiny.remove", 60.0)])
+                                          ("tiny.remove", 60.0),
+                                          ("tinyw.insert", 0.3)])
 def test_mixes_of_other_shapes_run_from_new_files(bench, cell, seconds):
     line = _run(bench, cell=cell, seconds=seconds)
     assert line["correct"] is True and line["failed"] == 0
@@ -134,52 +164,100 @@ def test_traced_line_reads_the_per_layer_metrics(bench):
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
-def test_control_is_not_correct(bench):
-    line = _run(bench, control="stale")
+@pytest.mark.parametrize("cell", [CELL, WCELL], ids=["unweighted",
+                                                    "weighted"])
+def test_control_is_not_correct(bench, cell):
+    line = _run(bench, control="stale", cell=cell)
     assert line["correct"] is False
     assert line["checks"]["core_mismatch"]["value"] > 0
 
 
-def _unchanged(real, *a, **kw):
-    keep = [a[i].copy() for i in range(6)]
+@pytest.mark.parametrize("cell", ["tinyu.burst", "tinyv.burst"],
+                         ids=["unknown_check", "weighted_without_weights"])
+def test_configuration_is_refused(bench, cell):
+    with pytest.raises(ValueError, match="checks|weights"):
+        _run(bench, cell=cell)
+
+
+# where each program keeps its state, lanes and outputs: (src, dst, valid,
+# [w,] core, label, n_edges, lanes...) in, the state and the stats out
+AT = {
+    "apply_batch": {"state": 6, "core": 3, "ok": (8, 11)},
+    "apply_batch_weighted": {"state": 7, "core": 4, "ok": (10, 13), "w": 3,
+                             "ins_w": 9},
+}
+
+
+def _unchanged(real, at, *a, **kw):
+    keep = [a[i].copy() for i in range(at["state"])]
     out = real(*a, **kw)
-    return (*keep, out[6])
+    return (*keep, out[at["state"]])
 
 
-def _half_batch(real, *a, **kw):
+def _half_batch(real, at, *a, **kw):
     a = list(a)
-    for i in (8, 11):  # ins_ok, rm_ok: the second half of the lanes off
+    for i in at["ok"]:  # ins_ok, rm_ok: the second half of the lanes off
         ok = np.asarray(a[i]).copy()
         ok[len(ok) // 2:] = False
         a[i] = ok
     return real(*a, **kw)
 
 
-def _altered_core(real, *a, **kw):
+def _altered_core(real, at, *a, **kw):
     out = list(real(*a, **kw))
-    out[3] = out[3].at[7].add(1)
+    out[at["core"]] = out[at["core"]].at[7].add(1)
     return tuple(out)
 
 
-@pytest.mark.parametrize("fault,caught", [
-    (_unchanged, "slot_table_diff"),
-    (_half_batch, "burst_count_diff"),
-    (_altered_core, "core_mismatch"),
-    (_altered_core, "probe_core_mismatch"),
+def _unit_weights(real, at, *a, **kw):
+    a = list(a)
+    a[at["ins_w"]] = np.ones_like(np.asarray(a[at["ins_w"]]))
+    return real(*a, **kw)
+
+
+def _altered_weight(real, at, *a, **kw):
+    out = list(real(*a, **kw))
+    slot = int(np.argmax(np.asarray(out[2])))  # the first valid slot
+    out[at["w"]] = out[at["w"]].at[slot].add(1)
+    return tuple(out)
+
+
+@pytest.mark.parametrize("program,fault,caught", [
+    ("apply_batch", _unchanged, ("slot_table_diff",)),
+    ("apply_batch", _half_batch, ("burst_count_diff",)),
+    ("apply_batch", _altered_core, ("core_mismatch",)),
+    ("apply_batch", _altered_core, ("probe_core_mismatch",)),
+    ("apply_batch_weighted", _unchanged, ("slot_table_diff",)),
+    ("apply_batch_weighted", _half_batch, ("burst_count_diff",)),
+    ("apply_batch_weighted", _unit_weights,
+     ("core_mismatch", "slot_table_diff")),
+    ("apply_batch_weighted", _altered_weight, ("slot_table_diff",)),
+    ("apply_batch_weighted", _altered_core,
+     ("core_mismatch", "probe_core_mismatch")),
 ], ids=["state_unchanged", "half_batch", "answer_altered",
-        "answer_altered_earlier"])
-def test_planted_fault_is_not_correct(bench, monkeypatch, fault, caught):
+        "answer_altered_earlier", "weighted_state_unchanged",
+        "weighted_half_batch", "weighted_unit_weights",
+        "weighted_weight_altered", "weighted_answer_altered"])
+def test_planted_fault_is_not_correct(bench, monkeypatch, program, fault,
+                                      caught):
     """Each fault a one-chip cell can have, planted under the timed path
-    (the unified engine's batch program as ``CoreMaintainer`` calls it).
-    There is no exchange between chips to leave out on one chip."""
+    (the unified engine's batch program, unweighted or weighted, as
+    ``CoreMaintainer`` calls it). There is no exchange between chips to
+    leave out on one chip. A weighted cell can also lose the insertions'
+    weights or alter a slot's weight where it is produced."""
     from repro.core import api
 
-    real = api.apply_batch
-    monkeypatch.setattr(api, "apply_batch",
-                        lambda *a, **kw: fault(real, *a, **kw))
-    line = _run(bench)
+    real = getattr(api, program)
+    monkeypatch.setattr(api, program,
+                        lambda *a, **kw: fault(real, AT[program], *a, **kw))
+    line = _run(bench, cell=WCELL if program.endswith("weighted") else CELL)
     assert line["correct"] is False
-    assert line["checks"][caught]["value"] > 0
+    # the weighted cell names no certificate check: the faults must show
+    # in the checks it does name
+    assert any(line["checks"][c]["value"] > 0 for c in caught)
+    if fault is _altered_core:  # both the end and the earlier burst
+        for c in caught:
+            assert line["checks"][c]["value"] > 0
 
 
 def test_benchmark_refuses_to_run_without_a_tpu(tmp_path):

@@ -87,3 +87,22 @@ def test_short_name_of_an_hlo_op():
     assert op.short == ("fusion.499 kCustom pred[1048576] <- "
                         "(pred[65536], s32[1048576])")
     assert tr.Op("%sort.1 = (s32[8]{0}) sort(s32[8]{0} %x)", 0, 1).kind == ""
+
+
+def test_idle_gaps_inside_apply_batch_take_the_programs_span_names():
+    # ba16.burst25k, one burst, TPU v5 lite, with the program's host spans
+    t = tr.read_xspace((RECORDED.parent / "ba16_burst25k_phases.xplane.pb.gz")
+                       .read_bytes())
+    names = {name for name, _, _ in t.spans}
+    assert {"bench.window", "coremaint.apply_batch",
+            "coremaint.transfer"} <= names
+    # the window is still the harness's span, not one of the program's
+    assert tr.window_s(t) == pytest.approx(11.388835933, abs=1e-9)
+    gaps = tr.idle_gaps(t)
+    assert sum(g for _, g in gaps) == pytest.approx(
+        tr.window_s(t) - tr.busy_s(t), rel=1e-6)
+    # the gap while apply_batch plans the burst, named by its step
+    assert gaps[0] == ("coremaint.transfer", pytest.approx(3.1626e-3,
+                                                           rel=1e-3))
+    assert {name for name, _ in gaps} <= {"bench.plan", "bench.wait",
+                                          "coremaint.transfer"}

@@ -6,11 +6,13 @@ planes are named ``/device:TPU:<i>``; their ``XLA Ops`` line holds one
 event per executed HLO op, named by the op's HLO text
 (``%fusion.499 = pred[1048576]{...} fusion(...), kind=kCustom, ...``).
 A ``while`` op's event spans its body's ops, so time per op is self
-time: an event's duration less that of the events nested in it. The
-harness's own host spans
-(``jax.profiler.TraceAnnotation``) are named ``bench.*`` and share the
-trace's clock: ``bench.window`` bounds the measured window, and the
-innermost span over an idle gap says what the host was doing in it.
+time: an event's duration less that of the events nested in it. Host
+spans (``jax.profiler.TraceAnnotation``) share the trace's clock: the
+harness's are named ``bench.*``, and ``bench.window`` bounds the
+measured window; the program's are named ``coremaint.*``
+(``coremaint.apply_batch`` and its steps ``coremaint.validate``,
+``coremaint.transfer``, ``coremaint.dispatch``, ...). The innermost of
+either over an idle gap says what the host was doing in it.
 """
 from __future__ import annotations
 
@@ -21,7 +23,7 @@ from pathlib import Path
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "coremaint.")
 WINDOW_SPAN = "bench.window"
 _HLO = re.compile(r"^%(\S+) = (.*?) ([a-z][a-z0-9-]*)\((.*)$")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
@@ -64,7 +66,7 @@ class Op:
 @dataclass
 class Trace:
     ops: dict            # device plane name -> [Op] on its XLA Ops line
-    spans: list          # host (name, start_ns, dur_ns), bench.* only
+    spans: list          # host (name, start_ns, dur_ns), SPAN_PREFIX only
 
     def window(self):
         """(start_ns, end_ns) of the ``bench.window`` span."""
@@ -184,7 +186,8 @@ def share_pct(trace: Trace, pred):
 def idle_gaps(trace: Trace) -> list:
     """``[(host span, seconds)]`` for every idle stretch of the first
     busy device inside the window, longest first. The host span is the
-    innermost ``bench.*`` span covering the gap's midpoint."""
+    innermost ``bench.*`` or ``coremaint.*`` span covering the gap's
+    midpoint."""
     lo, hi = trace.window()
     per = [ops for ops in trace.window_ops().values() if ops]
     edges = [lo] + [t for iv in busy_intervals(per[0] if per else [])
