@@ -96,6 +96,12 @@ EDGE_AXIS = "data"  # mesh axis the sharded engine shards edge slots over
 
 _ENGINES = ("unified", "host", "sharded")
 
+# the largest weighted degree the weighted engine holds: every core, the
+# promotion's start ``core + batch weight`` and every support sum stay at
+# or below a weighted degree, and the bisection's ``lo + hi + 1`` (with
+# lo <= hi) must fit int32
+STRENGTH_MAX = (2**31 - 2) // 2
+
 
 class BatchCall(NamedTuple):
     """One ``CoreMaintainer.apply_batch`` call as a profiler reads it
@@ -314,6 +320,9 @@ class CoreMaintainer:
     live_ub: int = -1           # upper bound on live edges (-1: from valid)
     hwm_ub: int = -1            # upper bound on the per-shard slot
     #                             high-water mark (-1: compute from valid)
+    strength_ub: int = -1       # weighted only: upper bound on every
+    #                             vertex's weighted degree, so on every
+    #                             weighted core (-1: compute from the table)
     _last_window: int = dataclasses.field(default=0, repr=False)
     host_renumbered: bool = False  # last host-path call triggered a renumber
     _sharded_fns: Dict[Tuple[int, int], Callable] = dataclasses.field(
@@ -446,6 +455,8 @@ class CoreMaintainer:
             idx = np.nonzero(val)[0]
             self.live_ub = int(idx.shape[0])
             self.hwm_ub = int(idx[-1]) + 1 if idx.size else 0
+        if self.weighted and self.strength_ub < 0:
+            self.strength_ub = self._max_strength()
         if self.engine == "host":
             # the host path bump-allocates from n_edges: it must cover the
             # high-water mark (device-engine saves store the live count)
@@ -680,7 +691,14 @@ class CoreMaintainer:
             deg_w = np.zeros(g.n, dtype=np.int64)
             np.add.at(deg_w, edges[:, 0], wv)
             np.add.at(deg_w, edges[:, 1], wv)
-            core, _, _ = weighted_core_fixpoint_pass(
+            strength = int(deg_w.max()) if g.n else 0
+            if strength > STRENGTH_MAX:
+                raise ValueError(
+                    f"a vertex's weighted degree is {strength}; the "
+                    f"weighted engine holds at most {STRENGTH_MAX} "
+                    f"(int32 sums)"
+                )
+            core, _, _, _ = weighted_core_fixpoint_pass(
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(val),
                 jnp.asarray(wcol), jnp.asarray(deg_w.astype(np.int32)),
                 g.n,
@@ -713,6 +731,7 @@ class CoreMaintainer:
                 validate=validate,
                 live_ub=m,
                 hwm_ub=m,
+                strength_ub=strength,
             )
         if init == "host-bz":
             adj = [set(g.neighbors(v).tolist()) for v in range(g.n)]
@@ -881,6 +900,8 @@ class CoreMaintainer:
                                                  dtype=np.int64)
                     ins, ins_wts = self._validated(insert_edges, "insert",
                                                    weights=insert_weights)
+                    batch_w = int(ins_wts.sum())
+                    self._check_strength(batch_w)
                 else:
                     ins = self._validated(insert_edges, "insert")
                 rm = self._validated(remove_edges, "remove")
@@ -910,6 +931,8 @@ class CoreMaintainer:
                     n_overflow=jnp.int32(0),
                     forward_waves=in_st.forward_waves,
                     evict_waves=in_st.evict_waves,
+                    remove_bisect_steps=jnp.int32(0),
+                    insert_bisect_steps=jnp.int32(0),
                 )
                 self.last_batch_stats = stats
                 RECENT_CALLS.append(BatchCall(stats))
@@ -923,6 +946,7 @@ class CoreMaintainer:
                     renumbered=jnp.bool_(False), n_recycled=z,
                     high_water=jnp.int32(self.hwm_ub), max_frontier=z,
                     n_overflow=z, forward_waves=z, evict_waves=z,
+                    remove_bisect_steps=z, insert_bisect_steps=z,
                 )
                 self.last_batch_stats = stats
                 RECENT_CALLS.append(BatchCall(stats))
@@ -1020,6 +1044,9 @@ class CoreMaintainer:
             # (_refresh_bounds).
             self.hwm_ub = min(self.hwm_ub + b_ins, self._local_cap)
             self.live_ub = min(self.live_ub + b_ins, self.capacity)
+            if self.weighted:
+                # an insert raises a weighted degree by at most its weight
+                self.strength_ub += batch_w
             self.slot_cache = None
             self.last_batch_stats = stats
             RECENT_CALLS.append(BatchCall(stats, program, shapes, kwargs))
@@ -1173,6 +1200,32 @@ class CoreMaintainer:
             if self.last_batch_stats is not None:
                 self.hwm_ub = int(self.last_batch_stats.high_water)
             self.live_ub = int(self.n_edges)
+
+    def _max_strength(self) -> int:
+        """The largest weighted degree of the live slot table (a sync)."""
+        val = np.asarray(self.valid)
+        w = np.asarray(self.w)[val]
+        deg = sum(np.bincount(np.asarray(end)[val], w, minlength=self.n)
+                  for end in (self.src, self.dst))
+        return int(deg.max()) if self.n else 0
+
+    def _check_strength(self, batch_w: int) -> None:
+        """Refuse a weighted batch whose inserted weight ``batch_w`` could
+        lift a weighted degree, or the promotion's start ``core +
+        batch weight``, past ``STRENGTH_MAX``, before any state changes.
+        The sync-free bound ``strength_ub`` only grows; where it would
+        cross the limit it is first replaced by the table's exact
+        largest weighted degree (one sync), so removals are counted."""
+        if self.strength_ub + batch_w <= STRENGTH_MAX:
+            return
+        self.strength_ub = self._max_strength()
+        if self.strength_ub + batch_w > STRENGTH_MAX:
+            raise ValueError(
+                f"inserting total weight {batch_w} onto a largest "
+                f"weighted degree of {self.strength_ub} could exceed "
+                f"{STRENGTH_MAX}, the most the weighted engine's int32 "
+                f"sums hold"
+            )
 
     def _ensure_capacity(self, b_ins: int) -> None:
         """Make the per-shard window able to hold the live slots plus this

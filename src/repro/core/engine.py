@@ -109,6 +109,11 @@ class BatchStats(NamedTuple):
     evict_waves: Array     # EVICT iterations over all promotion rounds;
     #                        a batch's passes over the slot table are
     #                        remove_rounds + insert_rounds + both waves
+    #                        (unweighted)
+    remove_bisect_steps: Array  # weighted h-index bisection steps over
+    #                             all removal rounds, one support pass
+    #                             each (0 unweighted)
+    insert_bisect_steps: Array  # the same over all promotion rounds
 
 
 def edge_key(lo: Array, hi: Array, n: int) -> Array:
@@ -266,12 +271,14 @@ def batch_program(
     core_pre_rm = core
     with jax.named_scope("coremaint.remove.stats"):
         if w is not None:
-            core, rm_rounds, rm_fmax = weighted_core_fixpoint_pass(
+            (core, rm_rounds, rm_fmax,
+             rm_steps) = weighted_core_fixpoint_pass(
                 src, dst, valid, w, core, n, layout=layout,
                 kernel_backend=kernel_backend,
             )
             hi = dout_same = layout.zeros()
         else:
+            rm_steps = jnp.int32(0)
             (core, label, rm_rounds, hi, dout_same,
              rm_fmax) = removal_fixpoint(
                 src, dst, valid, core, label, n, n_levels, layout=layout,
@@ -327,13 +334,15 @@ def batch_program(
             # under sharding (freelist_alloc narrows it from all-gathered
             # counts), so the sum needs no collective
             total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
-            core, ins_rounds, ins_fmax = weighted_promotion_fixpoint(
+            (core, ins_rounds, ins_fmax,
+             ins_steps) = weighted_promotion_fixpoint(
                 src, dst, valid, w, core, total_w, n, layout=layout,
                 kernel_backend=kernel_backend,
             )
             v_plus = core != core_pre_ins
         fwd_waves = ev_waves = jnp.int32(0)
     else:
+        ins_steps = jnp.int32(0)
         with jax.named_scope("coremaint.promote.seed"):
             # O(batch) delta keeps the shared (hi, dout_same) statistics
             # exact for the table with the new edges — same per-edge
@@ -394,6 +403,8 @@ def batch_program(
         n_overflow=jnp.int32(0),
         forward_waves=fwd_waves,
         evict_waves=ev_waves,
+        remove_bisect_steps=rm_steps,
+        insert_bisect_steps=ins_steps,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats
@@ -522,14 +533,15 @@ def batch_program_halo(
     core_pre_rm = core
     with jax.named_scope("coremaint.remove.stats"):
         if w is not None:
-            (core, core_h, rm_rounds,
-             rm_fmax) = weighted_core_fixpoint_pass_halo(
+            (core, core_h, rm_rounds, rm_fmax,
+             rm_steps) = weighted_core_fixpoint_pass_halo(
                 src_h, dst_h, valid, w, core, core_h, session,
                 kernel_backend=kernel_backend,
             )
             hi = dout_same = session.zeros()
             rm_ovf = jnp.int32(0)
         else:
+            rm_steps = jnp.int32(0)
             (core, label, core_h, label_h, rm_rounds, hi, dout_same,
              rm_fmax, rm_ovf) = removal_fixpoint_halo(
                 src_h, dst_h, valid, core, label, core_h, label_h, session,
@@ -570,14 +582,15 @@ def batch_program_halo(
     if w is not None:
         with jax.named_scope("coremaint.promote.stats"):
             total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
-            (core, core_h, ins_rounds,
-             ins_fmax) = weighted_promotion_fixpoint_halo(
+            (core, core_h, ins_rounds, ins_fmax,
+             ins_steps) = weighted_promotion_fixpoint_halo(
                 src_h, dst_h, valid, w, core, core_h, total_w, session,
                 kernel_backend=kernel_backend,
             )
             v_plus = core != core_pre_ins
         ins_ovf = fwd_waves = ev_waves = jnp.int32(0)
     else:
+        ins_steps = jnp.int32(0)
         with jax.named_scope("coremaint.promote.seed"):
             u_pos = session.locate(ilo)
             v_pos = session.locate(ihi)
@@ -634,6 +647,8 @@ def batch_program_halo(
         n_overflow=rm_ovf + ins_ovf,
         forward_waves=fwd_waves,
         evict_waves=ev_waves,
+        remove_bisect_steps=rm_steps,
+        insert_bisect_steps=ins_steps,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats

@@ -33,7 +33,7 @@ stay bit-identical (integer adds in a different order).
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -379,7 +379,7 @@ def weighted_h_index(
     src: Array, dst: Array, valid: Array, w: Array, core: Array,
     upper: Array, n: int, layout: Optional[VertexLayout] = None,
     backend: str = "lax",
-) -> Array:
+) -> Tuple[Array, Array]:
     """Per-vertex weighted h-index by lockstep bisection:
     ``H_w(v) = max{h <= upper[v] : sum of weights to nbrs with
     core >= h is >= h}`` (Zhou et al., WWW'21). The feasible set is a
@@ -388,25 +388,29 @@ def weighted_h_index(
     support pass over the edge window. The invariant is lo-feasible
     (``lo = 0`` trivially so); converged lanes re-test ``mid == lo``
     and stay fixed, so the while_loop runs until the SLOWEST lane
-    converges with every lane stable. Replicated/plain layouts only —
-    the halo twin lives in core/remove.py next to its fixpoint."""
+    converges with every lane stable. Returns ``(h, steps)``: ``steps``
+    is the loop's iteration count, one support pass each. Replicated/
+    plain layouts only — the halo twin lives in core/remove.py next to
+    its fixpoint."""
     upper = jnp.maximum(upper.astype(jnp.int32), 0)
     lo = jnp.zeros_like(upper)
 
     def cond(state):
-        lo_, hi_ = state
+        lo_, hi_, _ = state
         return jnp.any(lo_ < hi_)
 
     def body(state):
-        lo_, hi_ = state
+        lo_, hi_, steps = state
         mid = (lo_ + hi_ + 1) // 2
         s = weighted_support(src, dst, valid, w, core, mid, n,
                              layout, backend)
         ok = s >= mid
-        return jnp.where(ok, mid, lo_), jnp.where(ok, hi_, mid - 1)
+        return (jnp.where(ok, mid, lo_), jnp.where(ok, hi_, mid - 1),
+                steps + 1)
 
-    lo, _ = jax.lax.while_loop(cond, body, (lo, upper))
-    return lo
+    lo, _, steps = jax.lax.while_loop(cond, body,
+                                      (lo, upper, jnp.int32(0)))
+    return lo, steps
 
 
 def expand_forward(

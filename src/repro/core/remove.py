@@ -225,7 +225,7 @@ def weighted_core_fixpoint_pass(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
-) -> Tuple[Array, Array, Array]:
+) -> Tuple[Array, Array, Array, Array]:
     """Decrease-only weighted h-index fixpoint (Zhou et al., WWW'21):
     per round ``core <- min(core, H_w(core))`` where ``H_w`` is the
     per-vertex weighted h-index bisection (graph_ops.weighted_h_index),
@@ -239,8 +239,9 @@ def weighted_core_fixpoint_pass(
     append order to maintain (levels are unbounded in maxW, so the
     bucketed ``place_block`` does not apply); the engine commits ONE
     bucket-free renumber per batch instead. Returns ``(core, rounds,
-    max_frontier)``. Replicated/plain layouts only — the halo twin is
-    ``weighted_core_fixpoint_pass_halo``."""
+    max_frontier, steps)``, ``steps`` the bisection steps (support
+    passes) summed over the rounds. Replicated/plain layouts only — the
+    halo twin is ``weighted_core_fixpoint_pass_halo``."""
     if layout is None:
         layout = ReplicatedVertices(n)
 
@@ -248,19 +249,19 @@ def weighted_core_fixpoint_pass(
         return state[1]
 
     def body(state):
-        core, _, rounds, fmax = state
-        h = G.weighted_h_index(src, dst, valid, w, core, core, n,
-                               layout, backend=kernel_backend)
+        core, _, rounds, fmax, steps = state
+        h, k = G.weighted_h_index(src, dst, valid, w, core, core, n,
+                                  layout, backend=kernel_backend)
         new_core = jnp.minimum(core, h)
         changed = new_core < core
         fmax = jnp.maximum(fmax, layout.frontier_peak(changed))
-        return new_core, jnp.any(changed), rounds + 1, fmax
+        return new_core, jnp.any(changed), rounds + 1, fmax, steps + k
 
-    core, _, rounds, fmax = jax.lax.while_loop(
+    core, _, rounds, fmax, steps = jax.lax.while_loop(
         cond, body,
-        (core, jnp.bool_(True), jnp.int32(0), jnp.int32(0)),
+        (core, jnp.bool_(True), jnp.int32(0), jnp.int32(0), jnp.int32(0)),
     )
-    return core, rounds, fmax
+    return core, rounds, fmax, steps
 
 
 def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
@@ -274,7 +275,8 @@ def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
     step; dense is the right exchange here). Continuation is carried in
     the loop STATE (one ``any_owned`` psum per step) so the while cond
     stays collective-free and every shard runs the same trip count.
-    Returns ``(lo_own, lo_halo)`` — the h-index and its halo image."""
+    Returns ``(lo_own, lo_halo, steps)`` — the h-index, its halo image
+    and the loop's iteration count."""
     hcap = session.halo_cap
     lo_o = jnp.zeros_like(core_own)
     hi_o = jnp.maximum(core_own, 0)
@@ -285,7 +287,7 @@ def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
         return state[4]
 
     def body(state):
-        lo_o, hi_o, lo_h, hi_h, _ = state
+        lo_o, hi_o, lo_h, hi_h, _, steps = state
         mid_o = (lo_o + hi_o + 1) // 2
         mid_h = (lo_h + hi_h + 1) // 2
         s = G.weighted_support(src_h, dst_h, valid, w, core_h, mid_h,
@@ -297,13 +299,13 @@ def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
         lo_h = jnp.where(ok_h, mid_h, lo_h)
         hi_h = jnp.where(ok_h, hi_h, mid_h - 1)
         cont = session.any_owned(lo_o < hi_o)
-        return lo_o, hi_o, lo_h, hi_h, cont
+        return lo_o, hi_o, lo_h, hi_h, cont, steps + 1
 
     cont0 = session.any_owned(lo_o < hi_o)
-    lo_o, _, lo_h, _, _ = jax.lax.while_loop(
-        cond, body, (lo_o, hi_o, lo_h, hi_h, cont0)
+    lo_o, _, lo_h, _, _, steps = jax.lax.while_loop(
+        cond, body, (lo_o, hi_o, lo_h, hi_h, cont0, jnp.int32(0))
     )
-    return lo_o, lo_h
+    return lo_o, lo_h, steps
 
 
 def weighted_core_fixpoint_pass_halo(
@@ -323,16 +325,17 @@ def weighted_core_fixpoint_pass_halo(
     update is the same local ``min`` (sentinel rows hold 0 and stay 0;
     no valid edge references them). Labels are frozen (see the plain
     twin); the engine runs one ring renumber per batch afterwards.
-    Returns ``(core_own, core_h, rounds, max_frontier)`` with
+    Returns ``(core_own, core_h, rounds, max_frontier, steps)`` with
     ``max_frontier`` the LOCAL running per-round owned change count
-    (completed by the engine's batch-end pmax)."""
+    (completed by the engine's batch-end pmax); every shard runs the
+    same trip counts, so ``steps`` needs no collective."""
 
     def cond(state):
         return state[2]
 
     def body(state):
-        core_own, core_h, _, rounds, fmax = state
-        lo_o, lo_h = _weighted_h_index_halo(
+        core_own, core_h, _, rounds, fmax, steps = state
+        lo_o, lo_h, k = _weighted_h_index_halo(
             src_h, dst_h, valid, w, core_own, core_h, session,
             kernel_backend=kernel_backend,
         )
@@ -341,13 +344,14 @@ def weighted_core_fixpoint_pass_halo(
         changed = new_o < core_own
         fmax = jnp.maximum(fmax, session.frontier_peak(changed))
         cont = session.any_owned(changed)
-        return new_o, new_h, cont, rounds + 1, fmax
+        return new_o, new_h, cont, rounds + 1, fmax, steps + k
 
-    core_own, core_h, _, rounds, fmax = jax.lax.while_loop(
+    core_own, core_h, _, rounds, fmax, steps = jax.lax.while_loop(
         cond, body,
-        (core_own, core_h, jnp.bool_(True), jnp.int32(0), jnp.int32(0)),
+        (core_own, core_h, jnp.bool_(True), jnp.int32(0), jnp.int32(0),
+         jnp.int32(0)),
     )
-    return core_own, core_h, rounds, fmax
+    return core_own, core_h, rounds, fmax, steps
 
 
 @partial(jax.jit, static_argnames=("n", "n_levels"))

@@ -297,6 +297,11 @@ def _run_weighted_churn_differential(m0, graph_seed, stream_seed,
         for e, st_ in stats.items():
             assert int(st_.n_inserted) == inserted, e
             assert int(st_.n_removed) == removed, e
+        # the sharded program runs the same bisection steps
+        assert [(int(st_.remove_bisect_steps), int(st_.insert_bisect_steps))
+                for st_ in stats.values()] == [
+            (int(stats["weighted"].remove_bisect_steps),
+             int(stats["weighted"].insert_bisect_steps))] * len(stats)
         assert ms["weighted_sharded"].edge_slot.keys() == \
             u.edge_slot.keys()
         # the stored weight column mirrors the live map exactly

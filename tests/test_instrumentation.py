@@ -101,6 +101,12 @@ def test_wave_counters_on_a_removal_only_batch():
     for name in ("host", "weighted"):
         assert int(st[name].forward_waves) == 0, name
         assert int(st[name].evict_waves) == 0, name
+    # bisection steps exist on the weighted path only
+    for name in ("host", "unified"):
+        assert int(st[name].remove_bisect_steps) == 0, name
+        assert int(st[name].insert_bisect_steps) == 0, name
+    assert int(st["weighted"].remove_bisect_steps) > 0
+    assert int(st["weighted"].insert_bisect_steps) > 0
     # the unified program still runs its one promotion round with no
     # seeds: one forward wave and one evict wave, each a real pass
     u = st["unified"]
@@ -137,6 +143,73 @@ def test_weighted_engine_counts_no_waves():
             insert_weights=rng.integers(1, 4, len(ev.edges)))
         assert int(st.insert_rounds) > 0
         assert int(st.forward_waves) == int(st.evict_waves) == 0
+
+
+def _replay_weighted_fixpoint(n, live, start):
+    """A plain NumPy replay of the weighted fixpoint on the ``(lo, hi)
+    -> weight`` edge set ``live``, from the upper bound ``start``: each
+    round bisects every vertex's weighted h-index in lockstep, ``mid =
+    (lo + hi + 1) // 2`` kept where the weight to neighbours of core >=
+    mid reaches mid, until no lane moves; rounds run until no core
+    drops. Returns ``(cores, steps)``, the steps summed over the rounds
+    (the last, unchanging round's included)."""
+    e = np.asarray(sorted(live), dtype=np.int64).reshape(-1, 2)
+    w = np.asarray([live[k] for k in sorted(live)], dtype=np.int64)
+    u, v = e[:, 0], e[:, 1]
+    core, steps = np.asarray(start, dtype=np.int64), 0
+    while True:
+        lo, hi = np.zeros(n, np.int64), np.maximum(core, 0)
+        while (lo < hi).any():
+            mid = (lo + hi + 1) // 2
+            s = (np.bincount(u, w * (core[v] >= mid[u]), minlength=n)
+                 + np.bincount(v, w * (core[u] >= mid[v]), minlength=n))
+            ok = s >= mid
+            lo, hi = np.where(ok, mid, lo), np.where(ok, hi, mid - 1)
+            steps += 1
+        new = np.minimum(core, lo)
+        if (new == core).all():
+            return core, steps
+        core = new
+
+
+@pytest.mark.parametrize("config", [
+    {}, dict(engine="sharded"),
+    # the halo program on a degenerate (1, 1) mesh: its own loops
+    dict(engine="sharded", vertex_sharding="halo"),
+], ids=["unified", "sharded", "halo"])
+def test_bisection_steps_match_a_numpy_replay(config):
+    g = erdos_renyi(30, 110, seed=4)
+    rng = np.random.default_rng(11)
+    w0 = rng.integers(1, 9, g.m)
+    m = CoreMaintainer.from_graph(g, capacity=4 * g.m + 64, weighted=True,
+                                  weights=w0, **config)
+    norm = [tuple(sorted(map(int, e))) for e in g.edge_array()]
+    live = dict(zip(norm, map(int, w0)))
+    steps = []
+    for ev in churn_stream(g, 3, 10, seed=2, p_reinsert=0.5):
+        iw = rng.integers(1, 9, len(ev.edges))
+        core0 = m.cores()
+        st = m.apply_batch(insert_edges=ev.edges, remove_edges=ev.removals,
+                           insert_weights=iw)
+        for e in ev.removals:
+            live.pop(tuple(sorted(map(int, e))), None)
+        core1, rm_steps = _replay_weighted_fixpoint(g.n, live, core0)
+        total_w = 0  # the weight the batch really inserted
+        for e, wt in zip(ev.edges, iw):
+            k = tuple(sorted(map(int, e)))
+            if k[0] != k[1] and k not in live:
+                live[k] = int(wt)
+                total_w += int(wt)
+        core2, ins_steps = _replay_weighted_fixpoint(g.n, live,
+                                                     core1 + total_w)
+        np.testing.assert_array_equal(m.cores(), core2)
+        assert (int(st.remove_bisect_steps),
+                int(st.insert_bisect_steps)) == (rm_steps, ins_steps)
+        assert int(st.forward_waves) == int(st.evict_waves) == 0
+        steps.append(rm_steps + ins_steps)
+    # what the unified engine counts on these seeded batches: the
+    # sharded and halo programs count the same
+    assert steps == [62, 107, 77]
 
 
 def test_recent_calls_record_each_batch_and_its_program():

@@ -70,6 +70,21 @@ def test_unified_batch_program_compiles_for_one_v5e_chip(topo):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 << 30
 
 
+def test_weighted_batch_program_compiles_for_one_v5e_chip(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    a = _batch_program_args(one, one, one)
+    w = jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one)
+    ins_w = jax.ShapeDtypeStruct((LANES,), jnp.int32, sharding=one)
+    # (src, dst, valid, w, core, label, n_edges, ins_u, ins_v, ins_w,
+    # ins_ok, rm_u, rm_v, rm_ok)
+    args = a[:3] + [w] + a[3:8] + [ins_w] + a[8:]
+    compiled = engine.apply_batch_weighted.lower(
+        *args, n=N, n_levels=N + 2, active_cap=CAP // 2,
+        kernel_backend="lax",
+    ).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
 def test_halo_batch_program_compiles_on_v5e_2x2_mesh(topo):
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("edge", "data"))
     fn = make_sharded_apply(mesh, N, N + 2, axis="data",

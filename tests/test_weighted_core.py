@@ -67,3 +67,49 @@ def test_unit_weights_reduce_to_unweighted_cores():
     ones = np.ones(edges.shape[0], np.int32)
     m = WeightedCoreMaintainer(g.n, edges, ones)
     np.testing.assert_array_equal(m.cores(), bz_from_csr(g))
+
+
+def _heavy_maintainer(w_big):
+    """A 4-cycle with one edge of weight ``w_big``, on the unified
+    weighted engine."""
+    from repro.core.api import maintainer_from_edges
+
+    edges = np.array([[0, 1], [1, 2], [2, 3], [0, 3]])
+    return maintainer_from_edges(4, edges, weighted=True,
+                                 weights=[w_big, 1, 1, 1], capacity=64)
+
+
+def test_from_graph_refuses_strengths_past_int32():
+    from repro.core.api import STRENGTH_MAX
+
+    m = _heavy_maintainer(STRENGTH_MAX - 1)  # vertex 0: STRENGTH_MAX
+    assert m.strength_ub == STRENGTH_MAX
+    with pytest.raises(ValueError, match="weighted degree"):
+        _heavy_maintainer(STRENGTH_MAX)
+    with pytest.raises(ValueError, match="weighted degree"):
+        _heavy_maintainer(2**31 + 5)  # would wrap in an int32 cast
+
+
+def test_apply_batch_refuses_weight_past_int32_atomically():
+    from repro.core.api import STRENGTH_MAX
+
+    big = STRENGTH_MAX // 2
+    m = _heavy_maintainer(big)
+    core0, slots0 = m.cores(), dict(m.edge_slot)
+    # one more edge of that weight onto vertex 0 fits; two do not
+    with pytest.raises(ValueError, match="could exceed"):
+        m.apply_batch(insert_edges=[[0, 2], [1, 3]],
+                      remove_edges=[[1, 2]], insert_weights=[big, big])
+    np.testing.assert_array_equal(m.cores(), core0)
+    assert m.edge_slot == slots0  # nothing applied, removals included
+    # removing the heavy edge first brings the exact bound down: the
+    # same heavy insertion is then taken, not refused
+    m.apply_batch(remove_edges=[[0, 1]])
+    m.apply_batch(insert_edges=[[0, 2]], insert_weights=[big + 2])
+    m.apply_batch(insert_edges=[[0, 1]], insert_weights=[big - 5])
+    assert m.strength_ub <= STRENGTH_MAX
+    np.testing.assert_array_equal(
+        m.cores(),
+        weighted_core_oracle(4, np.array([[1, 2], [2, 3], [0, 3], [0, 2],
+                                          [0, 1]]),
+                             np.array([1, 1, 1, big + 2, big - 5])))
