@@ -374,7 +374,7 @@ def trace_weighted_round(
     sm = shard_map(
         kernel, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
-        out_specs=(P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P()),
         check_vma=False,
     )
     src = jnp.zeros(cap, jnp.int32)

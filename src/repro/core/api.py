@@ -308,8 +308,10 @@ class CoreMaintainer:
     weighted: bool = False      # weight-generalized engine: the slot table
     #                             carries a per-edge integer weight column
     #                             and both maintenance phases run the
-    #                             weighted h-index bisection fixpoint
-    #                             (docs/DESIGN.md §4.5); device engines only
+    #                             weighted h-index fixpoint (one sort a
+    #                             round on one device, a bisection under
+    #                             a mesh axis; docs/DESIGN.md §4.5);
+    #                             device engines only
     w: Optional[jax.Array] = None  # [capacity] per-slot edge weights
     #                                (weighted=True only; None -> all-ones)
     validate: bool = True       # raise on out-of-range endpoints (else mask)
@@ -698,7 +700,7 @@ class CoreMaintainer:
                     f"weighted engine holds at most {STRENGTH_MAX} "
                     f"(int32 sums)"
                 )
-            core, _, _, _ = weighted_core_fixpoint_pass(
+            core, _, _, _, _ = weighted_core_fixpoint_pass(
                 jnp.asarray(src), jnp.asarray(dst), jnp.asarray(val),
                 jnp.asarray(wcol), jnp.asarray(deg_w.astype(np.int32)),
                 g.n,
@@ -947,6 +949,7 @@ class CoreMaintainer:
                     high_water=jnp.int32(self.hwm_ub), max_frontier=z,
                     n_overflow=z, forward_waves=z, evict_waves=z,
                     remove_bisect_steps=z, insert_bisect_steps=z,
+                    hindex_sorts=z if self.weighted else None,
                 )
                 self.last_batch_stats = stats
                 RECENT_CALLS.append(BatchCall(stats))

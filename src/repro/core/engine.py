@@ -45,7 +45,7 @@ per-batch critical path. See docs/DESIGN.md §3.
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +114,11 @@ class BatchStats(NamedTuple):
     #                             all removal rounds, one support pass
     #                             each (0 unweighted)
     insert_bisect_steps: Array  # the same over all promotion rounds
+    hindex_sorts: Optional[Array] = None  # weighted fixpoint rounds,
+    #                             removal plus promotion, whose h-index
+    #                             came from one sort (a layout with no
+    #                             mesh axis; 0 sharded and halo); None
+    #                             unweighted: no output of that program
 
 
 def edge_key(lo: Array, hi: Array, n: int) -> Array:
@@ -271,8 +276,8 @@ def batch_program(
     core_pre_rm = core
     with jax.named_scope("coremaint.remove.stats"):
         if w is not None:
-            (core, rm_rounds, rm_fmax,
-             rm_steps) = weighted_core_fixpoint_pass(
+            (core, rm_rounds, rm_fmax, rm_steps,
+             rm_sorts) = weighted_core_fixpoint_pass(
                 src, dst, valid, w, core, n, layout=layout,
                 kernel_backend=kernel_backend,
             )
@@ -334,8 +339,8 @@ def batch_program(
             # under sharding (freelist_alloc narrows it from all-gathered
             # counts), so the sum needs no collective
             total_w = jnp.sum(jnp.where(iok, ins_w, 0), dtype=jnp.int32)
-            (core, ins_rounds, ins_fmax,
-             ins_steps) = weighted_promotion_fixpoint(
+            (core, ins_rounds, ins_fmax, ins_steps,
+             ins_sorts) = weighted_promotion_fixpoint(
                 src, dst, valid, w, core, total_w, n, layout=layout,
                 kernel_backend=kernel_backend,
             )
@@ -405,6 +410,7 @@ def batch_program(
         evict_waves=ev_waves,
         remove_bisect_steps=rm_steps,
         insert_bisect_steps=ins_steps,
+        hindex_sorts=rm_sorts + ins_sorts if w is not None else None,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats
@@ -649,6 +655,8 @@ def batch_program_halo(
         evict_waves=ev_waves,
         remove_bisect_steps=rm_steps,
         insert_bisect_steps=ins_steps,
+        # the halo layout has a mesh axis: its fixpoints bisect
+        hindex_sorts=jnp.int32(0) if w is not None else None,
     )
     if w is not None:
         return src, dst, valid, w, core, label, n_edges, stats

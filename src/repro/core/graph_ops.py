@@ -33,6 +33,7 @@ stay bit-identical (integer adds in a different order).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -375,7 +376,79 @@ def weighted_support(
     return _seg2(to_src, to_dst, src, dst, n, layout)
 
 
-def weighted_h_index(
+class HIndexEntries(NamedTuple):
+    """A slot table's directed entries, two per slot (one per endpoint),
+    for ``sorted_h_index``. The table holds through a fixpoint, so one
+    view serves every round of it: sorted by owner, whatever the order
+    within each owner, v's entries fill the same positions."""
+    owner: Array  # [2C] int32 the entry's vertex; n on an invalid slot
+    nbr: Array    # [2C] int32 the other endpoint, whose core keys it
+    w: Array      # [2C] int32 the edge weight; 0 on an invalid slot
+    reset: Array  # [2C] int32 -strength(v) just past v's last position
+    last: Array   # [n] int32 v's last position (first: last - deg + 1)
+    deg: Array    # [n] int32 v's entries
+
+
+def hindex_entries(src: Array, dst: Array, valid: Array, w: Array,
+                   n: int) -> HIndexEntries:
+    """The ``HIndexEntries`` of a slot table. Invalid slots own the
+    sentinel ``n``, which sorts past every real vertex."""
+    owner = jnp.concatenate([jnp.where(valid, src, n),
+                             jnp.where(valid, dst, n)]).astype(jnp.int32)
+    nbr = jnp.concatenate([dst, src]).astype(jnp.int32)
+    wi = jnp.where(valid, w.astype(jnp.int32), 0)
+    col = jnp.stack([valid.astype(jnp.int32), wi], axis=-1)
+    deg_strength = _seg2(col, col, src, dst, n)
+    deg, strength = deg_strength[:, 0], deg_strength[:, 1]
+    last = jnp.cumsum(deg) - 1
+    # an empty vertex adds its 0 at its predecessor's reset position
+    reset = jnp.zeros(owner.shape[0], jnp.int32).at[last + 1].add(
+        -strength, mode="drop")
+    return HIndexEntries(owner, nbr, jnp.concatenate([wi, wi]), reset,
+                         last, deg)
+
+
+def sorted_h_index(entries: HIndexEntries, core: Array,
+                   upper: Array) -> Array:
+    """Per-vertex weighted h-index in closed form: sort each vertex's
+    entries by neighbour core, descending, with ``W_j`` the prefix sums
+    of their weights; then ``H(v) = min(upper(v), max_j min(c_j, W_j))``
+    (0 without entries). Each ``min(c_j, W_j)`` is feasible, since the
+    first j entries carry ``W_j`` to neighbours of core >= c_j, and the
+    largest feasible h is reached at the last entry of core >= h; the
+    last entry of a tie in c carries the tie's full support, and the
+    feasible set is a prefix of ``[0, ...]``, so the cap is exact. Equal
+    to the bisection (``bisect_h_index``), integer for integer.
+
+    One gather of the cores, one two-key sort, two int32 cumsums and
+    n-sized gathers. ``W`` is the cumsum of the weights with
+    ``entries.reset`` added: each owner's strength is taken off just
+    past its last entry, so the sums restart at every owner. Every
+    ``W_j`` lies in ``[0, STRENGTH_MAX]``, and a partial sum that
+    wraps int32 on the way (a table's total weight may pass 2^31)
+    still ends there exactly. ``W_j - c_j`` never decreases within an
+    owner, so ``W_j <= c_j`` holds on a prefix of it, counted by the
+    second cumsum; the max is then ``W`` at the prefix's last entry or
+    ``c`` at the entry after it."""
+    c = core[entries.nbr].astype(jnp.int32)
+    owner, neg_c, w = jax.lax.sort((entries.owner, -c, entries.w),
+                                   num_keys=2, is_stable=False)
+    c = -neg_c
+    support = jnp.cumsum(w + entries.reset)
+    below = jnp.cumsum((support <= c).astype(jnp.int32))
+    last, deg = entries.last, entries.deg
+    first = last - deg + 1
+    at = partial(jnp.take, mode="clip")
+    cnt = jnp.where(deg > 0, at(below, last) - jnp.where(
+        first > 0, at(below, first - 1), 0), 0)
+    pos = first + cnt
+    h = jnp.maximum(jnp.where(cnt > 0, at(support, pos - 1), 0),
+                    jnp.where(cnt < deg, at(c, pos), 0))
+    return jnp.minimum(jnp.maximum(h, 0),
+                       jnp.maximum(upper.astype(jnp.int32), 0))
+
+
+def bisect_h_index(
     src: Array, dst: Array, valid: Array, w: Array, core: Array,
     upper: Array, n: int, layout: Optional[VertexLayout] = None,
     backend: str = "lax",
@@ -411,6 +484,29 @@ def weighted_h_index(
     lo, _, steps = jax.lax.while_loop(cond, body,
                                       (lo, upper, jnp.int32(0)))
     return lo, steps
+
+
+def weighted_h_index(
+    src: Array, dst: Array, valid: Array, w: Array, core: Array,
+    upper: Array, n: int, layout: Optional[VertexLayout] = None,
+    backend: str = "lax", entries: Optional[HIndexEntries] = None,
+) -> Tuple[Array, Array]:
+    """Per-vertex weighted h-index capped by ``upper``; returns ``(h,
+    steps)``, ``steps`` the bisection's support passes. Where the layout
+    has no mesh axis (``completes_locally``) every edge of a vertex is
+    on this device, so one sort gives it (``sorted_h_index``, 0 steps);
+    ``entries`` is the table's view from ``hindex_entries``, which a
+    fixpoint builds once (built here when None). Under a mesh axis a
+    vertex's support is a sum of partial sums completed by the layout,
+    and a max of prefix minima does not split that way: the lockstep
+    bisection runs (``bisect_h_index``; ``backend`` picks its support
+    pass)."""
+    if completes_locally(layout):
+        if entries is None:
+            entries = hindex_entries(src, dst, valid, w, n)
+        return sorted_h_index(entries, core, upper), jnp.int32(0)
+    return bisect_h_index(src, dst, valid, w, core, upper, n, layout,
+                          backend)
 
 
 def expand_forward(

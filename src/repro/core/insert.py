@@ -700,7 +700,7 @@ def weighted_promotion_fixpoint(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
-) -> Tuple[Array, Array, Array, Array]:
+) -> Tuple[Array, Array, Array, Array, Array]:
     """Weighted promotion phase. The Order machinery's forward/evict
     passes have no weighted analogue of the +1-per-round theorem, so the
     promotion phase is the SAME decrease-only h-index fixpoint as the
@@ -709,7 +709,7 @@ def weighted_promotion_fixpoint(
     by at most W — including vertices with NO inserted edge incident
     (a new path can close a cycle through them), which is why the
     per-vertex incident-weight bound is unsound (docs/DESIGN.md §4.5).
-    Returns ``(core, rounds, max_frontier, steps)``."""
+    Returns ``(core, rounds, max_frontier, steps, sorts)``."""
     return weighted_core_fixpoint_pass(
         src, dst, valid, w, core + total_w, n, layout=layout,
         kernel_backend=kernel_backend,

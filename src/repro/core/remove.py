@@ -225,43 +225,53 @@ def weighted_core_fixpoint_pass(
     n: int,
     layout: VertexLayout | None = None,
     kernel_backend: str = "lax",
-) -> Tuple[Array, Array, Array, Array]:
+) -> Tuple[Array, Array, Array, Array, Array]:
     """Decrease-only weighted h-index fixpoint (Zhou et al., WWW'21):
     per round ``core <- min(core, H_w(core))`` where ``H_w`` is the
-    per-vertex weighted h-index bisection (graph_ops.weighted_h_index),
-    until no vertex moves. Converges to the exact weighted cores from
+    per-vertex weighted h-index (graph_ops.weighted_h_index), until no
+    vertex moves. Converges to the exact weighted cores from
     ANY state upper-bounding them — both engine phases use it: removal
     starts from the current cores, promotion from ``core + W`` (W the
     batch's total inserted weight — docs/DESIGN.md §4.5 derives why the
     per-vertex incident bound is NOT sound).
 
-    Labels are FROZEN throughout: the weighted fixpoint has no per-level
-    append order to maintain (levels are unbounded in maxW, so the
-    bucketed ``place_block`` does not apply); the engine commits ONE
-    bucket-free renumber per batch instead. Returns ``(core, rounds,
-    max_frontier, steps)``, ``steps`` the bisection steps (support
-    passes) summed over the rounds. Replicated/plain layouts only — the
-    halo twin is ``weighted_core_fixpoint_pass_halo``."""
+    Where the layout has no mesh axis each round's h-index comes from
+    one sort over the table's entries, built once here
+    (graph_ops.hindex_entries); under a mesh axis from the lockstep
+    bisection. Labels are FROZEN throughout: the weighted fixpoint has
+    no per-level append order to maintain (levels are unbounded in
+    maxW, so the bucketed ``place_block`` does not apply); the engine
+    commits ONE bucket-free renumber per batch instead. Returns
+    ``(core, rounds, max_frontier, steps, sorts)``: ``steps`` the
+    bisection steps (support passes) summed over the rounds, ``sorts``
+    the rounds whose h-index came from the sort. Replicated/plain
+    layouts only — the halo twin is ``weighted_core_fixpoint_pass_halo``."""
     if layout is None:
         layout = ReplicatedVertices(n)
+    entries = (G.hindex_entries(src, dst, valid, w, n)
+               if G.completes_locally(layout) else None)
+    sorted_round = jnp.int32(entries is not None)
 
     def cond(state):
         return state[1]
 
     def body(state):
-        core, _, rounds, fmax, steps = state
+        core, _, rounds, fmax, steps, sorts = state
         h, k = G.weighted_h_index(src, dst, valid, w, core, core, n,
-                                  layout, backend=kernel_backend)
+                                  layout, backend=kernel_backend,
+                                  entries=entries)
         new_core = jnp.minimum(core, h)
         changed = new_core < core
         fmax = jnp.maximum(fmax, layout.frontier_peak(changed))
-        return new_core, jnp.any(changed), rounds + 1, fmax, steps + k
+        return (new_core, jnp.any(changed), rounds + 1, fmax, steps + k,
+                sorts + sorted_round)
 
-    core, _, rounds, fmax, steps = jax.lax.while_loop(
+    core, _, rounds, fmax, steps, sorts = jax.lax.while_loop(
         cond, body,
-        (core, jnp.bool_(True), jnp.int32(0), jnp.int32(0), jnp.int32(0)),
+        (core, jnp.bool_(True), jnp.int32(0), jnp.int32(0), jnp.int32(0),
+         jnp.int32(0)),
     )
-    return core, rounds, fmax, steps
+    return core, rounds, fmax, steps, sorts
 
 
 def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,

@@ -5,8 +5,9 @@ The PRODUCTION weighted engine lives in the engine matrix now:
 through ``core/engine.py::batch_program`` (and its halo twin), runs
 both maintenance phases through the shared decrease-only weighted
 h-index fixpoint (``core/remove.py::weighted_core_fixpoint_pass`` /
-``core/insert.py::weighted_promotion_fixpoint``, statistics via
-``core/graph_ops.py::weighted_support`` on either kernel backend), and
+``core/insert.py::weighted_promotion_fixpoint``, each round's h-index
+from ``core/graph_ops.py::weighted_h_index``: one sort on one device, a
+bisection of ``weighted_support`` passes under a mesh axis), and
 is audited by the committed ``weighted`` / ``weighted_sharded`` budget
 manifests. This module is what that engine is PINNED against: the
 numpy peeling oracle (``weighted_core_oracle``), a standalone

@@ -297,11 +297,20 @@ def _run_weighted_churn_differential(m0, graph_seed, stream_seed,
         for e, st_ in stats.items():
             assert int(st_.n_inserted) == inserted, e
             assert int(st_.n_removed) == removed, e
-        # the sharded program runs the same bisection steps
-        assert [(int(st_.remove_bisect_steps), int(st_.insert_bisect_steps))
+        # the same rounds on both: the unified program takes each
+        # round's h-index from one sort, the sharded one bisects
+        assert [(int(st_.remove_rounds), int(st_.insert_rounds))
                 for st_ in stats.values()] == [
-            (int(stats["weighted"].remove_bisect_steps),
-             int(stats["weighted"].insert_bisect_steps))] * len(stats)
+            (int(stats["weighted"].remove_rounds),
+             int(stats["weighted"].insert_rounds))] * len(stats)
+        uw, sw = stats["weighted"], stats["weighted_sharded"]
+        assert int(uw.hindex_sorts) == \
+            int(uw.remove_rounds) + int(uw.insert_rounds)
+        assert int(uw.remove_bisect_steps) == int(uw.insert_bisect_steps) \
+            == 0
+        assert int(sw.hindex_sorts) == 0
+        assert int(sw.remove_bisect_steps) + int(sw.insert_bisect_steps) \
+            > 0
         assert ms["weighted_sharded"].edge_slot.keys() == \
             u.edge_slot.keys()
         # the stored weight column mirrors the live map exactly

@@ -101,12 +101,17 @@ def test_wave_counters_on_a_removal_only_batch():
     for name in ("host", "weighted"):
         assert int(st[name].forward_waves) == 0, name
         assert int(st[name].evict_waves) == 0, name
-    # bisection steps exist on the weighted path only
-    for name in ("host", "unified"):
+    # bisection steps exist on a weighted path with a mesh axis only:
+    # the one-device weighted program takes each round's h-index from
+    # one sort, and counts those rounds
+    for name in ("host", "unified", "weighted"):
         assert int(st[name].remove_bisect_steps) == 0, name
         assert int(st[name].insert_bisect_steps) == 0, name
-    assert int(st["weighted"].remove_bisect_steps) > 0
-    assert int(st["weighted"].insert_bisect_steps) > 0
+    for name in ("host", "unified"):
+        assert st[name].hindex_sorts is None, name
+    wt = st["weighted"]
+    assert int(wt.hindex_sorts) == \
+        int(wt.remove_rounds) + int(wt.insert_rounds) > 0
     # the unified program still runs its one promotion round with no
     # seeds: one forward wave and one evict wave, each a real pass
     u = st["unified"]
@@ -151,13 +156,14 @@ def _replay_weighted_fixpoint(n, live, start):
     round bisects every vertex's weighted h-index in lockstep, ``mid =
     (lo + hi + 1) // 2`` kept where the weight to neighbours of core >=
     mid reaches mid, until no lane moves; rounds run until no core
-    drops. Returns ``(cores, steps)``, the steps summed over the rounds
-    (the last, unchanging round's included)."""
+    drops. Returns ``(cores, steps, rounds)``, the steps summed over the
+    rounds (the last, unchanging round's included)."""
     e = np.asarray(sorted(live), dtype=np.int64).reshape(-1, 2)
     w = np.asarray([live[k] for k in sorted(live)], dtype=np.int64)
     u, v = e[:, 0], e[:, 1]
-    core, steps = np.asarray(start, dtype=np.int64), 0
+    core, steps, rounds = np.asarray(start, dtype=np.int64), 0, 0
     while True:
+        rounds += 1
         lo, hi = np.zeros(n, np.int64), np.maximum(core, 0)
         while (lo < hi).any():
             mid = (lo + hi + 1) // 2
@@ -168,7 +174,7 @@ def _replay_weighted_fixpoint(n, live, start):
             steps += 1
         new = np.minimum(core, lo)
         if (new == core).all():
-            return core, steps
+            return core, steps, rounds
         core = new
 
 
@@ -193,22 +199,33 @@ def test_bisection_steps_match_a_numpy_replay(config):
                            insert_weights=iw)
         for e in ev.removals:
             live.pop(tuple(sorted(map(int, e))), None)
-        core1, rm_steps = _replay_weighted_fixpoint(g.n, live, core0)
+        core1, rm_steps, rm_rounds = _replay_weighted_fixpoint(
+            g.n, live, core0)
         total_w = 0  # the weight the batch really inserted
         for e, wt in zip(ev.edges, iw):
             k = tuple(sorted(map(int, e)))
             if k[0] != k[1] and k not in live:
                 live[k] = int(wt)
                 total_w += int(wt)
-        core2, ins_steps = _replay_weighted_fixpoint(g.n, live,
-                                                     core1 + total_w)
+        core2, ins_steps, ins_rounds = _replay_weighted_fixpoint(
+            g.n, live, core1 + total_w)
         np.testing.assert_array_equal(m.cores(), core2)
-        assert (int(st.remove_bisect_steps),
-                int(st.insert_bisect_steps)) == (rm_steps, ins_steps)
         assert int(st.forward_waves) == int(st.evict_waves) == 0
+        assert (int(st.remove_rounds), int(st.insert_rounds)) == \
+            (rm_rounds, ins_rounds)
+        if config:
+            assert (int(st.remove_bisect_steps),
+                    int(st.insert_bisect_steps)) == (rm_steps, ins_steps)
+            assert int(st.hindex_sorts) == 0
+        else:
+            # one device: every round's h-index comes from one sort,
+            # the replay's rounds with no bisection step
+            assert (int(st.remove_bisect_steps),
+                    int(st.insert_bisect_steps)) == (0, 0)
+            assert int(st.hindex_sorts) == rm_rounds + ins_rounds
         steps.append(rm_steps + ins_steps)
-    # what the unified engine counts on these seeded batches: the
-    # sharded and halo programs count the same
+    # what the replay counts on these seeded batches, and the sharded
+    # and halo programs with it
     assert steps == [62, 107, 77]
 
 
