@@ -7,78 +7,12 @@ families (ER / BA / RMAT power-law) and its protocol.
 """
 from __future__ import annotations
 
-import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict
 
 import numpy as np
 
 from repro.graph.csr import CSRGraph
 from repro.graph.generators import barabasi_albert, erdos_renyi, rmat
-from repro.graph.stream import churn_stream, sliding_window_stream
-
-
-def churn_workload(
-    n: int = 1500,
-    m: int = 6000,
-    n_batches: int = 30,
-    batch_size: int = 128,
-    seed: int = 23,
-):
-    """Steady-state churn: balanced 50/50 insert/remove batches with
-    heavy just-removed re-insertion (graph/stream.py::churn_stream) —
-    the workload where the device engines' in-program slot recycling
-    pays and the host engine must fall back to ``_compact``. Live edge
-    count is exactly flat, so per-batch work and capacity should be too.
-
-    Returns ``(graph, events)``; every event is a dirty mixed
-    ``EdgeEvent`` (duplicates/self-loops/absent removals included, as a
-    production stream would carry).
-    """
-    g = erdos_renyi(n, m, seed=seed)
-    events = list(
-        churn_stream(g, n_batches, batch_size, p_reinsert=0.6, seed=seed)
-    )
-    return g, events
-
-
-def temporal_workload(
-    n: int = 1500,
-    arrivals: int = 3000,
-    horizon: int = 30,
-    window: int = 6,
-    stride: int = 3,
-    seed: int = 31,
-):
-    """Sliding-window temporal stream: ``arrivals`` random (u, v, t)
-    rows with timestamps uniform over ``[0, horizon)``, replayed through
-    ``graph/stream.py::sliding_window_stream`` — each step inserts the
-    edges arriving in the new stride and bulk-removes the live edges
-    older than ``window``. Unlike ``churn_workload`` the removals are
-    STRUCTURAL (expiry by age), not sampled, and the stream drains: the
-    final live set is empty, so total insertions == total removals and
-    the final cores are all zero.
-
-    Returns ``(n, edges_with_time, events, max_live)`` where
-    ``max_live`` is the peak live-edge count over the replay (the
-    capacity-planning datum) and every event is a mixed ``EdgeEvent``
-    whose removals the consumer applies first.
-    """
-    rng = np.random.default_rng(seed)
-    ewt = np.stack(
-        [
-            rng.integers(0, n, arrivals),
-            rng.integers(0, n, arrivals),
-            rng.integers(0, horizon, arrivals),
-        ],
-        axis=1,
-    ).astype(np.int64)
-    events = list(sliding_window_stream(ewt, window=window, stride=stride))
-    live = 0
-    max_live = 0
-    for ev in events:  # removals-first, matching apply_batch
-        live += len(ev.edges) - len(ev.removals)
-        max_live = max(max_live, live)
-    return n, ewt, events, max_live
 
 
 def paper_graphs(scale: float = 1.0) -> Dict[str, CSRGraph]:
@@ -115,30 +49,3 @@ def sample_insertions(g: CSRGraph, k: int, seed: int = 0) -> np.ndarray:
         out.append(key)
     return np.asarray(out, dtype=np.int64)
 
-
-def timed(
-    fn: Callable,
-    *args,
-    warmup: int = 1,
-    iters: int = 3,
-    sync: Optional[Callable] = None,
-) -> float:
-    """Median wall seconds of fn(*args).
-
-    JAX dispatch is asynchronous: without blocking on the result the
-    timer reads enqueue time, not execution time. ``sync`` is called on
-    fn's return value before each timer read; the default blocks on every
-    JAX array in the result (a no-op for plain Python/numpy results).
-    """
-    if sync is None:
-        import jax
-
-        sync = jax.block_until_ready
-    for _ in range(warmup):
-        sync(fn(*args))
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        sync(fn(*args))
-        ts.append(time.perf_counter() - t0)
-    return sorted(ts)[len(ts) // 2]

@@ -5,7 +5,7 @@ What runs is what a user calls. A Graph500-specification R-MAT graph
 (``graph.generators.rmat``: initiator 0.57/0.19/0.19/0.05, edge factor
 16) at SCALE 16 has 65,536 vertices and 910,247 edges after dedup
 (seed 0). ``CoreMaintainer.from_graph(g, engine="unified",
-kernel_backend="lax", init="jax-peel")`` loads it with the default
+init="jax-peel")`` loads it with the default
 capacity of 2m slots and peels it on the device. Then four mixed
 ``apply_batch`` calls each remove 25,000 sampled live edges and insert
 25,000 edges: batch 1 fresh absent edges, batches 2-4 the previous
@@ -260,7 +260,6 @@ def batch_program_memory(mt) -> str:
     m = engine.apply_batch.lower(
         mt.src, mt.dst, mt.valid, mt.core, mt.label, mt.n_edges,
         idx, idx, ok, idx, idx, ok, mt.n, mt.n_levels, mt._last_window,
-        kernel_backend=mt.kernel_backend,
     ).compile().memory_analysis()
     return (f"batch program memory_analysis "
             f"({time.perf_counter() - t0:.3f} s to lower and fetch): "
@@ -287,8 +286,7 @@ def build(g, **engine) -> object:
     from repro.core.api import CoreMaintainer
 
     t0 = time.perf_counter()
-    mt = CoreMaintainer.from_graph(g, kernel_backend="lax", init="jax-peel",
-                                   **engine)
+    mt = CoreMaintainer.from_graph(g, init="jax-peel", **engine)
     mt.core.block_until_ready()
     log(f"from_graph {engine}: {time.perf_counter() - t0:.3f} s "
         f"capacity={mt.capacity}")

@@ -20,8 +20,7 @@ before any benchmark runs:
     no-replicated-O(n)-buffer policy for the range layouts
     (``memory_budget``, ``memory.py``),
 
-plus an AST lint of the sync-free planning path (``hostlint``) and the
-BENCH_stream.json coherence gate (``benchcheck``). CLI:
+plus an AST lint of the sync-free planning path (``hostlint``). CLI:
 ``python -m repro.analysis.audit --engine all``; see docs/DESIGN.md §5.
 """
 from .audit import (  # noqa: F401
@@ -34,7 +33,6 @@ from .audit import (  # noqa: F401
     make_report,
     write_budgets,
 )
-from .benchcheck import check_bench  # noqa: F401
 from .hostlint import LintFinding, lint_file  # noqa: F401
 from .memory import (  # noqa: F401
     generate_memory_section,

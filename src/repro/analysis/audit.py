@@ -3,16 +3,13 @@
 Runs every registered rule (repro.analysis.rules) over the traced
 programs of each engine configuration and diffs against the committed
 budget manifests (``budgets/<engine>.json``), emitting one
-machine-readable report (schema ``repro.analysis/report/v1`` — the same
-shape ``benchcheck`` uses for the BENCH_stream.json coherence gate, so
-CI consumes exactly one report format).
+machine-readable report (schema ``repro.analysis/report/v1``).
 
 Usage:
     python -m repro.analysis.audit --engine all            # gate
     python -m repro.analysis.audit --engine all --devices 8
     python -m repro.analysis.audit --engine all --memory   # memory only
     python -m repro.analysis.audit --write-budgets --devices 8
-    python -m repro.analysis.audit --check-bench BENCH_stream.json
 
 ``--devices N`` re-execs in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` when the current
@@ -183,9 +180,8 @@ def generate_budget(traced, paired=None) -> dict:
             p: count_collectives(jx) for p, jx in traced.programs.items()
         },
         "rounds": rounds,
-        # launch-class primitives per fixpoint round (a fused pallas_call
-        # counts as ONE; rules.check_launch_budget pins these and, for
-        # pallas configs, proves the count strictly beats the lax twin)
+        # launch-class primitives per fixpoint round
+        # (rules.check_launch_budget pins these)
         "round_launches": {
             rname: count_round_launches(closed)
             for rname, (_, closed) in traced.rounds.items()
@@ -357,21 +353,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p.add_argument("--write-budgets", action="store_true",
                    help="regenerate the budget manifests instead of "
                         "checking (run with --devices 8)")
-    p.add_argument("--check-bench", default=None, metavar="PATH",
-                   help="check a BENCH_stream.json artifact for "
-                        "coherence instead of auditing engines")
     args = p.parse_args(argv)
-
-    if args.check_bench:
-        from .benchcheck import check_bench
-
-        report = make_report([check_bench(args.check_bench)],
-                             artifact=args.check_bench)
-        if args.out:
-            with open(args.out, "w") as fh:
-                json.dump(report, fh, indent=2)
-        _print_summary(report)
-        return 0 if report["ok"] else 1
 
     import jax  # after arg parsing: --help must not initialize a backend
 

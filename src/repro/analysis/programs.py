@@ -6,17 +6,13 @@ sparse exchange, donated batch buffers, a bounded jit-variant lattice).
 This module produces the artifacts the audit rules inspect, without
 executing a single batch:
 
-* ``ENGINE_CONFIGS`` — the nine engine configurations
+* ``ENGINE_CONFIGS`` — the eight engine configurations
   (host / unified / sharded / vertex_range / frontier_sparse /
-  vertex_halo / pallas, all bit-identical on unweighted streams, plus
-  the weight-generalized ``weighted`` / ``weighted_sharded`` pair —
+  vertex_halo, all bit-identical on unweighted streams, plus the
+  weight-generalized ``weighted`` / ``weighted_sharded`` pair —
   bit-identical to each other and to ``weighted_core_oracle`` on
   weighted streams), exactly the matrix
-  ``tests/test_churn_streams.py`` proves equivalent. The ``pallas``
-  config is the sharded engine with the fused COO stat kernels
-  (kernels/coremaint.py): the fusion swaps only LOCAL partials, so its
-  collective histogram and memory budgets must EQUAL the lax sharded
-  config's — an equality the audit enforces, not assumes. The
+  ``tests/test_churn_streams.py`` proves equivalent. The
   ``vertex_halo`` config runs the halo working set on a genuine 2-axis
   edge x vertex mesh (``mesh_shape=(d_e, d_v)``,
   ``launch/mesh.py::make_edge_vertex_mesh``) — its manifest carries the
@@ -84,7 +80,6 @@ class EngineConfig:
     frontier_exchange: str = "bitmask"
     frontier_cap: int = 0             # pinned sparse cap (sparse only)
     freelist: str = "interleaved"
-    kernel_backend: str = "lax"       # "lax" | "pallas" stat kernels
     weighted: bool = False            # weight-generalized engine (both
     #                                   phases run the weighted h-index
     #                                   bisection fixpoint; the slot
@@ -115,7 +110,6 @@ ENGINE_CONFIGS: Dict[str, EngineConfig] = {
             frontier_exchange="sparse", frontier_cap=16,
             mesh_shape=(4, 2),
         ),
-        EngineConfig("pallas", "sharded", kernel_backend="pallas"),
         EngineConfig("weighted", "unified", weighted=True),
         EngineConfig("weighted_sharded", "sharded", weighted=True),
     )
@@ -173,7 +167,6 @@ def trace_removal_round(
     vertex_sharding: str, n: int, cap: int, mesh,
     frontier_cap: Optional[int] = None,
     window: Optional[int] = None, lanes: int = 8,
-    kernel_backend: str = "lax",
 ) -> Tuple[List[Traffic], Any]:
     """Trace (not run) the removal fixpoint under shard_map.
 
@@ -214,7 +207,7 @@ def trace_removal_round(
             dst_h = session.locate(dst_w)
             return removal_fixpoint_halo(
                 src_h, dst_h, valid_w, core, label, core_h, label_h,
-                session, n + 2, kernel_backend=kernel_backend,
+                session, n + 2,
             )
 
         sm = shard_map(
@@ -240,8 +233,7 @@ def trace_removal_round(
 
     def kernel(src, dst, valid, core, label):
         return removal_fixpoint(src, dst, valid, core, label, n, n + 2,
-                                layout=layout,
-                                kernel_backend=kernel_backend)
+                                layout=layout)
 
     sm = shard_map(
         kernel, mesh=mesh,
@@ -263,7 +255,6 @@ def trace_promotion_round(
     vertex_sharding: str, n: int, cap: int, mesh,
     frontier_cap: Optional[int] = None, lanes: int = 8,
     window: Optional[int] = None,
-    kernel_backend: str = "lax",
 ) -> Tuple[List[Traffic], Any]:
     """Trace the promotion fixpoint under shard_map — the insertion-side
     counterpart of ``trace_removal_round``. Returns ``(log, jaxpr)``;
@@ -294,7 +285,6 @@ def trace_promotion_round(
             return promotion_fixpoint_halo(
                 src_h, dst_h, valid_w, core, label, core_h, label_h,
                 nu, nv, u_pos, v_pos, nok, hi, dout, session, n + 2,
-                kernel_backend=kernel_backend,
             )
 
         sm = shard_map(
@@ -325,8 +315,7 @@ def trace_promotion_round(
     def kernel(src, dst, valid, core, label, nu, nv, nok, hi, dout):
         return promotion_fixpoint(src, dst, valid, core, label,
                                   nu, nv, nok, hi, dout, n, n + 2,
-                                  layout=layout,
-                                  kernel_backend=kernel_backend)
+                                  layout=layout)
 
     sm = shard_map(
         kernel, mesh=mesh,
@@ -353,7 +342,6 @@ def trace_promotion_round(
 
 def trace_weighted_round(
     n: int, cap: int, mesh,
-    kernel_backend: str = "lax",
 ) -> Tuple[List[Traffic], Any]:
     """Trace the weighted h-index fixpoint under shard_map — the one
     round shape of BOTH weighted maintenance phases (removal runs it
@@ -368,7 +356,6 @@ def trace_weighted_round(
     def kernel(src, dst, valid, ew, core):
         return weighted_core_fixpoint_pass(
             src, dst, valid, ew, core, n, layout=layout,
-            kernel_backend=kernel_backend,
         )
 
     sm = shard_map(
@@ -549,7 +536,6 @@ def trace_engine(name: str,
             freelist=cfg.freelist,
             frontier_exchange=cfg.frontier_exchange,
             frontier_cap=fcap,
-            kernel_backend=cfg.kernel_backend,
             weighted=cfg.weighted,
         )
         n_state = (n_owned * d_v
@@ -563,7 +549,7 @@ def trace_engine(name: str,
             # one round shape serves both weighted phases (the
             # promotion fixpoint is the same program from core + W)
             rounds["weighted_round"] = trace_weighted_round(
-                n, cap, mesh, kernel_backend=cfg.kernel_backend,
+                n, cap, mesh,
             )
         else:
             round_fcap = (fcap if cfg.frontier_exchange == "sparse"
@@ -571,12 +557,10 @@ def trace_engine(name: str,
             rounds["removal_round"] = trace_removal_round(
                 cfg.vertex_sharding, n, cap, mesh, round_fcap,
                 window=window, lanes=lanes,
-                kernel_backend=cfg.kernel_backend,
             )
             rounds["promotion_round"] = trace_promotion_round(
                 cfg.vertex_sharding, n, cap, mesh, round_fcap, lanes,
                 window=window,
-                kernel_backend=cfg.kernel_backend,
             )
 
     n_pad = (n_owned * d_v

@@ -23,11 +23,7 @@ Registry (``RULES``, decorated with ``@rule``):
   outputs and paired through ``sort`` operands (so argsort index
   columns never inherit their keys' taint).
 * ``launch_budget`` — the per-round kernel-launch histogram
-  (gather/scatter/sort, a fused ``pallas_call`` counting as ONE) stays
-  pinned to the manifest; pallas configs are additionally re-traced
-  against their lax twin, whose collective schedule must be identical
-  (that is what lets them share the lax traffic budgets) and whose
-  launch count they must strictly undercut.
+  (gather/scatter/sort) stays pinned to the manifest.
 * ``recompile_surface`` — the (window, frontier-cap) static bucket
   lattice the planners can reach stays within the manifest's jit
   variant bound (the class of mid-stream recompile that halved unified
@@ -743,24 +739,10 @@ def check_dtype_policy(traced, budget: dict) -> List[Finding]:
 
 @rule("launch_budget")
 def check_launch_budget(traced, budget: dict) -> List[Finding]:
-    """Pin the per-round kernel-launch histogram, and prove the pallas
-    backend's fusion claim against its lax twin.
-
-    Part 1 (every engine with round traces): the histogram of
-    launch-class primitives per fixpoint round (``LAUNCH_PRIMS``; a
-    fused ``pallas_call`` counts as ONE launch) must equal the committed
-    ``round_launches`` section — a drifted count is a silently
-    re-grown gather/scatter train.
-
-    Part 2 (``kernel_backend="pallas"`` only): re-trace the SAME rounds
-    with ``kernel_backend="lax"`` and require (a) the collective
-    schedules to be IDENTICAL — op by op, payload by payload, branch by
-    branch — which is what entitles the pallas config to share the lax
-    collective/traffic budgets rather than assume them, and (b) the
-    pallas round's launch total to be STRICTLY lower than the lax
-    twin's — the whole point of the fusion, checked structurally so a
-    refactor that quietly unfuses the hot path fails the audit, not
-    just a benchmark."""
+    """Pin the per-round kernel-launch histogram: the launch-class
+    primitives per fixpoint round (``LAUNCH_PRIMS``) must equal the
+    committed ``round_launches`` section — a drifted count is a silently
+    re-grown gather/scatter train."""
     cfg = traced.config
     findings: List[Finding] = []
 
@@ -782,61 +764,6 @@ def check_launch_budget(traced, budget: dict) -> List[Finding]:
             bad(
                 f"launch histogram drifted: budget {want} vs traced "
                 f"{got or '{}'}",
-                rname,
-            )
-
-    if cfg.kernel_backend == "lax" or not traced.rounds:
-        return findings
-
-    from .programs import (
-        resolve_mesh,
-        trace_promotion_round,
-        trace_removal_round,
-    )
-
-    # rebuild the mesh the audited rounds were traced on — for a halo
-    # config that means the SAME (d_e, d_v) factorization, read back
-    # from the traced size environment, so the twin comparison never
-    # mixes factorizations
-    twin_shape = ((traced.sizes["d_e"], traced.sizes["d_v"])
-                  if cfg.vertex_sharding == "halo" else None)
-    mesh = resolve_mesh(cfg, traced.n_devices, twin_shape)
-    n, cap = traced.params.n, traced.params.capacity
-    fcap = (traced.frontier_cap
-            if cfg.frontier_exchange == "sparse" else None)
-    twins = {
-        "removal_round": lambda: trace_removal_round(
-            cfg.vertex_sharding, n, cap, mesh, fcap,
-            window=traced.window, lanes=traced.params.lanes,
-            kernel_backend="lax",
-        ),
-        "promotion_round": lambda: trace_promotion_round(
-            cfg.vertex_sharding, n, cap, mesh, fcap,
-            traced.params.lanes, window=traced.window,
-            kernel_backend="lax",
-        ),
-    }
-    for rname, (_, closed) in traced.rounds.items():
-        _, lax_closed = twins[rname]()
-        mine = [(c.op, c.out_bytes, c.cond_branches)
-                for c in collectives(closed)]
-        twin = [(c.op, c.out_bytes, c.cond_branches)
-                for c in collectives(lax_closed)]
-        if mine != twin:
-            bad(
-                f"collective schedule diverged from the lax twin: "
-                f"pallas {mine} vs lax {twin} — the fused kernels may "
-                "only replace LOCAL partials, never a collective",
-                rname,
-            )
-        n_mine = sum(count_round_launches(closed).values())
-        n_twin = sum(count_round_launches(lax_closed).values())
-        if n_mine >= n_twin:
-            bad(
-                f"pallas round launches {n_mine} launch-class "
-                f"primitives but the lax twin launches {n_twin} — "
-                "fusion must STRICTLY reduce the per-round launch "
-                "count",
                 rname,
             )
     return findings

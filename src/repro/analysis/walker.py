@@ -67,10 +67,7 @@ ROUND_TAG = "while:body_jaxpr"  # path element marking a fixpoint round
 # Launch-class primitives: each one the XLA runtime dispatches as (at
 # least) its own kernel on an accelerator backend. The gather/scatter
 # family is what ``jax.ops.segment_sum`` and the endpoint-state reads
-# lower to; ``pallas_call`` is a single fused launch REGARDLESS of how
-# many ops its body contains — which is exactly the reduction the fused
-# maintenance kernels (kernels/coremaint.py) claim, and what
-# ``count_round_launches`` measures.
+# lower to, and what ``count_round_launches`` measures.
 LAUNCH_PRIMS = frozenset(
     {
         "gather",
@@ -80,7 +77,6 @@ LAUNCH_PRIMS = frozenset(
         "scatter-min",
         "scatter-mul",
         "sort",
-        "pallas_call",
     }
 )
 
@@ -249,21 +245,13 @@ def collectives(closed) -> List[CollectiveSite]:
 
 def count_round_launches(closed) -> dict:
     """Histogram of launch-class primitives that execute once per
-    FIXPOINT ROUND (``Site.in_round`` only).
-
-    Equations nested inside a ``pallas_call``'s body jaxpr are skipped:
-    the whole fused kernel is ONE launch, so its internal gathers and
-    dots must not count — that skip is precisely what makes the lax
-    vs pallas launch comparison meaningful (the pallas round replaces a
-    gather/scatter train with a single ``pallas_call`` entry here).
-    Counts are per traced round body: a ``lax.while_loop`` body traces
-    exactly once, so the histogram IS the per-round launch budget."""
+    FIXPOINT ROUND (``Site.in_round`` only). Counts are per traced
+    round body: a ``lax.while_loop`` body traces exactly once, so the
+    histogram IS the per-round launch budget."""
     hist: dict = {}
     for s in iter_sites(closed):
         if not s.in_round or s.prim not in LAUNCH_PRIMS:
             continue
-        if any(t.startswith("pallas_call:") for t in s.path):
-            continue  # inside a fused kernel: already counted as one
         hist[s.prim] = hist.get(s.prim, 0) + 1
     return hist
 

@@ -51,14 +51,6 @@ explicitly), with an in-program per-round fallback to the dense halo
 regather on overflow — bit-identical results in every regime
 (docs/DESIGN.md §4.3). ``freelist`` picks the slot-allocator ranking
 (``"interleaved"`` | ``"hierarchical"`` — `insert.freelist_alloc`).
-``kernel_backend="pallas"`` routes every per-round statistics pass of
-the device engines through the fused COO Pallas kernel
-(kernels/coremaint.py) — one launch per round instead of a
-gather/scatter train, with the removal drop decision + core commit
-folded into the same launch wherever the layout completes statistics
-locally. Bit-identical to ``"lax"`` (integer adds only), and the mesh
-collective schedule is unchanged, so the sharded variants share the
-committed collective/memory budgets.
 All engine configurations are bit-identical in cores AND k-order labels
 on the same streams (tests/test_churn_streams.py).
 
@@ -84,7 +76,6 @@ from jax.profiler import TraceAnnotation
 from ..graph.csr import CSRGraph, build_csr
 from .decomposition import peel_decomposition, rank_to_labels
 from .engine import BatchStats, apply_batch, apply_batch_weighted
-from .graph_ops import KERNEL_BACKENDS
 from .insert import InsertStats, insert_batch
 from .oracle import bz_core_decomposition
 from .order import needs_renumber, renumber
@@ -111,7 +102,6 @@ class BatchCall(NamedTuple):
     stats: BatchStats
     program: Optional[Callable] = None  # None: host engine, empty batch
     args: tuple = ()  # ShapeDtypeStructs, then the static arguments
-    kwargs: Optional[dict] = None
 
     def compiled_text(self) -> Optional[str]:
         """The optimized HLO text of the program this call ran; each
@@ -120,8 +110,7 @@ class BatchCall(NamedTuple):
         in JAX's cache: a step for after a run, not for the hot path."""
         if self.program is None:
             return None
-        return self.program.lower(*self.args, **(self.kwargs or {})) \
-            .compile().as_text()
+        return self.program.lower(*self.args).compile().as_text()
 
 
 # the last calls of apply_batch in this process, oldest first
@@ -303,8 +292,6 @@ class CoreMaintainer:
     #                                      | "sparse" (range/halo only)
     frontier_cap: int = 0       # sparse index-buffer capacity; 0 = planned
     #                             per batch as a static pow2 bucket
-    kernel_backend: str = "lax"  # "lax" | "pallas" per-round stat kernels
-    #                              (kernels/coremaint.py; device engines only)
     weighted: bool = False      # weight-generalized engine: the slot table
     #                             carries a per-edge integer weight column
     #                             and both maintenance phases run the
@@ -413,17 +400,6 @@ class CoreMaintainer:
                 f"frontier_cap={self.frontier_cap} is only consumed by "
                 "frontier_exchange='sparse' — the bitmask exchange "
                 "would silently ignore it"
-            )
-        if self.kernel_backend not in KERNEL_BACKENDS:
-            raise ValueError(
-                f"unknown kernel_backend {self.kernel_backend!r} "
-                f"(expected one of {KERNEL_BACKENDS})"
-            )
-        if self.kernel_backend != "lax" and self.engine == "host":
-            raise ValueError(
-                "kernel_backend='pallas' needs a device engine "
-                "('unified' | 'sharded') — the host path runs the seed "
-                "two-program kernels and would silently ignore it"
             )
         if self.weighted:
             if self.engine == "host":
@@ -563,7 +539,6 @@ class CoreMaintainer:
                 freelist=self.freelist,
                 frontier_exchange=self.frontier_exchange,
                 frontier_cap=frontier_cap,
-                kernel_backend=self.kernel_backend,
                 weighted=self.weighted,
             )
             self._sharded_fns[key] = fn
@@ -655,7 +630,17 @@ class CoreMaintainer:
         are the ``(core, vertex id)`` lexicographic ranks — weighted
         maintenance freezes labels through the fixpoints and renumbers
         once per batch, so any deterministic unique assignment agrees
-        across every engine configuration."""
+        across every engine configuration.
+
+        ``kernel_backend`` names the round statistics' implementation,
+        of which there is one: ``"lax"`` is the only value accepted, and
+        the maintainer does not keep it (benchmark configurations still
+        pass it)."""
+        if kernel_backend != "lax":
+            raise ValueError(
+                f"kernel_backend={kernel_backend!r}: the round statistics "
+                "have one implementation, 'lax'"
+            )
         _require_x64()  # before any label math that would truncate quietly
         edges = g.edge_array()
         m = edges.shape[0]
@@ -688,8 +673,7 @@ class CoreMaintainer:
             wcol = np.zeros(capacity, dtype=np.int32)
             wcol[:m] = wv.astype(np.int32)
             # weighted-degree upper bound -> exact weighted cores via
-            # the engines' own decrease-only fixpoint (lax; backend
-            # choice cannot change the integer result)
+            # the engines' own decrease-only fixpoint
             deg_w = np.zeros(g.n, dtype=np.int64)
             np.add.at(deg_w, edges[:, 0], wv)
             np.add.at(deg_w, edges[:, 1], wv)
@@ -727,7 +711,6 @@ class CoreMaintainer:
                 freelist=freelist,
                 frontier_exchange=frontier_exchange,
                 frontier_cap=frontier_cap,
-                kernel_backend=kernel_backend,
                 weighted=True,
                 w=jnp.asarray(wcol),
                 validate=validate,
@@ -768,7 +751,6 @@ class CoreMaintainer:
             freelist=freelist,
             frontier_exchange=frontier_exchange,
             frontier_cap=frontier_cap,
-            kernel_backend=kernel_backend,
             validate=validate,
             live_ub=m,
             hwm_ub=m,
@@ -1009,15 +991,14 @@ class CoreMaintainer:
                     # off)
                     fcap = self._frontier_bucket(max(len(iu), len(ru)))
                     program = self._get_sharded_fn(window, fcap)
-                    static, kwargs = (), {}
+                    static = ()
                 else:
                     program = (apply_batch_weighted if self.weighted
                                else apply_batch)
                     static = (self.n, self.n_levels, window)
-                    kwargs = dict(kernel_backend=self.kernel_backend)
                 # the shapes before the call: it donates the state
                 shapes = tuple(_shape_of(x) for x in args) + static
-                out = program(*args, *static, **kwargs)
+                out = program(*args, *static)
             if self.weighted:
                 (
                     self.src,
@@ -1052,7 +1033,7 @@ class CoreMaintainer:
                 self.strength_ub += batch_w
             self.slot_cache = None
             self.last_batch_stats = stats
-            RECENT_CALLS.append(BatchCall(stats, program, shapes, kwargs))
+            RECENT_CALLS.append(BatchCall(stats, program, shapes))
             if (self.frontier_exchange == "sparse"
                     and self.frontier_cap == 0):
                 # queue the device scalar for the sync-free
@@ -1368,7 +1349,6 @@ class CoreMaintainer:
         freelist: str = "interleaved",
         frontier_exchange: str = "bitmask",
         frontier_cap: int = 0,
-        kernel_backend: str = "lax",
         weighted: bool = False,
         validate: bool = True,
     ) -> "CoreMaintainer":
@@ -1396,7 +1376,6 @@ class CoreMaintainer:
             freelist=freelist,
             frontier_exchange=frontier_exchange,
             frontier_cap=frontier_cap,
-            kernel_backend=kernel_backend,
             weighted=weighted,
             w=w,
             validate=validate,

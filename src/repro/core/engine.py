@@ -193,7 +193,6 @@ def batch_program(
     axis: str | None = None,
     layout: VertexLayout | None = None,
     freelist: str = "interleaved",
-    kernel_backend: str = "lax",
     w: Array | None = None,
     ins_w: Array | None = None,
 ):
@@ -279,7 +278,6 @@ def batch_program(
             (core, rm_rounds, rm_fmax, rm_steps,
              rm_sorts) = weighted_core_fixpoint_pass(
                 src, dst, valid, w, core, n, layout=layout,
-                kernel_backend=kernel_backend,
             )
             hi = dout_same = layout.zeros()
         else:
@@ -287,7 +285,6 @@ def batch_program(
             (core, label, rm_rounds, hi, dout_same,
              rm_fmax) = removal_fixpoint(
                 src, dst, valid, core, label, n, n_levels, layout=layout,
-                kernel_backend=kernel_backend,
             )
         n_dropped = jnp.sum(core != core_pre_rm, dtype=jnp.int32)
 
@@ -342,7 +339,6 @@ def batch_program(
             (core, ins_rounds, ins_fmax, ins_steps,
              ins_sorts) = weighted_promotion_fixpoint(
                 src, dst, valid, w, core, total_w, n, layout=layout,
-                kernel_backend=kernel_backend,
             )
             v_plus = core != core_pre_ins
         fwd_waves = ev_waves = jnp.int32(0)
@@ -369,7 +365,6 @@ def batch_program(
          ev_waves) = promotion_fixpoint(
             src, dst, valid, core, label, ilo, ihi, iok,
             hi, dout_same, n, n_levels, layout=layout,
-            kernel_backend=kernel_backend,
         )
     with jax.named_scope("coremaint.promote.stats"):
         n_promoted = jnp.sum(core != core_pre_ins, dtype=jnp.int32)
@@ -482,7 +477,6 @@ def batch_program_halo(
     table_axis,
     layout: HaloShardedVertices,
     freelist: str = "interleaved",
-    kernel_backend: str = "lax",
     w: Array | None = None,
     ins_w: Array | None = None,
 ):
@@ -542,7 +536,6 @@ def batch_program_halo(
             (core, core_h, rm_rounds, rm_fmax,
              rm_steps) = weighted_core_fixpoint_pass_halo(
                 src_h, dst_h, valid, w, core, core_h, session,
-                kernel_backend=kernel_backend,
             )
             hi = dout_same = session.zeros()
             rm_ovf = jnp.int32(0)
@@ -551,7 +544,7 @@ def batch_program_halo(
             (core, label, core_h, label_h, rm_rounds, hi, dout_same,
              rm_fmax, rm_ovf) = removal_fixpoint_halo(
                 src_h, dst_h, valid, core, label, core_h, label_h, session,
-                n_levels, kernel_backend=kernel_backend,
+                n_levels,
             )
         n_dropped = vsum(jnp.sum(core != core_pre_rm, dtype=jnp.int32))
 
@@ -591,7 +584,6 @@ def batch_program_halo(
             (core, core_h, ins_rounds, ins_fmax,
              ins_steps) = weighted_promotion_fixpoint_halo(
                 src_h, dst_h, valid, w, core, core_h, total_w, session,
-                kernel_backend=kernel_backend,
             )
             v_plus = core != core_pre_ins
         ins_ovf = fwd_waves = ev_waves = jnp.int32(0)
@@ -619,7 +611,6 @@ def batch_program_halo(
          ins_ovf, fwd_waves, ev_waves) = promotion_fixpoint_halo(
             src_h, dst_h, valid, core, label, core_h, label_h,
             ilo, ihi, u_pos, v_pos, iok, hi, dout_same, session, n_levels,
-            kernel_backend=kernel_backend,
         )
     with jax.named_scope("coremaint.promote.stats"):
         n_promoted = vsum(jnp.sum(core != core_pre_ins, dtype=jnp.int32))
@@ -665,7 +656,7 @@ def batch_program_halo(
 
 @partial(
     jax.jit,
-    static_argnames=("n", "n_levels", "active_cap", "kernel_backend"),
+    static_argnames=("n", "n_levels", "active_cap"),
     donate_argnums=DONATED_STATE_ARGS,
 )
 def apply_batch(
@@ -684,7 +675,6 @@ def apply_batch(
     n: int,
     n_levels: int,
     active_cap: int,
-    kernel_backend: str = "lax",
 ) -> Tuple[Array, Array, Array, Array, Array, Array, BatchStats]:
     """Apply one mixed batch (removals first, then insertions) and restore
     core numbers + k-order labels.
@@ -708,7 +698,7 @@ def apply_batch(
     src, dst, valid, core, label, n_edges, stats = batch_program(
         src, dst, valid, core, label, n_edges,
         ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
-        n, n_levels, kernel_backend=kernel_backend,
+        n, n_levels,
     )
     with jax.named_scope("coremaint.table"):
         # splice the active region back into the full-capacity buffers
@@ -721,7 +711,7 @@ def apply_batch(
 
 @partial(
     jax.jit,
-    static_argnames=("n", "n_levels", "active_cap", "kernel_backend"),
+    static_argnames=("n", "n_levels", "active_cap"),
     donate_argnums=WEIGHTED_DONATED_STATE_ARGS,
 )
 def apply_batch_weighted(
@@ -742,7 +732,6 @@ def apply_batch_weighted(
     n: int,
     n_levels: int,
     active_cap: int,
-    kernel_backend: str = "lax",
 ):
     """``apply_batch`` with the slot table's weight column: the same
     active-window slice/splice with ``w`` riding alongside the other
@@ -756,7 +745,7 @@ def apply_batch_weighted(
     src, dst, valid, w, core, label, n_edges, stats = batch_program(
         src, dst, valid, core, label, n_edges,
         ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
-        n, n_levels, kernel_backend=kernel_backend, w=w, ins_w=ins_w,
+        n, n_levels, w=w, ins_w=ins_w,
     )
     with jax.named_scope("coremaint.table"):
         src = jnp.concatenate([src, full_src[active_cap:]])

@@ -21,15 +21,6 @@ additionally psum over the pure-edge axes first). With ``layout=None``
 GSPMD) completion is the identity and the functions are unchanged. This
 is how the sharded engines reuse the exact fixpoint code of remove.py /
 insert.py regardless of where the vertex state lives.
-
-This module is also the KERNEL DISPATCH POINT: the round statistics
-accept ``backend="lax" | "pallas"``. The lax path (default) is the
-bit-exact reference above; the pallas path replaces the per-stat
-gather + two-segment-sum launch train with one fused ``pallas_call``
-(``kernels/coremaint.py``) producing the SAME local partial sums, then
-completes them with the layout exactly as before — so switching the
-backend changes kernel launches, never collectives, and the results
-stay bit-identical (integer adds in a different order).
 """
 from __future__ import annotations
 
@@ -39,20 +30,15 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels import coremaint
 from .vertex_layout import ReplicatedVertices, VertexLayout
 
 Array = jax.Array
 
-KERNEL_BACKENDS = ("lax", "pallas")
-
-
 def completes_locally(layout: Optional[VertexLayout]) -> bool:
     """True when ``layout.complete`` is the identity (single device /
-    GSPMD): partial statistics ARE the global statistics, so the fused
-    pallas kernels may commit per-vertex threshold decisions in the same
-    launch that produced the stat. Under a mesh axis the decision must
-    wait for the layout's collective."""
+    GSPMD): partial statistics ARE the global statistics, so a
+    per-vertex result may be computed from this device's edges alone.
+    Under a mesh axis it must wait for the layout's collective."""
     return layout is None or (
         isinstance(layout, ReplicatedVertices) and layout.axis is None
     )
@@ -97,17 +83,8 @@ def degree(src: Array, dst: Array, valid: Array, n: int,
 
 
 def count_ge(src: Array, dst: Array, valid: Array, vals: Array, n: int,
-             layout: Optional[VertexLayout] = None,
-             backend: str = "lax") -> Array:
+             layout: Optional[VertexLayout] = None) -> Array:
     """mcd (Def 3.8): per-vertex count of neighbors w with vals[w] >= vals[v]."""
-    if backend == "pallas":
-        # the "mcd" stat compares core only; the kernel's label input is
-        # unused by its predicates but fixed int64 — synthesize one
-        out = coremaint.coo_stat(
-            src, dst, valid, vals,
-            jnp.zeros(vals.shape[0], jnp.int64), n, stat="mcd",
-        )
-        return _complete(out, layout)[:, 0]
     to_src = (valid & (vals[dst] >= vals[src])).astype(jnp.int32)
     to_dst = (valid & (vals[src] >= vals[dst])).astype(jnp.int32)
     return _seg2(to_src, to_dst, src, dst, n, layout)
@@ -180,18 +157,11 @@ def _hi_dout_sums(indicators, src: Array, dst: Array, n: int,
 
 def hi_and_dout_same(
     src: Array, dst: Array, valid: Array, core: Array, label: Array, n: int,
-    layout: Optional[VertexLayout] = None, backend: str = "lax",
+    layout: Optional[VertexLayout] = None,
 ):
     """Packed (hi, dout_same) for the insertion round: one [n, 2] result
     (single collective) carries both the higher-core neighbor count and
     the same-level k-order successor count (Defs 3.6/3.7 pieces)."""
-    if backend == "pallas":
-        out = _complete(
-            coremaint.coo_stat(src, dst, valid, core, label, n,
-                               stat="hi_dout"),
-            layout,
-        )
-        return out[:, 0], out[:, 1]
     return _hi_dout_sums(
         hi_dout_indicators(core, label, src, dst, valid), src, dst, n, layout
     )
@@ -201,7 +171,7 @@ def hi_dout_same_and_masks(
     src: Array, dst: Array, valid: Array, core: Array, label: Array, n: int,
     layout: Optional[VertexLayout] = None,
 ):
-    """``hi_and_dout_same`` (lax) plus the ``WaveMasks`` of the same
+    """``hi_and_dout_same`` plus the ``WaveMasks`` of the same
     (core, label, valid), built from the same gathers: a promotion round's
     closing statistics pass hands the next round both."""
     ind = hi_dout_indicators(core, label, src, dst, valid)
@@ -211,20 +181,13 @@ def hi_dout_same_and_masks(
 
 def mcd_hi_dout(
     src: Array, dst: Array, valid: Array, core: Array, label: Array, n: int,
-    layout: Optional[VertexLayout] = None, backend: str = "lax",
+    layout: Optional[VertexLayout] = None,
 ):
     """Packed (mcd, hi, dout_same) — one [n, 3] scatter carries the removal
     fixpoint's support count (Def 3.8) together with both promotion-seeding
     statistics (Defs 3.6/3.7 pieces). The unified engine runs this once per
     removal round; the terminating round's (hi, dout_same) columns are then
     reused to seed the promotion phase without a fresh O(m) pass."""
-    if backend == "pallas":
-        out = _complete(
-            coremaint.coo_stat(src, dst, valid, core, label, n,
-                               stat="mcd_hi_dout"),
-            layout,
-        )
-        return out[:, 0], out[:, 1], out[:, 2]
     hi_s, hi_d, do_s, do_d = hi_dout_indicators(core, label, src, dst, valid)
     to_src = jnp.stack(
         [
@@ -281,15 +244,9 @@ def count_same_level_before_in(
 
 def count_same_level_in(
     src: Array, dst: Array, valid: Array, core: Array, mask: Array, n: int,
-    layout: Optional[VertexLayout] = None, backend: str = "lax",
+    layout: Optional[VertexLayout] = None,
 ) -> Array:
     """Per-vertex count of same-level neighbors inside ``mask``."""
-    if backend == "pallas":
-        out = coremaint.coo_stat(
-            src, dst, valid, core, jnp.zeros(core.shape[0], jnp.int64), n,
-            stat="same_in", aux=mask,
-        )
-        return _complete(out, layout)[:, 0]
     same = valid & (core[src] == core[dst])
     to_src = (same & mask[dst]).astype(jnp.int32)
     to_dst = (same & mask[src]).astype(jnp.int32)
@@ -316,18 +273,11 @@ def din_and_expand(
     rp: Array,
     n: int,
     layout: Optional[VertexLayout] = None,
-    backend: str = "lax",
 ):
     """Fused FORWARD-wave statistics in ONE scatter-add: din counts
     reached-and-passing k-order predecessors, and frontier growth is
     exactly ``din > 0`` (a vertex is newly reachable iff it has an RP
     predecessor) — iteration C1."""
-    if backend == "pallas":
-        out = coremaint.coo_stat(
-            src, dst, valid, core, label, n, stat="din", aux=rp,
-        )
-        din = _complete(out, layout)[:, 0]
-        return din, din > 0
     same = valid & (core[src] == core[dst])
     fwd_to_dst = same & (label[src] < label[dst]) & rp[src]
     fwd_to_src = same & (label[dst] < label[src]) & rp[dst]
@@ -355,7 +305,6 @@ def din_and_expand_masked(
 def weighted_support(
     src: Array, dst: Array, valid: Array, w: Array, core: Array,
     thresh: Array, n: int, layout: Optional[VertexLayout] = None,
-    backend: str = "lax",
 ) -> Array:
     """Weighted generalization of ``count_ge``: per-vertex SUM of incident
     edge weights to neighbors u with ``core[u] >= thresh[v]`` (the inner
@@ -363,13 +312,6 @@ def weighted_support(
     ``thresh == core`` this IS mcd). The weighted column rides the exact
     same two-scatter + layout-completion schedule as the unit stats, so
     the sharded collective budget is unchanged per pass."""
-    if backend == "pallas":
-        out = coremaint.coo_stat(
-            src, dst, valid, core,
-            jnp.zeros(core.shape[0], jnp.int64), n, stat="wsum",
-            aux=thresh, edge_w=w,
-        )
-        return _complete(out, layout)[:, 0]
     wi = w.astype(jnp.int32)
     to_src = jnp.where(valid & (core[dst] >= thresh[src]), wi, 0)
     to_dst = jnp.where(valid & (core[src] >= thresh[dst]), wi, 0)
@@ -451,7 +393,6 @@ def sorted_h_index(entries: HIndexEntries, core: Array,
 def bisect_h_index(
     src: Array, dst: Array, valid: Array, w: Array, core: Array,
     upper: Array, n: int, layout: Optional[VertexLayout] = None,
-    backend: str = "lax",
 ) -> Tuple[Array, Array]:
     """Per-vertex weighted h-index by lockstep bisection:
     ``H_w(v) = max{h <= upper[v] : sum of weights to nbrs with
@@ -475,8 +416,7 @@ def bisect_h_index(
     def body(state):
         lo_, hi_, steps = state
         mid = (lo_ + hi_ + 1) // 2
-        s = weighted_support(src, dst, valid, w, core, mid, n,
-                             layout, backend)
+        s = weighted_support(src, dst, valid, w, core, mid, n, layout)
         ok = s >= mid
         return (jnp.where(ok, mid, lo_), jnp.where(ok, hi_, mid - 1),
                 steps + 1)
@@ -489,7 +429,7 @@ def bisect_h_index(
 def weighted_h_index(
     src: Array, dst: Array, valid: Array, w: Array, core: Array,
     upper: Array, n: int, layout: Optional[VertexLayout] = None,
-    backend: str = "lax", entries: Optional[HIndexEntries] = None,
+    entries: Optional[HIndexEntries] = None,
 ) -> Tuple[Array, Array]:
     """Per-vertex weighted h-index capped by ``upper``; returns ``(h,
     steps)``, ``steps`` the bisection's support passes. Where the layout
@@ -499,14 +439,12 @@ def weighted_h_index(
     fixpoint builds once (built here when None). Under a mesh axis a
     vertex's support is a sum of partial sums completed by the layout,
     and a max of prefix minima does not split that way: the lockstep
-    bisection runs (``bisect_h_index``; ``backend`` picks its support
-    pass)."""
+    bisection runs (``bisect_h_index``)."""
     if completes_locally(layout):
         if entries is None:
             entries = hindex_entries(src, dst, valid, w, n)
         return sorted_h_index(entries, core, upper), jnp.int32(0)
-    return bisect_h_index(src, dst, valid, w, core, upper, n, layout,
-                          backend)
+    return bisect_h_index(src, dst, valid, w, core, upper, n, layout)
 
 
 def expand_forward(

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 
 from . import graph_ops as G
-from ..kernels import coremaint
 from .order import place_block, place_block_ring
 from .remove import (
     weighted_core_fixpoint_pass,
@@ -188,7 +187,6 @@ def promotion_fixpoint(
     n: int,
     n_levels: int,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
 ) -> Tuple[Array, Array, Array, Array, Array]:
     """Promotion rounds for pending edges already written into the table.
 
@@ -224,26 +222,15 @@ def promotion_fixpoint(
     helpers, ``promote.stats`` for the closing statistics pass), so a
     profile splits device time by phase.
 
-    With the lax backend a round's FORWARD and EVICT waves read the
-    round's per-edge ``G.WaveMasks`` instead of gathering core and label
-    on every wave: (core, label, valid) hold through the round, so the
-    masks are built once before the first round and then by each round's
-    closing statistics pass, from the gathers that pass already makes,
-    and carried to the next round.
-
-    ``kernel_backend="pallas"`` runs every wave/evict/terminating
-    statistic through the fused COO kernels (kernels/coremaint.py) —
-    bit-identical partials, fewer launches; where the layout completes
-    locally the terminating violator check folds into the same launch
-    as its statistics (``fused_promotion_stats``). The fused kernels
-    make their own gathers, so that backend carries no masks.
+    A round's FORWARD and EVICT waves read the round's per-edge
+    ``G.WaveMasks`` instead of gathering core and label on every wave:
+    (core, label, valid) hold through the round, so the masks are built
+    once before the first round and then by each round's closing
+    statistics pass, from the gathers that pass already makes, and
+    carried to the next round.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
-    fuse_decision = (
-        kernel_backend == "pallas" and G.completes_locally(layout)
-    )
-    masked = kernel_backend == "lax"
 
     def round_cond(state):
         return state[2]
@@ -268,13 +255,11 @@ def promotion_fixpoint(
         seed = seed | viol | promoted_prev
 
         reach, passing, wave_fmax, fwd_waves = _forward_reach(
-            src, dst, valid, core, label, seed, hi, dout_same, n, layout,
-            kernel_backend=kernel_backend, masks=masks,
+            src, dst, core, seed, hi, dout_same, n, masks, layout,
         )
         cand0 = reach & passing
         cand, evict_round, ev_fmax, ev_waves = _evict_fixpoint(
-            src, dst, valid, core, cand0, hi, n, layout,
-            kernel_backend=kernel_backend, masks=masks,
+            src, dst, core, cand0, hi, n, masks, layout,
         )
         fmax = jnp.maximum(fmax, jnp.maximum(wave_fmax, ev_fmax))
 
@@ -295,31 +280,13 @@ def promotion_fixpoint(
         # implementation's trailing confirm round (a full forward + evict
         # + stats pass) entirely.
         with jax.named_scope("coremaint.promote.stats"):
-            if fuse_decision:
-                # ONE pallas_call: stats + the violator threshold mask
-                # that decides fixpoint termination
-                new_hi, new_dout, viol_next = (
-                    coremaint.fused_promotion_stats(
-                        src, dst, valid, new_core, label, n
-                    )
-                )
-                changed = jnp.any(viol_next)
-            elif masked:
-                # the next round's masks come from this pass's gathers
-                new_hi, new_dout, masks = G.hi_dout_same_and_masks(
-                    src, dst, valid, new_core, label, n, layout,
-                )
-                changed = layout.any_owned(
-                    (new_hi + new_dout) > layout.own(new_core)
-                )
-            else:
-                new_hi, new_dout = G.hi_and_dout_same(
-                    src, dst, valid, new_core, label, n, layout,
-                    backend=kernel_backend,
-                )
-                changed = layout.any_owned(
-                    (new_hi + new_dout) > layout.own(new_core)
-                )
+            # the next round's masks come from this pass's gathers
+            new_hi, new_dout, masks = G.hi_dout_same_and_masks(
+                src, dst, valid, new_core, label, n, layout,
+            )
+            changed = layout.any_owned(
+                (new_hi + new_dout) > layout.own(new_core)
+            )
         return (
             new_core,
             label,
@@ -335,7 +302,7 @@ def promotion_fixpoint(
             ev + ev_waves,
         )
 
-    masks = G.wave_masks(src, dst, valid, core, label) if masked else None
+    masks = G.wave_masks(src, dst, valid, core, label)
     z = jnp.int32(0)
     (core, label, _, _, rounds, v_plus, _, _, _, fmax, fwd,
      ev) = jax.lax.while_loop(
@@ -365,7 +332,6 @@ def promotion_fixpoint_halo(
     dout_same: Array,
     session: HaloSession,
     n_levels: int,
-    kernel_backend: str = "lax",
 ):
     """The promotion rounds on a halo working set — no [n] buffer.
 
@@ -417,12 +383,11 @@ def promotion_fixpoint_halo(
         (reach, passing, wave_fmax, wave_ovf,
          fwd_waves) = _forward_reach_halo(
             src_h, dst_h, valid, core_own, core_h, label_h, seed,
-            hi, dout_same, session, kernel_backend=kernel_backend,
+            hi, dout_same, session,
         )
         cand0 = reach & passing
         cand, evict_round, ev_fmax, ev_ovf, ev_waves = _evict_fixpoint_halo(
             src_h, dst_h, valid, core_own, core_h, cand0, hi, session,
-            kernel_backend=kernel_backend,
         )
         fmax = jnp.maximum(fmax, jnp.maximum(wave_fmax, ev_fmax))
 
@@ -449,7 +414,6 @@ def promotion_fixpoint_halo(
         with jax.named_scope("coremaint.promote.stats"):
             new_hi, new_dout = G.hi_and_dout_same(
                 src_h, dst_h, valid, core_h, label_h, hcap, session,
-                backend=kernel_backend,
             )
             changed = session.any_owned((new_hi + new_dout) > new_core)
         return (
@@ -483,7 +447,6 @@ def _forward_reach_halo(
     hi: Array,
     dout_same: Array,
     session: HaloSession,
-    kernel_backend: str = "lax",
 ):
     """``_forward_reach`` with OWNED loop masks and a per-wave halo
     refresh of the reached-and-passing frontier. Returns ``(reach,
@@ -499,7 +462,6 @@ def _forward_reach_halo(
         rp_h, ovf = session.refresh_mask(rp)
         din, grow = G.din_and_expand(
             src_h, dst_h, valid, core_h, label_h, rp_h, hcap, session,
-            backend=kernel_backend,
         )
         new_passing = (hi + dout_same + din) > core_own
         new_reach = reach | grow
@@ -532,7 +494,6 @@ def _evict_fixpoint_halo(
     cand: Array,
     hi: Array,
     session: HaloSession,
-    kernel_backend: str = "lax",
 ):
     """``_evict_fixpoint`` with OWNED candidate masks and a per-round
     halo refresh. Returns ``(cand, evict_round, max_frontier,
@@ -547,7 +508,6 @@ def _evict_fixpoint_halo(
         cand_h, ovf = session.refresh_mask(cand)
         support = hi + G.count_same_level_in(
             src_h, dst_h, valid, core_h, cand_h, hcap, session,
-            backend=kernel_backend,
         )
         keep = support > core_own
         fmax = jnp.maximum(fmax, session.frontier_peak(keep))
@@ -571,16 +531,13 @@ def _evict_fixpoint_halo(
 def _forward_reach(
     src: Array,
     dst: Array,
-    valid: Array,
     core: Array,
-    label: Array,
     seed: Array,
     hi: Array,
     dout_same: Array,
     n: int,
+    masks: G.WaveMasks,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
-    masks: G.WaveMasks | None = None,
 ) -> Tuple[Array, Array, Array, Array]:
     """Monotone fixpoint of gated forward expansion.
 
@@ -591,9 +548,8 @@ def _forward_reach(
     Under a range-sharded layout each wave moves one reduce_scatter
     (din, owned) plus the two wave bitmasks; the loop state stays
     full/replicated so the edge pass can index it at arbitrary
-    endpoints. With the round's ``masks`` (lax backend) a wave gathers
-    only ``rp``; without them (pallas) the fused kernel gathers core and
-    label itself.
+    endpoints. The round's ``masks`` hold the same-level order tests, so
+    a wave gathers only ``rp``.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
@@ -606,12 +562,7 @@ def _forward_reach(
         reach, passing, _, fmax, waves = state
         rp = reach & passing
         # one fused scatter per wave: din and frontier growth (C1)
-        if masks is None:
-            din, grow = G.din_and_expand(src, dst, valid, core, label, rp,
-                                         n, layout, backend=kernel_backend)
-        else:
-            din, grow = G.din_and_expand_masked(masks, rp, src, dst, n,
-                                                layout)
+        din, grow = G.din_and_expand_masked(masks, rp, src, dst, n, layout)
         new_passing = layout.gather_mask(
             (hi + dout_same + din) > core_own
         )
@@ -636,14 +587,12 @@ def _forward_reach(
 def _evict_fixpoint(
     src: Array,
     dst: Array,
-    valid: Array,
     core: Array,
     cand: Array,
     hi: Array,
     n: int,
+    masks: G.WaveMasks,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
-    masks: G.WaveMasks | None = None,
 ) -> Tuple[Array, Array, Array, Array]:
     """Greatest fixpoint of the candidate support test (sound + complete
     for any starting superset of V*).
@@ -652,8 +601,8 @@ def _evict_fixpoint(
     max_frontier, waves), masks full [n]. The round numbers order the Backward
     tail placement (never-evicted keep 0); they are maintained
     replicated from the gathered candidate masks, so no integer array
-    crosses the mesh. With the round's ``masks`` (lax backend) a wave
-    gathers only ``cand``.
+    crosses the mesh. With the round's ``masks`` a wave gathers only
+    ``cand``.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
@@ -665,13 +614,8 @@ def _evict_fixpoint(
 
     def body(state):
         cand, evict_round, rnd, _, fmax = state
-        if masks is None:
-            same_in = G.count_same_level_in(src, dst, valid, core, cand, n,
-                                            layout, backend=kernel_backend)
-        else:
-            same_in = G.count_same_level_in_masked(masks, cand, src, dst, n,
-                                                   layout)
-        support = hi + same_in
+        support = hi + G.count_same_level_in_masked(masks, cand, src, dst,
+                                                    n, layout)
         keep = layout.gather_mask(support > core_own)
         fmax = jnp.maximum(fmax, layout.frontier_peak(keep))
         new_cand = cand & keep
@@ -699,7 +643,6 @@ def weighted_promotion_fixpoint(
     total_w: Array,
     n: int,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
 ) -> Tuple[Array, Array, Array, Array, Array]:
     """Weighted promotion phase. The Order machinery's forward/evict
     passes have no weighted analogue of the +1-per-round theorem, so the
@@ -712,7 +655,6 @@ def weighted_promotion_fixpoint(
     Returns ``(core, rounds, max_frontier, steps, sorts)``."""
     return weighted_core_fixpoint_pass(
         src, dst, valid, w, core + total_w, n, layout=layout,
-        kernel_backend=kernel_backend,
     )
 
 
@@ -725,7 +667,6 @@ def weighted_promotion_fixpoint_halo(
     core_h: Array,
     total_w: Array,
     session: HaloSession,
-    kernel_backend: str = "lax",
 ):
     """``weighted_promotion_fixpoint`` on a halo working set: the upper
     bound ``+ total_w`` is replicated, so the halo image stays exact by
@@ -734,7 +675,7 @@ def weighted_promotion_fixpoint_halo(
     max_frontier, steps)``."""
     return weighted_core_fixpoint_pass_halo(
         src_h, dst_h, valid, w, core_own + total_w, core_h + total_w,
-        session, kernel_backend=kernel_backend,
+        session,
     )
 
 

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 
 from . import graph_ops as G
-from ..kernels import coremaint
 from .order import place_block, place_block_ring
 from .vertex_layout import (
     HaloSession,
@@ -62,7 +61,6 @@ def removal_fixpoint(
     n_levels: int,
     share_stats: bool = True,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
 ) -> Tuple[Array, Array, Array, Array, Array, Array]:
     """Run the decrease-only mcd fixpoint on an already-tombstoned table.
 
@@ -89,46 +87,24 @@ def removal_fixpoint(
     and the label tail placement — replays identically everywhere).
     Either way the working core/label stay replicated values, so all
     devices run the loop in lockstep.
-
-    ``kernel_backend="pallas"`` routes the statistics pass through the
-    fused COO kernel (kernels/coremaint.py): bit-identical partials, one
-    launch instead of a gather/scatter train. Where the layout completes
-    locally the drop decision + core commit fold into the same launch
-    (``fused_removal_round``); under a mesh the decision still runs after
-    the layout's collective, so the collective schedule never changes.
     """
     if layout is None:
         layout = ReplicatedVertices(n)
-    # decision fusion needs the GLOBAL mcd in-kernel: only where the
-    # layout completes statistics locally (single device / GSPMD)
-    fuse_decision = (
-        kernel_backend == "pallas" and G.completes_locally(layout)
-    )
 
     def cond(state):
         return state[2]
 
     def body(state):
         core, label, _, rounds, hi, dout_same, fmax = state
-        if fuse_decision:
-            # ONE pallas_call: packed stats + drop threshold + core commit
-            _, k_hi, k_dout, new_core, drop = coremaint.fused_removal_round(
-                src, dst, valid, core, label, n
+        if share_stats:
+            mcd, hi, dout_same = G.mcd_hi_dout(
+                src, dst, valid, core, label, n, layout,
             )
-            if share_stats:
-                hi, dout_same = k_hi, k_dout
         else:
-            if share_stats:
-                mcd, hi, dout_same = G.mcd_hi_dout(
-                    src, dst, valid, core, label, n, layout,
-                    backend=kernel_backend,
-                )
-            else:
-                mcd = G.count_ge(src, dst, valid, core, n, layout,
-                                 backend=kernel_backend)
-            core_own = layout.own(core)
-            drop = layout.gather_mask((mcd < core_own) & (core_own > 0))
-            new_core = core - drop.astype(jnp.int32)
+            mcd = G.count_ge(src, dst, valid, core, n, layout)
+        core_own = layout.own(core)
+        drop = layout.gather_mask((mcd < core_own) & (core_own > 0))
+        new_core = core - drop.astype(jnp.int32)
         fmax = jnp.maximum(fmax, layout.frontier_peak(drop))
         # place this round's droppers at the tail of their new level
         label = place_block(new_core, label, drop, at_head=False,
@@ -156,7 +132,6 @@ def removal_fixpoint_halo(
     label_h: Array,
     session: HaloSession,
     n_levels: int,
-    kernel_backend: str = "lax",
 ):
     """The removal fixpoint on a halo working set — no [n] buffer.
 
@@ -189,7 +164,6 @@ def removal_fixpoint_halo(
          fmax, n_ovf) = state
         mcd, hi, dout_same = G.mcd_hi_dout(
             src_h, dst_h, valid, core_h, label_h, hcap, session,
-            backend=kernel_backend,
         )
         drop = (mcd < core_own) & (core_own > 0)
         fmax = jnp.maximum(fmax, session.frontier_peak(drop))
@@ -224,7 +198,6 @@ def weighted_core_fixpoint_pass(
     core: Array,
     n: int,
     layout: VertexLayout | None = None,
-    kernel_backend: str = "lax",
 ) -> Tuple[Array, Array, Array, Array, Array]:
     """Decrease-only weighted h-index fixpoint (Zhou et al., WWW'21):
     per round ``core <- min(core, H_w(core))`` where ``H_w`` is the
@@ -258,8 +231,7 @@ def weighted_core_fixpoint_pass(
     def body(state):
         core, _, rounds, fmax, steps, sorts = state
         h, k = G.weighted_h_index(src, dst, valid, w, core, core, n,
-                                  layout, backend=kernel_backend,
-                                  entries=entries)
+                                  layout, entries=entries)
         new_core = jnp.minimum(core, h)
         changed = new_core < core
         fmax = jnp.maximum(fmax, layout.frontier_peak(changed))
@@ -275,8 +247,7 @@ def weighted_core_fixpoint_pass(
 
 
 def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
-                           session: HaloSession,
-                           kernel_backend: str = "lax"):
+                           session: HaloSession):
     """Lockstep owned+halo weighted h-index bisection. ``(lo, hi)`` live
     in BOTH domains: the owned pair is authoritative, the halo pair is
     its exact image (the per-step ``ok`` verdict crosses the mesh as a
@@ -301,7 +272,7 @@ def _weighted_h_index_halo(src_h, dst_h, valid, w, core_own, core_h,
         mid_o = (lo_o + hi_o + 1) // 2
         mid_h = (lo_h + hi_h + 1) // 2
         s = G.weighted_support(src_h, dst_h, valid, w, core_h, mid_h,
-                               hcap, session, backend=kernel_backend)
+                               hcap, session)
         ok_o = s >= mid_o
         ok_h = session.gather_values(ok_o.astype(jnp.int32)) > 0
         lo_o = jnp.where(ok_o, mid_o, lo_o)
@@ -326,7 +297,6 @@ def weighted_core_fixpoint_pass_halo(
     core_own: Array,
     core_h: Array,
     session: HaloSession,
-    kernel_backend: str = "lax",
 ):
     """``weighted_core_fixpoint_pass`` on a halo working set. The halo
     core image stays current WITHOUT ``refresh_values``: each round's
@@ -347,7 +317,6 @@ def weighted_core_fixpoint_pass_halo(
         core_own, core_h, _, rounds, fmax, steps = state
         lo_o, lo_h, k = _weighted_h_index_halo(
             src_h, dst_h, valid, w, core_own, core_h, session,
-            kernel_backend=kernel_backend,
         )
         new_o = jnp.minimum(core_own, lo_o)
         new_h = jnp.minimum(core_h, lo_h)

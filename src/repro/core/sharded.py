@@ -67,7 +67,6 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                        freelist: str = "interleaved",
                        frontier_exchange: str = "bitmask",
                        frontier_cap: int = 0,
-                       kernel_backend: str = "lax",
                        weighted: bool = False):
     """Build the jitted sharded mixed-batch engine over ``mesh``.
 
@@ -116,13 +115,6 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
     when any shard's frontier overflows the cap — bit-identical either
     way). ``frontier_cap`` is STATIC: one jitted engine per cap bucket,
     like ``local_active`` (api.py plans the pow2 bucket).
-
-    ``kernel_backend`` picks the per-round statistics implementation
-    (``"lax"`` segment_sum scatters or the ``"pallas"`` fused COO kernel,
-    kernels/coremaint.py). Inside the shard_map kernel the pallas path
-    replaces only the LOCAL partial-statistic computation — the layout
-    completion collectives are identical — so the mesh collective
-    schedule (and the committed budget manifests) are shared with lax.
 
     ``weighted`` builds the weight-generalized engine instead: the slot
     table carries a fourth sharded column ``w`` (per-slot edge weight,
@@ -254,7 +246,6 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                 src[:w], dst[:w], valid[:w], core, label, n_edges,
                 ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
                 n, n_levels, axis=axis, layout=None, freelist=freelist,
-                kernel_backend=kernel_backend,
             )
         else:
             # halo program: core/label stay OWNED [n_owned] slices end
@@ -265,7 +256,7 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                     src[:w], dst[:w], valid[:w], core, label, n_edges,
                     ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
                     n, n_levels, table_axis=table_axis, layout=layout,
-                    freelist=freelist, kernel_backend=kernel_backend,
+                    freelist=freelist,
                 )
             )
         with jax.named_scope("coremaint.table"):
@@ -288,8 +279,7 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                     src[:win], dst[:win], valid[:win], core, label,
                     n_edges, ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
                     n, n_levels, axis=axis, layout=None,
-                    freelist=freelist, kernel_backend=kernel_backend,
-                    w=ew[:win], ins_w=ins_w,
+                    freelist=freelist, w=ew[:win], ins_w=ins_w,
                 )
             )
         else:
@@ -298,8 +288,7 @@ def make_sharded_apply(mesh: Mesh, n: int, n_levels: int,
                     src[:win], dst[:win], valid[:win], core, label,
                     n_edges, ins_u, ins_v, ins_ok, rm_u, rm_v, rm_ok,
                     n, n_levels, table_axis=table_axis, layout=layout,
-                    freelist=freelist, kernel_backend=kernel_backend,
-                    w=ew[:win], ins_w=ins_w,
+                    freelist=freelist, w=ew[:win], ins_w=ins_w,
                 )
             )
         with jax.named_scope("coremaint.table"):

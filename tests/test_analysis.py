@@ -27,7 +27,6 @@ from repro.analysis import (
     Finding,
     TracedEngine,
     audit_engines,
-    check_bench,
     eval_formula,
     generate_budget,
     guess_formula,
@@ -427,232 +426,6 @@ def test_hostlint_bare_device_param_fires(tmp_path):
     [f] = finds
     assert f.func == "batch_program"
     assert "bool(...)" in f.message
-
-
-# -- benchcheck -------------------------------------------------------------
-
-def test_benchcheck_flags_incoherent_artifact(tmp_path):
-    from repro.analysis.benchcheck import BENCH_SCHEMA
-
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({
-        "schema": BENCH_SCHEMA,
-        "engines_agree": False,
-        "churn": {"engines_agree": True},
-        "frontier_scaling": [{"frontier_exchange": "bitmask"}],
-    }))
-    check = check_bench(str(p))
-    assert check["rule"] == "bench_coherence" and not check["ok"]
-    msgs = [f["message"] for f in check["findings"]]
-    assert any("engines diverged" in m for m in msgs)
-    assert any("lacks 'vertex_sharded'" in m for m in msgs)
-    assert any("n_devices" in m for m in msgs)
-    assert any("not a sparse-frontier row" in m for m in msgs)
-
-
-def test_benchcheck_launch_section(tmp_path):
-    """The v3 launch checks: a pallas round with no pallas_call (silent
-    fallback to the unfused path) and a pallas round that does not beat
-    lax's launch count are both incoherent; a genuinely-fused strictly
-    smaller section passes those checks."""
-    from repro.analysis.benchcheck import BENCH_SCHEMA
-
-    base = {
-        "schema": BENCH_SCHEMA,
-        "engines_agree": True,
-        "churn": {"engines_agree": True},
-        "pallas": {"batches_per_s": 3.0},
-    }
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({**base, "launches_per_round": {
-        "lax": {"removal": {"gather": 2}, "promotion": {"gather": 4}},
-        "pallas": {"removal": {"gather": 2},          # no pallas_call
-                   "promotion": {"pallas_call": 1, "gather": 4}},
-    }}))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("traces no pallas_call" in m for m in msgs)
-    assert any("not strictly fewer" in m for m in msgs)
-
-    p.write_text(json.dumps({**base, "launches_per_round": {
-        "lax": {"removal": {"gather": 9}, "promotion": {"gather": 9}},
-        "pallas": {"removal": {"pallas_call": 1, "scatter": 2},
-                   "promotion": {"pallas_call": 3, "scatter": 2}},
-    }}))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert not any("pallas" in m and "launch" in m for m in msgs)
-
-    p.write_text(json.dumps(base))  # section absent entirely
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("launches_per_round" in m for m in msgs)
-
-
-def test_benchcheck_v4_sections(tmp_path):
-    """The v4 coherence rules: interpret-mode pallas rows are excluded
-    from speedup coherence (the launch-count claim stays), mesh_scaling
-    rows must be halo rows whose [d_e, d_v] shape factorizes their
-    device count, and the frontier autoplan must show the overflow
-    fallback receding."""
-    from repro.analysis.benchcheck import BENCH_SCHEMA
-
-    base = {
-        "schema": BENCH_SCHEMA,
-        "engines_agree": True,
-        "churn": {"engines_agree": True},
-    }
-    p = tmp_path / "bench.json"
-    # interpret-mode pallas at a sub-1 speedup: NOT a finding; the same
-    # row without the stamp demands a coherent speedup and flags both
-    p.write_text(json.dumps({
-        **base,
-        "pallas": {"batches_per_s": 3.0, "interpret_mode": True},
-        "speedup_pallas_vs_host": 0.4,
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert not any("speedup_pallas" in m for m in msgs)
-    p.write_text(json.dumps({
-        **base,
-        "pallas": {"batches_per_s": 3.0},
-        "speedup_pallas_vs_host": 0.4,
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("interpret_mode stamp" in m for m in msgs)
-    assert any("speedup_pallas_vs_host is 0.40x" in m for m in msgs)
-    # a timed non-interpret engine row below the host baseline
-    p.write_text(json.dumps({
-        **base,
-        "vertex_halo": {"batches_per_s": 5.0},
-        "speedup_vertex_halo_vs_host": 0.9,
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("speedup_vertex_halo_vs_host is 0.90x" in m for m in msgs)
-    # mesh_scaling rows: the shape must factorize the device count, and
-    # only halo rows belong in the sweep
-    p.write_text(json.dumps({
-        **base,
-        "mesh_scaling": [
-            {"n_devices": 8, "mesh_shape": [4, 2],
-             "vertex_sharding": "halo"},
-            {"n_devices": 8, "mesh_shape": [4, 4],
-             "vertex_sharding": "halo"},
-            {"n_devices": 8, "mesh_shape": [2, 4],
-             "vertex_sharding": "range"},
-        ],
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert not any("mesh_scaling[0]" in m for m in msgs)
-    assert any("mesh_scaling[1]" in m and "factorizing" in m for m in msgs)
-    assert any("mesh_scaling[2]" in m and "not a halo row" in m
-               for m in msgs)
-    # the autoplan section must show fewer overflow fallbacks after
-    p.write_text(json.dumps({
-        **base,
-        "frontier_autoplan": {"overflow_rounds_before": 2,
-                              "overflow_rounds_after": 5,
-                              "blind_cap": 256, "tuned_cap": 512},
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("did not reduce overflow" in m for m in msgs)
-    p.write_text(json.dumps({
-        **base,
-        "frontier_autoplan": {"overflow_rounds_before": 9,
-                              "overflow_rounds_after": 0,
-                              "blind_cap": 256, "tuned_cap": 512},
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert not any("overflow" in m for m in msgs)
-
-
-def test_benchcheck_v5_sections(tmp_path):
-    """The v5 coherence rules: the weighted row must have been timed,
-    and the temporal sliding-window section must drain (insertions ==
-    removals, all-zero final cores), agree across engines, carry a sane
-    window/stride pair, and time every temporal engine."""
-    from repro.analysis.benchcheck import BENCH_SCHEMA
-
-    base = {
-        "schema": BENCH_SCHEMA,
-        "engines_agree": True,
-        "churn": {"engines_agree": True},
-    }
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({
-        **base,
-        "weighted": {"batches_per_s": 0.0},
-        "temporal": {
-            "window": 6, "stride": 9,  # stride > window: gap, flagged
-            "engines_agree": False,
-            "total_insertions": 500, "total_removals": 480,
-            "final_cores_zero": False,
-            "host": {"batches_per_s": 2.0},
-            # unified row missing entirely; sharded present but untimed
-            "sharded": {"batches_per_s": 0.0},
-            "weighted": {"batches_per_s": 1.0},
-        },
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert any("weighted.batches_per_s is not > 0" in m for m in msgs)
-    assert any("temporal engines diverged" in m for m in msgs)
-    assert any("did not drain" in m for m in msgs)
-    assert any("final_cores_zero" in m for m in msgs)
-    assert any("window/stride malformed" in m for m in msgs)
-    assert any("lacks the 'unified' engine row" in m for m in msgs)
-    assert any("temporal.sharded.batches_per_s is not > 0" in m
-               for m in msgs)
-    # a coherent v5 artifact raises none of the new findings
-    p.write_text(json.dumps({
-        **base,
-        "weighted": {"batches_per_s": 4.0},
-        "temporal": {
-            "window": 6, "stride": 3,
-            "engines_agree": True,
-            "total_insertions": 500, "total_removals": 500,
-            "final_cores_zero": True,
-            "host": {"batches_per_s": 2.0},
-            "unified": {"batches_per_s": 3.0},
-            "sharded": {"batches_per_s": 1.0},
-            "weighted": {"batches_per_s": 1.5},
-        },
-    }))
-    msgs = [f["message"] for f in check_bench(str(p))["findings"]]
-    assert not any("temporal" in m or "weighted" in m for m in msgs)
-
-
-def test_benchcheck_missing_artifact_one_actionable_finding(tmp_path):
-    """A missing BENCH_stream.json must produce ONE finding telling the
-    user how to regenerate it — not a traceback, not a cascade of
-    lacks-key noise."""
-    check = check_bench(str(tmp_path / "nope.json"))
-    assert not check["ok"]
-    [f] = check["findings"]
-    assert "no bench artifact" in f["message"]
-    assert "benchmarks.run" in f["message"]
-
-
-def test_benchcheck_stale_schema_one_actionable_finding(tmp_path):
-    """An artifact predating the current schema stamp (e.g. recorded
-    before max_frontier observability) is rejected with a single
-    regenerate hint, even if its other fields look coherent."""
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({
-        "schema": "repro.analysis/bench/v1",
-        "engines_agree": True,
-        "churn": {"engines_agree": True},
-    }))
-    check = check_bench(str(p))
-    assert not check["ok"]
-    [f] = check["findings"]
-    assert "predates the current artifact schema" in f["message"]
-    assert "repro.analysis/bench/v1" in f["message"]
-    assert "benchmarks.run" in f["message"]
-
-
-def test_benchcheck_accepts_committed_artifact():
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "BENCH_stream.json")
-    check = check_bench(path)
-    assert check["ok"], check["findings"]
 
 
 # -- report schema ----------------------------------------------------------

@@ -1,8 +1,7 @@
 """Cross-engine churn harness: long balanced insert/remove/re-insert
 streams through EVERY engine configuration — host / unified / sharded,
 plus the sharded engine's range-sharded vertex layout, hierarchical
-free-list, and sparse frontier-exchange variants, and the fused Pallas
-stat-kernel backend on both device engines — pinned bit-identical
+free-list, and sparse frontier-exchange variants — pinned bit-identical
 to each other and to the sequential oracle. This is the differential
 lockdown of the in-program free-list slot recycler, the per-shard
 high-water window, and the vertex-layout layer (sparse frontier
@@ -74,10 +73,6 @@ CONFIGS = {
     # single device) — the owner-range working set plus halo must stay
     # bit-identical to every flat layout
     "vertex_halo": dict(engine="sharded", vertex_sharding="halo"),
-    # the fused Pallas stat kernels (kernels/coremaint.py) — interpret
-    # mode off-TPU, so this runs (and must stay bit-identical) everywhere
-    "pallas": dict(engine="unified", kernel_backend="pallas"),
-    "pallas_sharded": dict(engine="sharded", kernel_backend="pallas"),
     # the weighted h-index engine: on the unit weights apply_batch
     # defaults to, weighted coreness degenerates to plain coreness, so
     # these rows ride the SAME dirty stream and must match every other
@@ -167,8 +162,8 @@ def _run_churn_differential(m0, graph_seed, stream_seed, n_batches,
             assert int(st_.n_inserted) == len(inserted), e
             assert int(st_.n_removed) == len(removed), e
         # the order-based engines run the same promotion passes: equal
-        # FORWARD / EVICT wave counts across engines, layouts and kernel
-        # backends; the weighted engines run no such waves
+        # FORWARD / EVICT wave counts across engines and layouts; the
+        # weighted engines run no such waves
         for e, st_ in stats.items():
             got = (int(st_.forward_waves), int(st_.evict_waves))
             if e in WEIGHTED_CONFIGS:
@@ -184,7 +179,7 @@ def _run_churn_differential(m0, graph_seed, stream_seed, n_batches,
         # both free-list rankings allocate the identical live set (slot
         # POSITIONS may differ across shards; the keys may not)
         for e in ("sharded", "vertex_range", "freelist_hier",
-                  "frontier_sparse", "vertex_halo", "pallas_sharded",
+                  "frontier_sparse", "vertex_halo",
                   "weighted", "weighted_sharded"):
             assert ms[e].edge_slot.keys() == u.edge_slot.keys(), e
     # balanced stream + generous initial capacity: nothing may grow
@@ -677,13 +672,8 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
                                    vertex_sharding="range",
                                    frontier_exchange="sparse",
                                    frontier_cap=4)
-    # the fused Pallas stat kernels under a REAL 8-shard mesh (interpret
-    # mode off-TPU): local partials swap in, the collective schedule does
-    # not change, so cores AND labels must track the lax engines exactly
-    mp = CoreMaintainer.from_graph(g, capacity=645, engine="sharded",
-                                   kernel_backend="pallas")
     # 2-axis halo meshes: both proper edge x vertex factorizations of the
-    # same 8 devices (one on each kernel backend) plus BOTH degenerate
+    # same 8 devices plus BOTH degenerate
     # shapes — (1, 8) is pure vertex sharding, (8, 1) pure edge sharding
     # — all of which must track the flat engines bit-exactly
     mh42 = CoreMaintainer.from_graph(g, capacity=645, engine="sharded",
@@ -691,8 +681,7 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
                                      mesh_shape=(4, 2))
     mh24 = CoreMaintainer.from_graph(g, capacity=645, engine="sharded",
                                      vertex_sharding="halo",
-                                     mesh_shape=(2, 4),
-                                     kernel_backend="pallas")
+                                     mesh_shape=(2, 4))
     mh18 = CoreMaintainer.from_graph(g, capacity=645, engine="sharded",
                                      vertex_sharding="halo",
                                      mesh_shape=(1, 8))
@@ -710,13 +699,13 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
     events = list(churn_stream(g, 8, 24, seed=5))
     for ev in events[:6]:
         waves = set()
-        for m in (ms, mu, mv, mh, mf, mp, *halos):
+        for m in (ms, mu, mv, mh, mf, *halos):
             st = m.apply_batch(insert_edges=ev.edges,
                                remove_edges=ev.removals)
             waves.add((int(st.insert_rounds), int(st.forward_waves),
                        int(st.evict_waves)))
-        # the wave counters count the same passes on every layout, mesh
-        # and kernel backend
+        # the wave counters count the same passes on every layout and
+        # mesh
         assert len(waves) == 1, waves
         for e in norm(ev.removals):
             live.discard(e)
@@ -732,8 +721,6 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
         np.testing.assert_array_equal(mu.labels(), mh.labels())
         np.testing.assert_array_equal(mu.cores(), mf.cores())
         np.testing.assert_array_equal(mu.labels(), mf.labels())
-        np.testing.assert_array_equal(mu.cores(), mp.cores())
-        np.testing.assert_array_equal(mu.labels(), mp.labels())
         for hm in halos:
             np.testing.assert_array_equal(mu.cores(), hm.cores())
             np.testing.assert_array_equal(mu.labels(), hm.labels())
@@ -763,7 +750,7 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
         tuple(e) for e in live
     }
     for ev in events[6:]:
-        for m in (ms, mu, mv, mh, mf, mp, *halos, m2, m3, m4, m5):
+        for m in (ms, mu, mv, mh, mf, *halos, m2, m3, m4, m5):
             m.apply_batch(insert_edges=ev.edges, remove_edges=ev.removals)
         for e in norm(ev.removals):
             live.discard(e)
@@ -774,8 +761,8 @@ _ROUNDTRIP_8DEV = textwrap.dedent(
                                                    dtype=np.int64)))
     for name, m in (("sharded", ms), ("unified", mu),
                     ("vertex-range", mv), ("freelist-hier", mh),
-                    ("frontier-sparse", mf), ("pallas-sharded", mp),
-                    ("halo-4x2", mh42), ("halo-2x4-pallas", mh24),
+                    ("frontier-sparse", mf),
+                    ("halo-4x2", mh42), ("halo-2x4", mh24),
                     ("halo-1x8", mh18), ("halo-8x1", mh81),
                     ("reload-sharded", m2), ("reload-unified", m3),
                     ("reload-vertex-range", m4), ("reload-vs-unified", m5)):
@@ -810,8 +797,6 @@ _WEIGHTED_8DEV = textwrap.dedent(
     mk = dict(capacity=cap, weighted=True, weights=w0)
     engines = {
         "unified": CoreMaintainer.from_graph(g, **mk),
-        "pallas": CoreMaintainer.from_graph(g, kernel_backend="pallas",
-                                            **mk),
         "sharded": CoreMaintainer.from_graph(g, engine="sharded", **mk),
         "range_sparse": CoreMaintainer.from_graph(
             g, engine="sharded", vertex_sharding="range",
@@ -885,8 +870,8 @@ _WEIGHTED_8DEV = textwrap.dedent(
 
 @pytest.mark.slow
 def test_weighted_churn_oracle_8dev(tmp_path):
-    """8 forced host devices: the weighted engine matrix (unified lax +
-    pallas, sharded replicated, range+sparse, 2x4 halo) under random
+    """8 forced host devices: the weighted engine matrix (unified,
+    sharded replicated, range+sparse, 2x4 halo) under random
     integer weights stays pinned to the peeling oracle — cores AND
     labels — through dirty churn and a mid-churn save/load whose
     checkpoint carries the weight column."""

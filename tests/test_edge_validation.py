@@ -114,3 +114,16 @@ def test_x64_guard_fires_loudly(small_graph):
             mh.remove_edges(small_graph.edge_array()[:1])
     finally:
         jax.config.update("jax_enable_x64", True)
+
+
+def test_kernel_backend_accepts_only_lax(small_graph):
+    """``from_graph`` still takes ``kernel_backend``, which older
+    configurations pass, and allows only the one statistics path: any
+    other value is refused by name, and the maintainer keeps no field."""
+    m = CoreMaintainer.from_graph(small_graph, capacity=64,
+                                  kernel_backend="lax")
+    np.testing.assert_array_equal(m.cores(), bz_from_csr(small_graph))
+    assert not hasattr(m, "kernel_backend")
+    with pytest.raises(ValueError, match="kernel_backend='pallas'"):
+        CoreMaintainer.from_graph(small_graph, capacity=64,
+                                  kernel_backend="pallas")

@@ -60,16 +60,14 @@ def _executed_instructions(hlo_text: str):
     return out
 
 
-@pytest.mark.parametrize("backend", ["lax", "pallas"])
-def test_every_kernel_of_the_unified_program_has_a_phase_scope(backend):
+def test_every_kernel_of_the_unified_program_has_a_phase_scope():
     n, cap, lanes, window = 48, 256, 8, 128
     z = jnp.zeros
     args = (z(cap, jnp.int32), jnp.ones(cap, jnp.int32), z(cap, bool),
             z(n, jnp.int32), z(n, jnp.int64), jnp.int32(0),
             z(lanes, jnp.int32), jnp.ones(lanes, jnp.int32), z(lanes, bool),
             z(lanes, jnp.int32), jnp.ones(lanes, jnp.int32), z(lanes, bool))
-    text = apply_batch.lower(*args, n, n + 2, window,
-                             kernel_backend=backend).compile().as_text()
+    text = apply_batch.lower(*args, n, n + 2, window).compile().as_text()
     rows = _executed_instructions(text)
     timed = [r for r in rows if r[1] in TIMED]
     assert sum(r[1] == "while" for r in timed) >= 5  # every fixpoint loop
@@ -122,8 +120,7 @@ def test_wave_counters_on_a_removal_only_batch():
     assert int(e.forward_waves) == int(e.evict_waves) == 0
 
 
-@pytest.mark.parametrize("config", [{}, dict(kernel_backend="pallas"),
-                                    dict(engine="sharded"),
+@pytest.mark.parametrize("config", [{}, dict(engine="sharded"),
                                     dict(engine="host")])
 def test_wave_counters_cover_every_promotion_round(config):
     g, ms = _maintainers(m=config)

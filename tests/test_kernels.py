@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import graph_ops as G
-from repro.kernels import coremaint, ref
+from repro.kernels import ref
 from repro.kernels.segment_ell import ell_aggregate, ell_stat
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fm_interaction import fm_interaction
@@ -199,137 +199,3 @@ def test_ell_stat_matches_graph_ops_on_random_graphs(op, seed):
     fn = G.count_ge if op == "count_ge" else G.count_gt
     want = fn(src, dst, valid, vals, g.n)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-# -- fused COO maintenance kernels (kernels/coremaint.py) -------------------
-
-def _random_slot_table(seed, n=150, cap=512):
-    """A random COO slot table shaped like the engines': dead slots,
-    self-edge-free random endpoints, maintenance-like core/label state."""
-    rng = np.random.default_rng(seed)
-    src = rng.integers(0, n, size=cap).astype(np.int32)
-    dst = rng.integers(0, n, size=cap).astype(np.int32)
-    dst = np.where(dst == src, (dst + 1) % n, dst).astype(np.int32)
-    valid = rng.random(cap) < 0.7
-    core = rng.integers(0, 6, size=n).astype(np.int32)
-    label = rng.integers(0, 1 << 40, size=n).astype(np.int64)
-    aux = rng.random(n) < 0.4
-    return (jnp.asarray(src), jnp.asarray(dst), jnp.asarray(valid),
-            jnp.asarray(core), jnp.asarray(label), jnp.asarray(aux))
-
-
-@pytest.mark.parametrize("seed", [0, 3, 7])
-def test_coo_stat_matches_graph_ops(seed):
-    """Every packed stat of the fused kernel is BIT-identical to the lax
-    segment-sum implementations it replaces (integer adds only — order
-    cannot matter)."""
-    n = 150
-    src, dst, valid, core, label, aux = _random_slot_table(seed, n=n)
-    k = lambda stat, a=None: np.asarray(coremaint.coo_stat(
-        src, dst, valid, core, label, n, stat=stat, aux=a, interpret=True))
-
-    mcd, hi, dout = G.mcd_hi_dout(src, dst, valid, core, label, n)
-    np.testing.assert_array_equal(
-        k("mcd_hi_dout"),
-        np.stack([np.asarray(mcd), np.asarray(hi), np.asarray(dout)], -1),
-    )
-    np.testing.assert_array_equal(
-        k("hi_dout"), np.stack([np.asarray(hi), np.asarray(dout)], -1)
-    )
-    np.testing.assert_array_equal(
-        k("mcd")[:, 0], np.asarray(G.count_ge(src, dst, valid, core, n))
-    )
-    np.testing.assert_array_equal(
-        k("same_in", aux)[:, 0],
-        np.asarray(G.count_same_level_in(src, dst, valid, core, aux, n)),
-    )
-    din, expand = G.din_and_expand(src, dst, valid, core, label, aux, n)
-    np.testing.assert_array_equal(k("din", aux)[:, 0], np.asarray(din))
-    np.testing.assert_array_equal(k("din", aux)[:, 0] > 0,
-                                  np.asarray(expand))
-
-
-@pytest.mark.parametrize("seed", [0, 5])
-def test_fused_removal_round_matches_unfused(seed):
-    """The single-launch removal round == stats pass + host-side
-    threshold + commit, including the decision outputs."""
-    n = 150
-    src, dst, valid, core, label, _ = _random_slot_table(seed, n=n)
-    mcd, hi, dout, new_core, drop = coremaint.fused_removal_round(
-        src, dst, valid, core, label, n, interpret=True
-    )
-    wm, wh, wd = G.mcd_hi_dout(src, dst, valid, core, label, n)
-    wdrop = (wm < core) & (core > 0)
-    np.testing.assert_array_equal(np.asarray(mcd), np.asarray(wm))
-    np.testing.assert_array_equal(np.asarray(hi), np.asarray(wh))
-    np.testing.assert_array_equal(np.asarray(dout), np.asarray(wd))
-    np.testing.assert_array_equal(np.asarray(drop), np.asarray(wdrop))
-    np.testing.assert_array_equal(
-        np.asarray(new_core),
-        np.asarray(core - wdrop.astype(jnp.int32)),
-    )
-    assert np.asarray(drop).any(), "degenerate case: no drops exercised"
-
-
-@pytest.mark.parametrize("seed", [0, 5])
-def test_fused_promotion_stats_matches_unfused(seed):
-    n = 150
-    src, dst, valid, core, label, _ = _random_slot_table(seed, n=n)
-    hi, dout, viol = coremaint.fused_promotion_stats(
-        src, dst, valid, core, label, n, interpret=True
-    )
-    wh, wd = G.hi_and_dout_same(src, dst, valid, core, label, n)
-    np.testing.assert_array_equal(np.asarray(hi), np.asarray(wh))
-    np.testing.assert_array_equal(np.asarray(dout), np.asarray(wd))
-    np.testing.assert_array_equal(
-        np.asarray(viol), np.asarray((wh + wd) > core)
-    )
-    assert np.asarray(viol).any(), "degenerate case: no violators exercised"
-
-
-def test_coo_stat_empty_table_short_circuits():
-    """cap == 0 and n == 0 must return explicit zeros (the same class of
-    zero-grid bug fixed in segment_ell)."""
-    core = jnp.zeros((9,), jnp.int32)
-    label = jnp.zeros((9,), jnp.int64)
-    e = jnp.zeros((0,), jnp.int32)
-    out = coremaint.coo_stat(e, e, jnp.zeros((0,), bool), core, label, 9,
-                             stat="mcd_hi_dout", interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.zeros((9, 3), np.int32))
-    out = coremaint.coo_stat(e, e, jnp.zeros((0,), bool),
-                             jnp.zeros((0,), jnp.int32),
-                             jnp.zeros((0,), jnp.int64), 0,
-                             stat="hi_dout", interpret=True)
-    assert out.shape == (0, 2)
-    mcd, hi, dout, new_core, drop = coremaint.fused_removal_round(
-        e, e, jnp.zeros((0,), bool), core, label, 9, interpret=True
-    )
-    np.testing.assert_array_equal(np.asarray(new_core), np.zeros(9, np.int32))
-    assert not np.asarray(drop).any()
-
-
-def test_coo_stat_rejects_non_int64_labels():
-    """x32 labels would silently truncate the k-order comparisons —
-    refuse loudly (the same guard the engines enforce via _require_x64)."""
-    n = 8
-    e = jnp.zeros((4,), jnp.int32)
-    with pytest.raises(TypeError, match="int64"):
-        coremaint.coo_stat(e, e + 1, jnp.ones((4,), bool),
-                           jnp.zeros((n,), jnp.int32),
-                           jnp.zeros((n,), jnp.int32), n,
-                           stat="hi_dout", interpret=True)
-
-
-def test_coo_stat_non_divisible_blocks():
-    """n and cap straddling block boundaries: padding slots/vertices must
-    contribute nothing and the unpadded prefix must round-trip."""
-    n = 77  # not a multiple of any pow2 block
-    src, dst, valid, core, label, _ = _random_slot_table(11, n=n, cap=300)
-    out = coremaint.coo_stat(src, dst, valid, core, label, n,
-                             stat="mcd_hi_dout", block_n=64, block_e=128,
-                             interpret=True)
-    mcd, hi, dout = G.mcd_hi_dout(src, dst, valid, core, label, n)
-    np.testing.assert_array_equal(
-        np.asarray(out),
-        np.stack([np.asarray(mcd), np.asarray(hi), np.asarray(dout)], -1),
-    )

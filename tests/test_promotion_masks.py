@@ -325,8 +325,7 @@ def test_wave_loops_gather_only_boolean_vertex_vectors():
             z(n, jnp.int32), z(n, jnp.int64), jnp.int32(0),
             z(lanes, jnp.int32), jnp.ones(lanes, jnp.int32), z(lanes, bool),
             z(lanes, jnp.int32), jnp.ones(lanes, jnp.int32), z(lanes, bool))
-    text = apply_batch.lower(*args, n, n + 2, window,
-                             kernel_backend="lax").as_text(
+    text = apply_batch.lower(*args, n, n + 2, window).as_text(
         dialect="hlo", debug_info=True)
     comps = _computations(text)
     loops = {}

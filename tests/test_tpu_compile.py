@@ -1,13 +1,10 @@
 """The main path's programs compiled for a described TPU v5e (no chip
-needed): the unified lax batch program for one chip, the halo batch
-program on a (2, 2) mesh, and the fused Pallas statistics kernel, which
-the TPU compiler refuses today.
+needed): the unified batch program and its weighted twin for one chip,
+and the halo batch program on a (2, 2) mesh.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file."""
-import functools
-
 import numpy as np
 import pytest
 
@@ -18,7 +15,6 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core import engine
 from repro.core.sharded import make_sharded_apply
-from repro.kernels.coremaint import coo_stat
 
 N = 1024          # vertices
 CAP = 4096        # slot-table capacity
@@ -62,7 +58,7 @@ def test_unified_batch_program_compiles_for_one_v5e_chip(topo):
     one = SingleDeviceSharding(topo.devices[0])
     compiled = engine.apply_batch.lower(
         *_batch_program_args(one, one, one),
-        n=N, n_levels=N + 2, active_cap=CAP // 2, kernel_backend="lax",
+        n=N, n_levels=N + 2, active_cap=CAP // 2,
     ).compile()
     mem = compiled.memory_analysis()
     # the six state buffers are donated: updated in place on the chip
@@ -80,7 +76,6 @@ def test_weighted_batch_program_compiles_for_one_v5e_chip(topo):
     args = a[:3] + [w] + a[3:8] + [ins_w] + a[8:]
     compiled = engine.apply_batch_weighted.lower(
         *args, n=N, n_levels=N + 2, active_cap=CAP // 2,
-        kernel_backend="lax",
     ).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0
 
@@ -97,17 +92,3 @@ def test_halo_batch_program_compiles_on_v5e_2x2_mesh(topo):
     text = compiled.as_text()
     assert "all-reduce" in text or "all-gather" in text  # a real mesh program
 
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="TPU compiler: 'Only 2D gather is supported' — "
-                          "coo_stat gathers endpoint state with jnp.take "
-                          "inside the kernel (ROADMAP A5)")
-def test_coo_stat_kernel_compiles_for_v5e(topo):
-    one = SingleDeviceSharding(topo.devices[0])
-    s = jax.ShapeDtypeStruct
-    fn = jax.jit(functools.partial(coo_stat, n=N, interpret=False))
-    fn.lower(s((CAP,), jnp.int32, sharding=one),
-             s((CAP,), jnp.int32, sharding=one),
-             s((CAP,), jnp.bool_, sharding=one),
-             s((N,), jnp.int32, sharding=one),
-             s((N,), jnp.int64, sharding=one)).compile()
